@@ -8,8 +8,10 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def setup_devices(n_devices):
-    """Force a virtual n-device CPU platform when no TPU slice is attached.
-    On a real TPU pod slice, pass --devices 0 to use the attached chips."""
+    """n_devices > 0: force the CPU platform with that many virtual devices
+    (the examples' default, so they run anywhere). n_devices == 0: leave
+    jax alone and use whatever is attached — the chips, where there are
+    any. Must run before anything else touches jax."""
     if n_devices and int(n_devices) > 0:
         flags = os.environ.get("XLA_FLAGS", "")
         if "host_platform_device_count" not in flags:

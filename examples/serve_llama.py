@@ -7,8 +7,9 @@ sequences retire and their blocks recycle mid-flight — the
 iteration-level scheduling loop of modern LLM servers, built on a
 block-paged KV pool so fragmentation never strands HBM.
 
-Run: python examples/serve_llama.py            (CPU or attached TPU)
-     python examples/serve_llama.py --devices 0  # force real devices
+Run: python examples/serve_llama.py              # one virtual CPU device
+     python examples/serve_llama.py --devices 0  # the attached devices (a
+                                                 # chip, where there is one)
 """
 
 import argparse

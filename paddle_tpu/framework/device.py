@@ -2,7 +2,7 @@
 
 On TPU there is one first-class device family; Place collapses to a thin
 wrapper over jax.Device. CUDAPlace/XPUPlace aliases exist for API parity and
-map to the accelerator if present, else CPU.
+map to the accelerator.
 """
 
 from __future__ import annotations
@@ -51,16 +51,26 @@ class XPUPlace(TPUPlace):
 
 
 def set_device(device: str):
-    """paddle.set_device('tpu') / ('cpu') / ('tpu:0')"""
+    """paddle.set_device('tpu') / ('cpu') / ('tpu:0'). Raises when the
+    named device does not exist: asking for an accelerator never yields
+    the CPU, and an out-of-range index never yields another chip."""
     global _current_device
     name, _, idx = device.partition(":")
     idx = int(idx) if idx else 0
     name = {"gpu": "tpu", "cuda": "tpu", "xpu": "tpu"}.get(name, name)
-    devs = jax.devices() if name != "cpu" else jax.devices("cpu")
-    if name not in ("cpu",):
-        accel = [d for d in devs if d.platform != "cpu"]
-        devs = accel or devs
-    _current_device = devs[min(idx, len(devs) - 1)]
+    if name == "cpu":
+        devs = jax.devices("cpu")
+    else:
+        devs = [d for d in jax.devices() if d.platform != "cpu"]
+        if not devs:
+            raise RuntimeError(
+                f"set_device({device!r}): no accelerator is attached "
+                f"(jax.default_backend() == {jax.default_backend()!r})")
+    if not 0 <= idx < len(devs):
+        raise ValueError(
+            f"set_device({device!r}): index {idx} out of range, "
+            f"{len(devs)} {name} device(s) present")
+    _current_device = devs[idx]
     jax.config.update("jax_default_device", _current_device)
     return get_device()
 

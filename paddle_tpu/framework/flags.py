@@ -50,10 +50,6 @@ def _apply_side_effect(name, value):
         import jax
         jax.config.update("jax_default_matmul_precision",
                           None if value == "default" else value)
-    elif name == "jit_cache_dir" and value:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", value)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     elif name == "observability":
         from ..observability import disable, enable
         s = str(value).lower()
@@ -115,7 +111,6 @@ define_flag("stop_check_timeout", 3600, "ref FLAGS_stop_check_timeout: elastic t
 define_flag("retain_grad_for_all_tensor", False, "ref FLAGS_retain_grad_for_all_tensor: keep .grad on non-leaf tensors")
 # compiled-step behavior
 define_flag("use_stride_kernel", False, "ref FLAGS_use_stride_kernel; XLA has no stride kernels (informational)")
-define_flag("jit_cache_dir", "", "persistent XLA compilation cache directory ('' = off)")
 define_flag("jit_donate_buffers", True, "donate param/opt buffers in compiled train steps")
 # PIR-lite compiler layer (paddle_tpu/pir/; ref: paddle/pir + FLAGS_enable_pir_api)
 define_flag("pir", True, "route to_static/serving compilation through the PIR pass pipeline (ref FLAGS_enable_pir_api); off = plain jax.jit")

@@ -220,6 +220,12 @@ def _finished_dict(req):
             "shed_count": int(getattr(req, "shed_count", 0))}
 
 
+def _engine_platform(engine):
+    """Platform of the device holding the engine's weights ("cpu",
+    "tpu") — the hello says where a replica really runs."""
+    return next(iter(engine.embed_w.devices())).platform
+
+
 def serve_request(engine, kind, meta, payload, exports=None):
     """Dispatch one decoded frame against `engine`; returns the reply
     frame parts (kind, meta, payload). `exports` is the worker-held
@@ -242,6 +248,7 @@ def serve_request(engine, kind, meta, payload, exports=None):
                 f"{kind} rejected: deadline expired before dispatch")
         if kind == "ping":
             return "ok", {"pid": os.getpid(),
+                          "platform": _engine_platform(engine),
                           "vocab": int(engine.embed_w.shape[0]),
                           "block_size": int(engine.pool.block_size)}, b""
         if kind == "add_request":
@@ -895,11 +902,14 @@ class ProcessReplica(Replica):
     worker-reported wall (the per-chip cost) and a lost transport walks
     the same death path as pool.kill."""
 
-    __slots__ = ("proc",)
+    __slots__ = ("proc", "platform")
 
-    def __init__(self, name, proxy, role="both", proc=None, **kw):
+    def __init__(self, name, proxy, role="both", proc=None, platform=None,
+                 **kw):
         super().__init__(name, proxy, role=role, **kw)
         self.proc = proc
+        # where the replica's engine runs, as its hello reported it
+        self.platform = platform
         proxy.on_lost = self._on_lost
 
     def _on_lost(self, _proxy):
@@ -919,8 +929,9 @@ class ProcessReplica(Replica):
 
 def _spawn_worker(name, spec, listener, worker_env=None):
     """Launch one worker child (two_proc_worker idiom: plain
-    sys.executable subprocess, CPU-pinned jax) and accept its transport
-    connection. Returns (proc, sock, hello-meta)."""
+    sys.executable subprocess, JAX_PLATFORMS=cpu — this process may hold
+    the chip) and accept its transport connection. Returns (proc, sock,
+    hello-meta); the hello carries the worker's platform."""
     specfile = tempfile.NamedTemporaryFile(
         mode="w", suffix=f".{name}.json", delete=False)
     json.dump(spec, specfile)
@@ -1029,6 +1040,7 @@ class ProcessReplicaPool(ReplicaPool):
                 # delivery is via the frame protocol, like a process
                 self._wire_loopback_sink(engine, proxy)
             return ProcessReplica(name, proxy, role=role,
+                                  platform=_engine_platform(engine),
                                   failure_threshold=failure_threshold,
                                   reset_timeout=reset_timeout)
         spec = dict(self.engine_spec)
@@ -1043,6 +1055,7 @@ class ProcessReplicaPool(ReplicaPool):
                             block_size=hello["block_size"], name=name,
                             op_timeout_s=self.op_timeout_s)
         return ProcessReplica(name, proxy, role=role, proc=proc,
+                              platform=hello["platform"],
                               failure_threshold=failure_threshold,
                               reset_timeout=reset_timeout)
 

@@ -69,10 +69,6 @@ def _use_pallas(q_shape, head_dim, has_bias, dtype=None, causal=True):
     backend = _flags.flag_value("flash_attention_backend")
     if backend == "xla":
         return False
-    try:
-        import jax.experimental.pallas  # noqa: F401
-    except Exception:
-        return False
     if jax.default_backend() != "tpu":
         return False
     if backend == "pallas":
@@ -81,8 +77,8 @@ def _use_pallas(q_shape, head_dim, has_bias, dtype=None, causal=True):
     # (ops/pallas/attention_router) — the r5 A/B showed the flash kernel
     # losing to dense XLA at most production shapes and winning at
     # others, so a fixed seq/head_dim threshold is wrong in both
-    # directions. The router falls back to measurement, then to the old
-    # thresholds, each with provenance.
+    # directions. On a TPU an import or router failure propagates: a
+    # broken kernel path must not read as "dense was chosen".
     from ...ops.pallas.attention_router import route
     b, seq = q_shape[0], q_shape[1]
     heads = q_shape[2] if len(q_shape) > 3 else 1

@@ -7,9 +7,8 @@ single substrate: Counter / Gauge / Histogram with labels, exported as
 Prometheus text or a JSONL snapshot that bench rows can embed verbatim.
 
 Deliberately STANDALONE: stdlib only, no package-relative imports — so
-`bench.py`'s orchestrating parent (which must never import jax) and
-`tools/metrics_dump.py` can load this file directly via
-importlib.util.spec_from_file_location.
+`tools/metrics_dump.py` (or any process that must not import jax) can
+load this file directly via importlib.util.spec_from_file_location.
 
 Zero-cost when disabled: every mutation starts with one attribute check
 (`self._state.enabled`) and returns before taking the lock or touching

@@ -104,9 +104,12 @@ def autotune(key, candidates: Sequence[Any], make_runner, default=None,
 
     make_runner(candidate) -> zero-arg callable that executes the kernel
     with that config on synthetic inputs and blocks until ready, or raises
-    to disqualify the candidate (e.g. VMEM overflow). Falls back to
-    `default` (or the first candidate) if tuning is disabled or every
-    candidate fails.
+    ValueError for a candidate that does not apply to the shape. A
+    candidate the compiler or the device refuses (e.g. VMEM overflow) is
+    disqualified with the message logged and counted
+    (attention_backend_failures_total{site="autotune"}). Returns
+    `default` (or the first candidate) if tuning is disabled; raises if
+    every applicable candidate was refused.
     """
     if default is None:
         default = candidates[0]
@@ -117,14 +120,26 @@ def autotune(key, candidates: Sequence[Any], make_runner, default=None,
         return cached
     best, best_t = default, float("inf")
     timings = {}
+    refused = 0
     for cand in candidates:
         try:
-            t = _time_once(make_runner(cand), repeats)
-        except Exception:
-            continue  # config not compilable on this device/shape
+            runner = make_runner(cand)
+        except ValueError:
+            continue  # candidate does not apply to this shape
+        try:
+            t = _time_once(runner, repeats)
+        except Exception as e:  # noqa: BLE001 — any compiler/runtime class
+            from .attention_router import _backend_failed
+            _backend_failed("autotune", e)
+            refused += 1
+            continue
         timings[str(cand)] = round(t * 1e3, 3)
         if t < best_t:
             best, best_t = cand, t
+    if refused and not timings:
+        raise RuntimeError(
+            f"autotune {key}: every applicable candidate was refused "
+            "(see the attention backend failure warnings above)")
     _global_cache.put(key, best)
     # full spread kept separately (not in the winner cache — it would
     # skew hit/size stats), for offline analysis when baking shipped

@@ -184,11 +184,10 @@ def _ring_dense(q, k, v, axis_name, causal, scale):
     acc = jnp.zeros((b, h, s_local, d), jnp.float32)
     m = jnp.full((b, h, s_local, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((b, h, s_local, 1), jnp.float32)
-    if hasattr(jax.lax, "pcast"):
-        # new-style shard_map tracks varying-manual-axes; mark the carries
-        # as varying over the ring axis so the scan carry types match
-        acc, m, l = (jax.lax.pcast(x, (axis_name,), to="varying")
-                     for x in (acc, m, l))
+    # shard_map tracks varying-manual-axes; mark the carries as varying
+    # over the ring axis so the scan carry types match
+    acc, m, l = (jax.lax.pcast(x, (axis_name,), to="varying")
+                 for x in (acc, m, l))
 
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
 

@@ -28,19 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map_fn
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_fn(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_old
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map_old(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
-
 __all__ = ["moe_dispatch_combine", "ExpertParallelMoE", "gshard_dispatch"]
 
 
@@ -139,9 +126,10 @@ def moe_dispatch_combine(x, gate_logits, expert_apply, expert_params,
 
     pspecs = jax.tree_util.tree_map(
         lambda w: P(axis_name, *([None] * (w.ndim - 1))), expert_params)
-    return shard_map(local, mesh,
-                     in_specs=(P(axis_name, None), P(axis_name, None), pspecs),
-                     out_specs=(P(axis_name, None), P(axis_name, None)))(
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(axis_name, None), P(axis_name, None), pspecs),
+        out_specs=(P(axis_name, None), P(axis_name, None)))(
         x, gate_logits, expert_params)
 
 

@@ -25,11 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # older spelling
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
 __all__ = ["pipeline_forward", "pipeline_1f1b_grads", "PipelinedLM",
            "OneFOneBPipeline", "ZeroBubblePipeline",
            "InterleavedPipelinedLM"]
@@ -38,16 +33,11 @@ __all__ = ["pipeline_forward", "pipeline_1f1b_grads", "PipelinedLM",
 def _pvary(x, axes):
     if isinstance(axes, str):
         axes = (axes,)
-    if not hasattr(jax.lax, "pcast"):
-        return x
-    try:
-        current = jax.typeof(x).vma
-    except Exception:
-        current = frozenset()
-    missing = tuple(a for a in axes if a not in current)
-    if not missing:
-        return x
-    return jax.lax.pcast(x, missing, to="varying")
+
+    def leaf(a):
+        missing = tuple(ax for ax in axes if ax not in jax.typeof(a).vma)
+        return jax.lax.pcast(a, missing, to="varying") if missing else a
+    return jax.tree_util.tree_map(leaf, x)
 
 
 def pipeline_forward(stage_fn: Callable, stacked_stage_params, inputs_mb,
@@ -510,8 +500,8 @@ class OneFOneBPipeline:
                 jax.tree_util.tree_map(lambda _: P(axis), stage_params),
                 jax.tree_util.tree_map(lambda _: P(), head_params),
             )
-            return shard_map(inner, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)(
+            return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs)(
                 embed_params, stage_params, head_params, tokens, labels)
 
         return spmd_grads
@@ -601,8 +591,8 @@ class PipelinedLM:
                 jax.tree_util.tree_map(lambda _: P(), head_params),
                 data_spec, data_spec,
             )
-            partials = shard_map(inner, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_spec)(
+            partials = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_spec)(
                 embed_params, stage_params, head_params, tokens, labels)
             if batch_axis is not None:
                 return jnp.mean(jnp.sum(partials, axis=0))  # sum pp, mean dp

@@ -253,13 +253,37 @@ class SpmdTrainer:
             donate_argnums=(0, 1),
         )
 
-    def step(self, batch, rng_key=None):
-        """batch: (x, y) of Tensors or arrays. Returns float loss."""
+    def _batch_arrays(self, batch):
         batch_arrays = jax.tree_util.tree_map(
             lambda t: t._data if isinstance(t, Tensor) else jnp.asarray(t),
             batch, is_leaf=lambda v: isinstance(v, Tensor))
         if self._compiled is None:
             self._compiled = self._build(batch_arrays)
+        return batch_arrays
+
+    def step_memory(self, batch):
+        """XLA's buffer assignment for the compiled step at this batch, in
+        bytes per device: arguments, outputs, donated aliases, temporaries.
+        A step holds argument + output - alias + temp. On a TPU
+        device.memory_stats() counts live arrays only — a running
+        program's temporaries (the activations) show up nowhere else.
+        Changes no state, but compiles the step a second time: jax keys
+        its compile caches on call-site metadata too, so this call never
+        hits the executable step() built."""
+        from ..framework.random import get_rng_state
+        batch_arrays = self._batch_arrays(batch)
+        with jax.set_mesh(self.mesh):
+            compiled = self._compiled.lower(
+                self.params, self.opt_state, batch_arrays,
+                get_rng_state()[0], jnp.asarray(self.step_count, jnp.int32),
+                jnp.asarray(self.optimizer.get_lr(), jnp.float32)).compile()
+        mem = compiled.memory_analysis()
+        return {k: int(getattr(mem, f"{k}_size_in_bytes"))
+                for k in ("argument", "output", "alias", "temp")}
+
+    def step(self, batch, rng_key=None):
+        """batch: (x, y) of Tensors or arrays. Returns float loss."""
+        batch_arrays = self._batch_arrays(batch)
         if rng_key is None:
             from ..framework.random import next_key
             rng_key = next_key()
@@ -267,8 +291,11 @@ class SpmdTrainer:
         # step/lr as device scalars so changing them never retraces
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         step = jnp.asarray(self.step_count, jnp.int32)
-        loss, self.params, self.opt_state = self._compiled(
-            self.params, self.opt_state, batch_arrays, rng_key, step, lr)
+        # the mesh is ambient while the step traces: code that GSPMD cannot
+        # partition (Pallas kernels) finds it there and shard_maps itself
+        with jax.set_mesh(self.mesh):
+            loss, self.params, self.opt_state = self._compiled(
+                self.params, self.opt_state, batch_arrays, rng_key, step, lr)
         return loss
 
     def sync_to_model(self):

@@ -266,8 +266,8 @@ class TestPackedGridDefault:
             _flags.set_flags({"FLAGS_flash_packed_grid": old})
 
     def test_shipped_ledger_keeps_hardware_packing_off(self):
-        # packed_grid_validated=False until .tpu_queue/451 proves it:
-        # the auto default must NOT enable packing on a real v5e yet
+        # packed_grid_validated=False until a chip run proves it (ROADMAP
+        # S4): the auto default must NOT enable packing on a real v5e yet
         led = ar.load_ledger()
         assert led["packed_grid_validated"] is False
 

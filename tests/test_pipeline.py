@@ -11,7 +11,7 @@ import paddle_tpu as paddle
 from paddle_tpu import optimizer
 from paddle_tpu.parallel.pipeline import (
     OneFOneBPipeline, PipelinedLM, ZeroBubblePipeline,
-    pipeline_forward_interleaved, shard_map)
+    pipeline_forward_interleaved)
 from paddle_tpu.parallel.llama_pipeline import LlamaPipeRunner
 from jax.sharding import PartitionSpec as P
 
@@ -257,7 +257,7 @@ class TestInterleavedPipeline:
                     stage_fn, cw_l, x_l, "pp", p_size=p, num_chunks=v,
                     remat=False)
                 return out[None]  # (1, M, mb, D): valid on last stage only
-            stacked = shard_map(
+            stacked = jax.shard_map(
                 inner, mesh=mesh,
                 in_specs=(P("pp"), P()), out_specs=P("pp"))(cw_, x_)
             return stacked[-1]
@@ -300,8 +300,8 @@ class TestInterleavedPipeline:
             def inner(cw_l, x_l):
                 return pipeline_forward_interleaved(
                     stage_fn, cw_l, x_l, "pp", p_size=p, num_chunks=v)[None]
-            shard_map(inner, mesh=mesh, in_specs=(P("pp"), P()),
-                      out_specs=P("pp"))(cw, x)
+            jax.shard_map(inner, mesh=mesh, in_specs=(P("pp"), P()),
+                          out_specs=P("pp"))(cw, x)
 
 
 class TestLlamaPipeline:

@@ -99,40 +99,48 @@ def _prefill_flash_routed(bh, s, d, dtype):
     return route(bh, s, s, d, dtype, True).fwd == "pallas"
 
 
+def _llama_mlp(lp, h, eps):
+    """h + MLP(norm(h)): the MLP sub-block of every Llama layer program,
+    under its component scope (observability/catalog.py TRACE_SCOPES)."""
+    with jax.named_scope("pt.mlp"):
+        x = _rms(h, lp["post_attention_layernorm.weight"], eps)
+        gate = x @ lp["mlp.gate_proj.weight"]
+        up = x @ lp["mlp.up_proj.weight"]
+        return h + (jax.nn.silu(gate) * up) @ lp["mlp.down_proj.weight"]
+
+
 def _llama_layer_prefill(lp, h, pos, cfg):
     """Full-sequence layer forward; returns (h_out, (k, v)) with k/v rotated
     and UNexpanded (kv heads)."""
     eps, theta = cfg["eps"], cfg["theta"]
     nh, nkv, hd = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
     b, s, _ = h.shape
-    x = _rms(h, lp["input_layernorm.weight"], eps)
-    q = (x @ lp["self_attn.q_proj.weight"]).reshape(b, s, nh, hd)
-    k = (x @ lp["self_attn.k_proj.weight"]).reshape(b, s, nkv, hd)
-    v = (x @ lp["self_attn.v_proj.weight"]).reshape(b, s, nkv, hd)
-    q = _rope(q, pos, theta)
-    k = _rope(k, pos, theta)
-    if _prefill_flash_routed(b * nh, s, hd, h.dtype):
-        # routed flash prefill: GQA-native (kv stays unexpanded), causal.
-        # Every prefill caller passes pos = arange rows, so the pos-based
-        # mask below IS the standard causal structure the kernel applies.
-        from .ops.pallas.flash_attention import flash_attention_bshd
-        attn = flash_attention_bshd(q, k, v, causal=True).reshape(
-            b, s, nh * hd)
-    else:
-        kx, vx = _gqa(k, nh // nkv), _gqa(v, nh // nkv)
-        scores = jnp.einsum(
-            "bqhd,bkhd->bhqk", q, kx,
-            preferred_element_type=jnp.float32) / (hd ** 0.5)
-        causal = pos[:, :, None] >= pos[:, None, :]       # (b, s, s)
-        scores = jnp.where(causal[:, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(vx.dtype)
-        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vx).reshape(
-            b, s, nh * hd)
-    h = h + attn @ lp["self_attn.o_proj.weight"]
-    x = _rms(h, lp["post_attention_layernorm.weight"], eps)
-    gate = x @ lp["mlp.gate_proj.weight"]
-    up = x @ lp["mlp.up_proj.weight"]
-    h = h + (jax.nn.silu(gate) * up) @ lp["mlp.down_proj.weight"]
+    with jax.named_scope("pt.attn"):
+        x = _rms(h, lp["input_layernorm.weight"], eps)
+        q = (x @ lp["self_attn.q_proj.weight"]).reshape(b, s, nh, hd)
+        k = (x @ lp["self_attn.k_proj.weight"]).reshape(b, s, nkv, hd)
+        v = (x @ lp["self_attn.v_proj.weight"]).reshape(b, s, nkv, hd)
+        q = _rope(q, pos, theta)
+        k = _rope(k, pos, theta)
+        if _prefill_flash_routed(b * nh, s, hd, h.dtype):
+            # routed flash prefill: GQA-native (kv stays unexpanded), causal.
+            # Every prefill caller passes pos = arange rows, so the pos-based
+            # mask below IS the standard causal structure the kernel applies.
+            from .ops.pallas.flash_attention import flash_attention_bshd
+            attn = flash_attention_bshd(q, k, v, causal=True).reshape(
+                b, s, nh * hd)
+        else:
+            kx, vx = _gqa(k, nh // nkv), _gqa(v, nh // nkv)
+            scores = jnp.einsum(
+                "bqhd,bkhd->bhqk", q, kx,
+                preferred_element_type=jnp.float32) / (hd ** 0.5)
+            causal = pos[:, :, None] >= pos[:, None, :]       # (b, s, s)
+            scores = jnp.where(causal[:, None], scores, -1e30)
+            probs = jax.nn.softmax(scores, axis=-1).astype(vx.dtype)
+            attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vx).reshape(
+                b, s, nh * hd)
+        h = h + attn @ lp["self_attn.o_proj.weight"]
+    h = _llama_mlp(lp, h, eps)
     return h, (k, v)
 
 
@@ -168,38 +176,36 @@ def _llama_layer_prefill_chunk(lp, h, kc, vc, table_row, start, cfg,
     nh, nkv, hd = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
     b, c, _ = h.shape                      # b == 1: one admission at a time
     pos = start + jnp.arange(c)[None]      # (1, C) absolute positions
-    x = _rms(h, lp["input_layernorm.weight"], eps)
-    q_lin = x @ lp["self_attn.q_proj.weight"]
-    v_lin = x @ lp["self_attn.v_proj.weight"]
-    if lora is not None:
-        a_q, b_q, a_v, b_v = lora
-        q_lin = q_lin + jnp.einsum("bch,hr,rd->bcd", x,
-                                   a_q.astype(x.dtype),
-                                   b_q.astype(x.dtype))
-        v_lin = v_lin + jnp.einsum("bch,hr,rd->bcd", x,
-                                   a_v.astype(x.dtype),
-                                   b_v.astype(x.dtype))
-    q = q_lin.reshape(b, c, nh, hd)
-    k = (x @ lp["self_attn.k_proj.weight"]).reshape(b, c, nkv, hd)
-    v = v_lin.reshape(b, c, nkv, hd)
-    q = _rope(q, pos, theta)
-    k = _rope(k, pos, theta)
-    quant = fmt is not None and fmt.quantized
-    if quant:
-        kc, vc, kc_scale, vc_scale = kv_write_chunk(
-            fmt, kc, vc, kc_scale, vc_scale, k[0], v[0], table_row, start)
-    else:
-        kc, vc = write_chunk_to_cache(kc, vc, k[0], v[0], table_row, start)
-    attn = paged_attention_prefill_chunk(q[0], kc, vc, table_row, start,
-                                         scale=1.0 / (hd ** 0.5),
-                                         fmt=fmt if quant else None,
-                                         k_scale_cache=kc_scale,
-                                         v_scale_cache=vc_scale)
-    h = h + attn.reshape(b, c, nh * hd) @ lp["self_attn.o_proj.weight"]
-    x = _rms(h, lp["post_attention_layernorm.weight"], eps)
-    gate = x @ lp["mlp.gate_proj.weight"]
-    up = x @ lp["mlp.up_proj.weight"]
-    h = h + (jax.nn.silu(gate) * up) @ lp["mlp.down_proj.weight"]
+    with jax.named_scope("pt.attn"):
+        x = _rms(h, lp["input_layernorm.weight"], eps)
+        q_lin = x @ lp["self_attn.q_proj.weight"]
+        v_lin = x @ lp["self_attn.v_proj.weight"]
+        if lora is not None:
+            a_q, b_q, a_v, b_v = lora
+            q_lin = q_lin + jnp.einsum("bch,hr,rd->bcd", x,
+                                       a_q.astype(x.dtype),
+                                       b_q.astype(x.dtype))
+            v_lin = v_lin + jnp.einsum("bch,hr,rd->bcd", x,
+                                       a_v.astype(x.dtype),
+                                       b_v.astype(x.dtype))
+        q = q_lin.reshape(b, c, nh, hd)
+        k = (x @ lp["self_attn.k_proj.weight"]).reshape(b, c, nkv, hd)
+        v = v_lin.reshape(b, c, nkv, hd)
+        q = _rope(q, pos, theta)
+        k = _rope(k, pos, theta)
+        quant = fmt is not None and fmt.quantized
+        if quant:
+            kc, vc, kc_scale, vc_scale = kv_write_chunk(
+                fmt, kc, vc, kc_scale, vc_scale, k[0], v[0], table_row, start)
+        else:
+            kc, vc = write_chunk_to_cache(kc, vc, k[0], v[0], table_row, start)
+        attn = paged_attention_prefill_chunk(q[0], kc, vc, table_row, start,
+                                             scale=1.0 / (hd ** 0.5),
+                                             fmt=fmt if quant else None,
+                                             k_scale_cache=kc_scale,
+                                             v_scale_cache=vc_scale)
+        h = h + attn.reshape(b, c, nh * hd) @ lp["self_attn.o_proj.weight"]
+    h = _llama_mlp(lp, h, eps)
     if quant:
         return h, (kc, vc, kc_scale, vc_scale)
     return h, (kc, vc)
@@ -212,28 +218,26 @@ def _llama_layer_decode(lp, h, k_cache, v_cache, t, cfg):
     nh, nkv, hd = cfg["heads"], cfg["kv_heads"], cfg["head_dim"]
     b = h.shape[0]
     T = k_cache.shape[1]
-    x = _rms(h, lp["input_layernorm.weight"], eps)
-    q = (x @ lp["self_attn.q_proj.weight"]).reshape(b, 1, nh, hd)
-    k = (x @ lp["self_attn.k_proj.weight"]).reshape(b, 1, nkv, hd)
-    v = (x @ lp["self_attn.v_proj.weight"]).reshape(b, 1, nkv, hd)
-    pos = jnp.full((b, 1), t, jnp.int32)
-    q = _rope(q, pos, theta)
-    k = _rope(k, pos, theta)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, t, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, t, axis=1)
-    kx = _gqa(k_cache, nh // nkv)
-    vx = _gqa(v_cache, nh // nkv)
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, kx,
-                        preferred_element_type=jnp.float32) / (hd ** 0.5)
-    valid = (jnp.arange(T) <= t)[None, None, None, :]
-    scores = jnp.where(valid, scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(vx.dtype)
-    attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vx).reshape(b, 1, nh * hd)
-    h = h + attn @ lp["self_attn.o_proj.weight"]
-    x = _rms(h, lp["post_attention_layernorm.weight"], eps)
-    gate = x @ lp["mlp.gate_proj.weight"]
-    up = x @ lp["mlp.up_proj.weight"]
-    h = h + (jax.nn.silu(gate) * up) @ lp["mlp.down_proj.weight"]
+    with jax.named_scope("pt.attn"):
+        x = _rms(h, lp["input_layernorm.weight"], eps)
+        q = (x @ lp["self_attn.q_proj.weight"]).reshape(b, 1, nh, hd)
+        k = (x @ lp["self_attn.k_proj.weight"]).reshape(b, 1, nkv, hd)
+        v = (x @ lp["self_attn.v_proj.weight"]).reshape(b, 1, nkv, hd)
+        pos = jnp.full((b, 1), t, jnp.int32)
+        q = _rope(q, pos, theta)
+        k = _rope(k, pos, theta)
+        k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k, t, axis=1)
+        v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v, t, axis=1)
+        kx = _gqa(k_cache, nh // nkv)
+        vx = _gqa(v_cache, nh // nkv)
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, kx,
+                            preferred_element_type=jnp.float32) / (hd ** 0.5)
+        valid = (jnp.arange(T) <= t)[None, None, None, :]
+        scores = jnp.where(valid, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(vx.dtype)
+        attn = jnp.einsum("bhqk,bkhd->bqhd", probs, vx).reshape(b, 1, nh * hd)
+        h = h + attn @ lp["self_attn.o_proj.weight"]
+    h = _llama_mlp(lp, h, eps)
     return h, k_cache, v_cache
 
 

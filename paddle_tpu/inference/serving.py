@@ -47,7 +47,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..generation import _llama_layer_prefill_chunk, _rms, _rope
+from ..generation import (_llama_layer_prefill_chunk, _llama_mlp, _rms,
+                          _rope)
 from .adapters import AdapterLoadError
 from ..observability import span as _span
 from ..observability.catalog import metric as _metric
@@ -2124,7 +2125,8 @@ class ContinuousBatchingEngine:
                 kspool, vspool, ids, start, last_idx, table_row = rest
             else:
                 ids, start, last_idx, table_row = rest
-            h = jnp.take(embed_w, ids, axis=0)       # (1, C, H)
+            with jax.named_scope("pt.embed"):
+                h = jnp.take(embed_w, ids, axis=0)       # (1, C, H)
 
             def layer(hh, xs):
                 if lora:
@@ -2152,8 +2154,9 @@ class ContinuousBatchingEngine:
                 xs = xs + (aq_p, bq_p, av_p, bv_p)
             h, pools = jax.lax.scan(layer, h, xs)
             h_last = h[0, last_idx]     # dynamic index: traced position
-            logits = (_rms(h_last, norm_w, cfg["eps"]) @ head_w).astype(
-                jnp.float32)
+            with jax.named_scope("pt.head"):
+                logits = (_rms(h_last, norm_w, cfg["eps"]) @ head_w
+                          ).astype(jnp.float32)
             return (logits,) + tuple(pools)
 
         return run
@@ -2194,7 +2197,8 @@ class ContinuousBatchingEngine:
                 else:
                     toks, lens, alive, rem, kpool, vpool = carry
                     kspool = vspool = None
-                h = jnp.take(embed_w, toks[:, None], axis=0)  # (B, 1, H)
+                with jax.named_scope("pt.embed"):
+                    h = jnp.take(embed_w, toks[:, None], axis=0)  # (B, 1, H)
                 pos = lens[:, None]                            # write pos
 
                 def layer(hh, xs):
@@ -2206,50 +2210,46 @@ class ContinuousBatchingEngine:
                     else:
                         lp, kc, vc = xs
                         ks = vs = None
-                    x = _rms(hh, lp["input_layernorm.weight"], eps)
-                    q_lin = x @ lp["self_attn.q_proj.weight"]
-                    v_lin = x @ lp["self_attn.v_proj.weight"]
-                    if lora:
-                        # per-lane batched low-rank delta: gather each
-                        # lane's (A, B) factors by slot id, one einsum
-                        # over the whole tile. Slot 0 is all-zeros, so
-                        # base lanes add exactly 0.
-                        aq = jnp.take(aq_l, aids, axis=0)   # (B, H, r)
-                        bq = jnp.take(bq_l, aids, axis=0)   # (B, r, Dq)
-                        q_lin = q_lin + jnp.einsum(
-                            "bch,bhr,brd->bcd", x,
-                            aq.astype(x.dtype), bq.astype(x.dtype))
-                        av = jnp.take(av_l, aids, axis=0)
-                        bv = jnp.take(bv_l, aids, axis=0)
-                        v_lin = v_lin + jnp.einsum(
-                            "bch,bhr,brd->bcd", x,
-                            av.astype(x.dtype), bv.astype(x.dtype))
-                    q = q_lin.reshape(B, 1, nh, hd)
-                    k = (x @ lp["self_attn.k_proj.weight"]
-                         ).reshape(B, 1, nkv, hd)
-                    v = v_lin.reshape(B, 1, nkv, hd)
-                    q = _rope(q, pos, theta)[:, 0]
-                    k = _rope(k, pos, theta)[:, 0]
-                    v = v[:, 0]
-                    # passthrough formats route through write_to_cache
-                    # with the exact pre-round-11 ops (byte-identical
-                    # trace); quantized formats also update the scales
-                    kc, vc, ks, vs = kv_write_token(
-                        fmt if quant else None, kc, vc, ks, vs, k, v,
-                        tables, lens, active=alive, scratch_block=scratch)
-                    attn = paged_attention_decode_inner(
-                        q, kc, vc, tables, lens + 1,
-                        scale=1.0 / (hd ** 0.5),
-                        fmt=fmt if quant else None,
-                        k_scale_cache=ks, v_scale_cache=vs)
-                    hh = hh + (attn.reshape(B, 1, nh * hd)
-                               @ lp["self_attn.o_proj.weight"])
-                    x = _rms(hh, lp["post_attention_layernorm.weight"],
-                             eps)
-                    gate = x @ lp["mlp.gate_proj.weight"]
-                    up = x @ lp["mlp.up_proj.weight"]
-                    hh = hh + ((jax.nn.silu(gate) * up)
-                               @ lp["mlp.down_proj.weight"])
+                    with jax.named_scope("pt.attn"):
+                        x = _rms(hh, lp["input_layernorm.weight"], eps)
+                        q_lin = x @ lp["self_attn.q_proj.weight"]
+                        v_lin = x @ lp["self_attn.v_proj.weight"]
+                        if lora:
+                            # per-lane batched low-rank delta: gather each
+                            # lane's (A, B) factors by slot id, one einsum
+                            # over the whole tile. Slot 0 is all-zeros, so
+                            # base lanes add exactly 0.
+                            aq = jnp.take(aq_l, aids, axis=0)   # (B, H, r)
+                            bq = jnp.take(bq_l, aids, axis=0)   # (B, r, Dq)
+                            q_lin = q_lin + jnp.einsum(
+                                "bch,bhr,brd->bcd", x,
+                                aq.astype(x.dtype), bq.astype(x.dtype))
+                            av = jnp.take(av_l, aids, axis=0)
+                            bv = jnp.take(bv_l, aids, axis=0)
+                            v_lin = v_lin + jnp.einsum(
+                                "bch,bhr,brd->bcd", x,
+                                av.astype(x.dtype), bv.astype(x.dtype))
+                        q = q_lin.reshape(B, 1, nh, hd)
+                        k = (x @ lp["self_attn.k_proj.weight"]
+                             ).reshape(B, 1, nkv, hd)
+                        v = v_lin.reshape(B, 1, nkv, hd)
+                        q = _rope(q, pos, theta)[:, 0]
+                        k = _rope(k, pos, theta)[:, 0]
+                        v = v[:, 0]
+                        # passthrough formats route through write_to_cache
+                        # with the exact pre-round-11 ops (byte-identical
+                        # trace); quantized formats also update the scales
+                        kc, vc, ks, vs = kv_write_token(
+                            fmt if quant else None, kc, vc, ks, vs, k, v,
+                            tables, lens, active=alive, scratch_block=scratch)
+                        attn = paged_attention_decode_inner(
+                            q, kc, vc, tables, lens + 1,
+                            scale=1.0 / (hd ** 0.5),
+                            fmt=fmt if quant else None,
+                            k_scale_cache=ks, v_scale_cache=vs)
+                        hh = hh + (attn.reshape(B, 1, nh * hd)
+                                   @ lp["self_attn.o_proj.weight"])
+                    hh = _llama_mlp(lp, hh, eps)
                     return hh, ((kc, vc, ks, vs) if quant else (kc, vc))
 
                 xs = ((stacked, kpool, vpool, kspool, vspool) if quant
@@ -2261,13 +2261,15 @@ class ContinuousBatchingEngine:
                     kpool, vpool, kspool, vspool = pools
                 else:
                     kpool, vpool = pools
-                logits = (_rms(h[:, 0], norm_w, eps) @ head_w).astype(
-                    jnp.float32)
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                if sampled:
-                    samp = _device_sample(logits, seeds, lens, temp,
-                                          top_k, top_p)
-                    nxt = jnp.where(do_sample, samp, nxt)
+                with jax.named_scope("pt.head"):
+                    logits = (_rms(h[:, 0], norm_w, eps) @ head_w).astype(
+                        jnp.float32)
+                with jax.named_scope("pt.serve.sample"):
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    if sampled:
+                        samp = _device_sample(logits, seeds, lens, temp,
+                                              top_k, top_p)
+                        nxt = jnp.where(do_sample, samp, nxt)
                 # frozen lanes re-emit their last token (never credited:
                 # the host walk stops at the same eos/length boundary)
                 nxt = jnp.where(alive, nxt, toks)
@@ -2354,7 +2356,8 @@ class ContinuousBatchingEngine:
                                  lens[:, None] + 1 + jnp.arange(D)[None, :],
                                  hmax)
                 hist = hist.at[rows[:, None], didx].set(drafts)
-                h = jnp.take(embed_w, u, axis=0)               # (B, C, H)
+                with jax.named_scope("pt.embed"):
+                    h = jnp.take(embed_w, u, axis=0)           # (B, C, H)
                 pos = lens[:, None] + jnp.arange(C)[None, :]   # (B, C)
 
                 def layer(hh, xs):
@@ -2366,47 +2369,43 @@ class ContinuousBatchingEngine:
                     else:
                         lp, kc, vc = xs
                         ks = vs = None
-                    x = _rms(hh, lp["input_layernorm.weight"], eps)
-                    q_lin = x @ lp["self_attn.q_proj.weight"]
-                    v_lin = x @ lp["self_attn.v_proj.weight"]
-                    if lora:
-                        # x is (B, C, H) here — the same batched einsum
-                        # covers all C verify positions of every lane
-                        aq = jnp.take(aq_l, aids, axis=0)
-                        bq = jnp.take(bq_l, aids, axis=0)
-                        q_lin = q_lin + jnp.einsum(
-                            "bch,bhr,brd->bcd", x,
-                            aq.astype(x.dtype), bq.astype(x.dtype))
-                        av = jnp.take(av_l, aids, axis=0)
-                        bv = jnp.take(bv_l, aids, axis=0)
-                        v_lin = v_lin + jnp.einsum(
-                            "bch,bhr,brd->bcd", x,
-                            av.astype(x.dtype), bv.astype(x.dtype))
-                    q = q_lin.reshape(B, C, nh, hd)
-                    k = (x @ lp["self_attn.k_proj.weight"]
-                         ).reshape(B, C, nkv, hd)
-                    v = v_lin.reshape(B, C, nkv, hd)
-                    q = _rope(q, pos, theta)
-                    k = _rope(k, pos, theta)
-                    # kv.write effect scope (stamped inside the callee):
-                    # the verify-write must stay ordered before the
-                    # rollback below — the PIR effect-order rule rejects
-                    # any pass that migrates one past the other
-                    kc, vc, ks, vs, saved = kv_write_tokens(
-                        fmt if quant else None, kc, vc, ks, vs, k, v,
-                        tables, lens, active=alive, scratch_block=scratch)
-                    attn = paged_attention_verify(
-                        q, kc, vc, tables, lens, scale=1.0 / (hd ** 0.5),
-                        fmt=fmt if quant else None,
-                        k_scale_cache=ks, v_scale_cache=vs)
-                    hh = hh + (attn.reshape(B, C, nh * hd)
-                               @ lp["self_attn.o_proj.weight"])
-                    x = _rms(hh, lp["post_attention_layernorm.weight"],
-                             eps)
-                    gate = x @ lp["mlp.gate_proj.weight"]
-                    up = x @ lp["mlp.up_proj.weight"]
-                    hh = hh + ((jax.nn.silu(gate) * up)
-                               @ lp["mlp.down_proj.weight"])
+                    with jax.named_scope("pt.attn"):
+                        x = _rms(hh, lp["input_layernorm.weight"], eps)
+                        q_lin = x @ lp["self_attn.q_proj.weight"]
+                        v_lin = x @ lp["self_attn.v_proj.weight"]
+                        if lora:
+                            # x is (B, C, H) here — the same batched einsum
+                            # covers all C verify positions of every lane
+                            aq = jnp.take(aq_l, aids, axis=0)
+                            bq = jnp.take(bq_l, aids, axis=0)
+                            q_lin = q_lin + jnp.einsum(
+                                "bch,bhr,brd->bcd", x,
+                                aq.astype(x.dtype), bq.astype(x.dtype))
+                            av = jnp.take(av_l, aids, axis=0)
+                            bv = jnp.take(bv_l, aids, axis=0)
+                            v_lin = v_lin + jnp.einsum(
+                                "bch,bhr,brd->bcd", x,
+                                av.astype(x.dtype), bv.astype(x.dtype))
+                        q = q_lin.reshape(B, C, nh, hd)
+                        k = (x @ lp["self_attn.k_proj.weight"]
+                             ).reshape(B, C, nkv, hd)
+                        v = v_lin.reshape(B, C, nkv, hd)
+                        q = _rope(q, pos, theta)
+                        k = _rope(k, pos, theta)
+                        # kv.write effect scope (stamped inside the callee):
+                        # the verify-write must stay ordered before the
+                        # rollback below — the PIR effect-order rule rejects
+                        # any pass that migrates one past the other
+                        kc, vc, ks, vs, saved = kv_write_tokens(
+                            fmt if quant else None, kc, vc, ks, vs, k, v,
+                            tables, lens, active=alive, scratch_block=scratch)
+                        attn = paged_attention_verify(
+                            q, kc, vc, tables, lens, scale=1.0 / (hd ** 0.5),
+                            fmt=fmt if quant else None,
+                            k_scale_cache=ks, v_scale_cache=vs)
+                        hh = hh + (attn.reshape(B, C, nh * hd)
+                                   @ lp["self_attn.o_proj.weight"])
+                    hh = _llama_mlp(lp, hh, eps)
                     out = (kc, vc, ks, vs) if quant else (kc, vc)
                     return hh, (out, saved)
 
@@ -2415,18 +2414,20 @@ class ContinuousBatchingEngine:
                 if lora:
                     xs = xs + (aq_p, bq_p, av_p, bv_p)
                 h, (pools, saved) = jax.lax.scan(layer, h, xs)
-                logits = (_rms(h, norm_w, eps) @ head_w).astype(
-                    jnp.float32)                               # (B, C, V)
+                with jax.named_scope("pt.head"):
+                    logits = (_rms(h, norm_w, eps) @ head_w).astype(
+                        jnp.float32)                           # (B, C, V)
                 # g[:, i] is the token the sequential policy emits at
                 # position lens+i+1 GIVEN the drafts up to i were right —
                 # so the committed tokens are exactly a prefix of g
-                g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                if sampled:
-                    samp = jnp.stack(
-                        [_device_sample(logits[:, i], seeds, lens + i,
-                                        temp, top_k, top_p)
-                         for i in range(C)], axis=1)
-                    g = jnp.where(do_sample[:, None], samp, g)
+                with jax.named_scope("pt.serve.sample"):
+                    g = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    if sampled:
+                        samp = jnp.stack(
+                            [_device_sample(logits[:, i], seeds, lens + i,
+                                            temp, top_k, top_p)
+                             for i in range(C)], axis=1)
+                        g = jnp.where(do_sample[:, None], samp, g)
                 # leading-run acceptance; +1 = the correction token
                 matches = (drafts == g[:, :D]).astype(jnp.int32)
                 n_acc = jnp.cumprod(matches, axis=1).sum(axis=1)

@@ -7,6 +7,8 @@ scaled_dot_product_attention path (Pallas on TPU).
 
 from __future__ import annotations
 
+import jax
+
 from .. import nn
 from ..nn import functional as F
 from ..tensor.manipulation import reshape
@@ -75,9 +77,13 @@ class GPTBlock(nn.Layer):
         self.dropout = nn.Dropout(config.dropout)
 
     def forward(self, x):
-        x = x + self.attn(self.ln_1(x))
-        h = self.fc2(F.gelu(self.fc1(self.ln_2(x))))
-        return x + self.dropout(h)
+        # component scopes (observability/catalog.py TRACE_SCOPES): the
+        # names a device trace attributes this block's operations to
+        with jax.named_scope("pt.attn"):
+            x = x + self.attn(self.ln_1(x))
+        with jax.named_scope("pt.mlp"):
+            h = self.fc2(F.gelu(self.fc1(self.ln_2(x))))
+            return x + self.dropout(h)
 
 
 class GPTModel(nn.Layer):
@@ -98,10 +104,12 @@ class GPTModel(nn.Layer):
             import jax.numpy as jnp
             from ..framework.core import Tensor
             position_ids = Tensor(jnp.arange(input_ids.shape[1])[None, :])
-        x = self.wte(input_ids) + self.wpe(position_ids)
+        with jax.named_scope("pt.embed"):
+            x = self.wte(input_ids) + self.wpe(position_ids)
         for block in self.h:
             x = block(x)
-        return self.ln_f(x)
+        with jax.named_scope("pt.head"):
+            return self.ln_f(x)
 
 
 class GPTForCausalLM(nn.Layer):
@@ -118,13 +126,15 @@ class GPTForCausalLM(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, labels=None):
         hidden = self.gpt(input_ids, position_ids)
-        if self.lm_head is not None:
-            logits = self.lm_head(hidden)
-        else:
-            logits = F.linear(hidden, self.gpt.wte.weight.T)
+        with jax.named_scope("pt.head"):
+            if self.lm_head is not None:
+                logits = self.lm_head(hidden)
+            else:
+                logits = F.linear(hidden, self.gpt.wte.weight.T)
         if labels is not None:
             # next-token LM loss: predict labels[t+1] from logits[t]
-            loss = F.cross_entropy(logits[:, :-1], labels[:, 1:])
+            with jax.named_scope("pt.loss"):
+                loss = F.cross_entropy(logits[:, :-1], labels[:, 1:])
             return loss, logits
         return logits
 

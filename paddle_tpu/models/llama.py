@@ -23,6 +23,8 @@ TPU-first design decisions:
 
 from __future__ import annotations
 
+import jax
+
 from .. import nn
 from ..incubate.nn.functional import fused_rotary_position_embedding, swiglu
 from ..nn import functional as F
@@ -118,13 +120,16 @@ class LlamaDecoderLayer(nn.Layer):
 
     def forward(self, hidden, position_ids=None, attn_mask=None, cache=None):
         residual = hidden
-        h = self.input_layernorm(hidden)
-        attn = self.self_attn(h, position_ids, attn_mask, cache)
-        if cache is not None:
-            attn, cache = attn
-        hidden = residual + attn
+        with jax.named_scope("pt.attn"):
+            h = self.input_layernorm(hidden)
+            attn = self.self_attn(h, position_ids, attn_mask, cache)
+            if cache is not None:
+                attn, cache = attn
+            hidden = residual + attn
         residual = hidden
-        hidden = residual + self.mlp(self.post_attention_layernorm(hidden))
+        with jax.named_scope("pt.mlp"):
+            hidden = residual + self.mlp(
+                self.post_attention_layernorm(hidden))
         if cache is not None:
             return hidden, cache
         return hidden
@@ -140,10 +145,12 @@ class LlamaModel(nn.Layer):
         self.norm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
 
     def forward(self, input_ids, position_ids=None, attn_mask=None):
-        hidden = self.embed_tokens(input_ids)
+        with jax.named_scope("pt.embed"):
+            hidden = self.embed_tokens(input_ids)
         for layer in self.layers:
             hidden = layer(hidden, position_ids, attn_mask)
-        return self.norm(hidden)
+        with jax.named_scope("pt.head"):
+            return self.norm(hidden)
 
 
 class LlamaForCausalLM(nn.Layer):
@@ -159,14 +166,16 @@ class LlamaForCausalLM(nn.Layer):
 
     def forward(self, input_ids, position_ids=None, labels=None):
         hidden = self.llama(input_ids, position_ids)
-        if self.lm_head is not None:
-            logits = self.lm_head(hidden)
-        else:
-            logits = F.linear(hidden, self.llama.embed_tokens.weight.T)
+        with jax.named_scope("pt.head"):
+            if self.lm_head is not None:
+                logits = self.lm_head(hidden)
+            else:
+                logits = F.linear(hidden, self.llama.embed_tokens.weight.T)
         if labels is not None:
             # next-token LM loss: predict labels[t+1] from logits[t]
-            loss = F.cross_entropy(logits[:, :-1], labels[:, 1:],
-                                   reduction="mean")
+            with jax.named_scope("pt.loss"):
+                loss = F.cross_entropy(logits[:, :-1], labels[:, 1:],
+                                       reduction="mean")
             return loss, logits
         return logits
 

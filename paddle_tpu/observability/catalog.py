@@ -13,7 +13,8 @@ from __future__ import annotations
 
 from . import metrics as _metrics
 
-__all__ = ["CATALOG", "metric", "register_all"]
+__all__ = ["CATALOG", "TRACE_SCOPES", "KERNEL_NAMES", "metric",
+           "register_all"]
 
 # latency bucket families (seconds)
 _TTFT_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
@@ -499,6 +500,38 @@ CATALOG = {
         "counter", "observability-plane failures that flipped a sampler or "
         "collector to degraded (plane off, serving untouched), by failure "
         "class (obs.sample fault site)", ("what",), None),
+}
+
+# The closed set of component scopes (jax.named_scope) on device
+# operations: what a profiler trace and the lowered program call the
+# part of the model a fusion or kernel belongs to. Entered where the
+# work is built; backward and rematerialised operations keep the name
+# inside transpose(jvp(...)) / checkpoint, and PIR replay restores it
+# (pir/ir.py Operation.evaluate). tools/static_check.py --rule
+# trace-scopes pins every named_scope("pt. ...") literal to this dict
+# and every row to OBSERVABILITY.md, both directions. No component may
+# equal an entry of pir/verifier.py EFFECT_SCOPES.
+TRACE_SCOPES = {
+    "pt.embed": "token (+ position) embedding lookup",
+    "pt.attn": "attention sub-block: norm, QKV projections, rope, the "
+               "attention kernel or paged attention, output projection",
+    "pt.mlp": "MLP sub-block with its norm",
+    "pt.head": "final norm + LM head matmul",
+    "pt.loss": "cross entropy over the vocabulary",
+    "pt.opt": "gradient clipping + optimizer update of the train step",
+    "pt.serve.gather": "paged attention: block-table gather of K/V "
+                       "(+ dequantisation) out of the pool",
+    "pt.serve.attend": "paged attention: scores, mask, softmax, PV",
+    "pt.serve.sample": "on-device argmax / categorical sampling in the "
+                       "decode scan",
+}
+
+# `name=` of the Pallas kernels (ops/pallas/flash_attention.py): the
+# Mosaic kernel name and the innermost scope of the call on the trace.
+KERNEL_NAMES = {
+    "fa_fwd": "flash attention forward (+ fused RMS epilogue)",
+    "fa_bwd_dq": "flash attention backward, dQ",
+    "fa_bwd_dkv": "flash attention backward, dK and dV",
 }
 
 

@@ -9,21 +9,35 @@ STANDALONE like metrics.py: stdlib only, loadable outside the package.
 
 Two entry points:
   - `span(name, **args)` — the gated context manager the hot paths use;
-    when tracing is disabled it returns a shared no-op (no allocation).
+    with tracing disabled and no profiler session running it returns a
+    shared no-op (no allocation).
   - `Tracer.begin/end` — ungated; profiler.RecordEvent uses these so its
     spans are ALWAYS recorded (pre-existing profiler contract).
+
+On the device trace's clock: while a jax profiler session runs
+(`jax.profiler.start_trace` / profiler.Profiler), begin/end also enter
+and leave a `jax.profiler.TraceAnnotation(name, **metadata)`, so every
+span lands on the host plane of the device trace with its args (`rid`,
+`lane`, ...) and `trace_id` as the event's stats and its parent by
+containment. `span()` is live when the tracer is enabled OR a session
+runs; a span opened only because a session runs is an annotation alone
+(the ring follows the tracer's own switch). jax is never imported from
+here: a process that has not imported jax has no session. Retroactive
+`add_span` spans are already over when they are recorded, so they stay
+in the ring only.
 """
 
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
 __all__ = ["Span", "Tracer", "get_tracer", "span", "trace",
            "enable", "disable", "enabled", "new_trace_id",
-           "LANE_TID_BASE"]
+           "session_annotation", "LANE_TID_BASE"]
 
 # bound the in-memory buffer: long-running serving processes must not
 # grow without limit. The ring IS the bound — when it wraps, the oldest
@@ -51,9 +65,36 @@ def new_trace_id(prefix="t"):
     return f"{prefix}{os.getpid():x}-{n:06x}"
 
 
+_ANNOTATION = None      # jax.profiler.TraceAnnotation once jax is loaded
+
+
+def session_annotation():
+    """jax.profiler.TraceAnnotation while a profiler session is running,
+    else None. One dict lookup until the process has imported jax, then
+    one read of a static C++ flag."""
+    global _ANNOTATION
+    cls = _ANNOTATION
+    if cls is None:
+        jax = sys.modules.get("jax")
+        cls = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+        if cls is None:
+            return None
+        _ANNOTATION = cls
+    return cls if cls.is_enabled() else None
+
+
+def _metadata(args, trace_id):
+    """Span args as TraceAnnotation stats: numbers and strings as they
+    are, anything else by its str()."""
+    meta = {} if trace_id is None else {"trace_id": trace_id}
+    for k, v in (args or {}).items():
+        meta[k] = v if isinstance(v, (int, float, str)) else str(v)
+    return meta
+
+
 class Span:
     __slots__ = ("name", "t0_ns", "dur_ns", "tid", "seq", "parent", "args",
-                 "trace_id", "links")
+                 "trace_id", "links", "ann")
 
     def __init__(self, name, t0_ns, tid, seq, parent=None, args=None,
                  trace_id=None, links=None):
@@ -66,6 +107,7 @@ class Span:
         self.args = args
         self.trace_id = trace_id    # request-scoped correlation id
         self.links = links          # trace/span ids this span links to
+        self.ann = None             # open TraceAnnotation (profiler session)
 
 
 class _Noop:
@@ -126,9 +168,16 @@ class Tracer:
         if trace_id is None and stack and stack[-1].trace_id is not None:
             sp.trace_id = stack[-1].trace_id    # inherit down the tree
         stack.append(sp)
+        cls = session_annotation()
+        if cls is not None:
+            sp.ann = cls(name, **_metadata(args, sp.trace_id))
+            sp.ann.__enter__()
         return sp
 
     def end(self, sp: Span):
+        if sp.ann is not None:
+            sp.ann.__exit__(None, None, None)
+            sp.ann = None
         sp.dur_ns = time.perf_counter_ns() - sp.t0_ns
         stack = self._stack()
         # tolerate mispaired ends (a crashed child left on the stack)
@@ -175,7 +224,12 @@ class Tracer:
     # -- gated context manager / decorator ----------------------------------
     def span(self, name, **args):
         if not self._state_enabled:
-            return _NOOP
+            cls = session_annotation()
+            if cls is None:
+                return _NOOP
+            # a session runs and the tracer is off: the annotation alone
+            trace_id = args.pop("trace_id", None)
+            return cls(name, **_metadata(args, trace_id))
         trace_id = args.pop("trace_id", None)
         return _SpanCtx(self, name, args or None, trace_id)
 
@@ -185,13 +239,8 @@ class Tracer:
             label = name or fn.__qualname__
 
             def inner(*a, **kw):
-                if not self._state_enabled:
+                with self.span(label):
                     return fn(*a, **kw)
-                sp = self.begin(label)
-                try:
-                    return fn(*a, **kw)
-                finally:
-                    self.end(sp)
             inner.__name__ = fn.__name__
             inner.__qualname__ = fn.__qualname__
             inner.__doc__ = fn.__doc__

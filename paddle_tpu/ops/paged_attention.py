@@ -314,21 +314,23 @@ def paged_attention_decode_inner(q, k_cache, v_cache, block_tables,
     dequant = fmt is not None and fmt.quantized
 
     def one(qb, table, n):
-        k = k_cache[table]                                    # [mb, bs, KVH, D]
-        v = v_cache[table]
-        if dequant:
-            k = fmt.decode(k, k_scale_cache[table])
-            v = fmt.decode(v, v_scale_cache[table])
-        k = k.reshape(L, KVH, D)
-        v = v.reshape(L, KVH, D)
-        qg = qb.reshape(KVH, groups, D)
-        # scores[kvh, g, l]
-        s = jnp.einsum("hgd,lhd->hgl", qg, k) * scale
-        mask = jnp.arange(L) < n
-        s = jnp.where(mask[None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("hgl,lhd->hgd", p, v)
-        return o.reshape(H, D)
+        with jax.named_scope("pt.serve.gather"):
+            k = k_cache[table]                               # [mb, bs, KVH, D]
+            v = v_cache[table]
+            if dequant:
+                k = fmt.decode(k, k_scale_cache[table])
+                v = fmt.decode(v, v_scale_cache[table])
+            k = k.reshape(L, KVH, D)
+            v = v.reshape(L, KVH, D)
+        with jax.named_scope("pt.serve.attend"):
+            qg = qb.reshape(KVH, groups, D)
+            # scores[kvh, g, l]
+            s = jnp.einsum("hgd,lhd->hgl", qg, k) * scale
+            mask = jnp.arange(L) < n
+            s = jnp.where(mask[None, None, :], s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            o = jnp.einsum("hgl,lhd->hgd", p, v)
+            return o.reshape(H, D)
 
     return jax.vmap(one)(q, block_tables, seq_lens)
 
@@ -370,22 +372,24 @@ def paged_attention_verify(q, k_cache, v_cache, block_tables, base_lens,
     dequant = fmt is not None and fmt.quantized
 
     def one(qb, table, n0):
-        k = k_cache[table]
-        v = v_cache[table]
-        if dequant:
-            k = fmt.decode(k, k_scale_cache[table])
-            v = fmt.decode(v, v_scale_cache[table])
-        k = k.reshape(L, KVH, D)
-        v = v.reshape(L, KVH, D)
-        qg = qb.reshape(C, KVH, groups, D)
-        s = jnp.einsum("chgd,lhd->chgl", qg, k,
-                       preferred_element_type=jnp.float32) * scale
-        pos_q = n0 + jnp.arange(C)
-        valid = jnp.arange(L)[None, :] <= pos_q[:, None]       # [C, L]
-        s = jnp.where(valid[:, None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        o = jnp.einsum("chgl,lhd->chgd", p, v)
-        return o.reshape(C, H, D)
+        with jax.named_scope("pt.serve.gather"):
+            k = k_cache[table]
+            v = v_cache[table]
+            if dequant:
+                k = fmt.decode(k, k_scale_cache[table])
+                v = fmt.decode(v, v_scale_cache[table])
+            k = k.reshape(L, KVH, D)
+            v = v.reshape(L, KVH, D)
+        with jax.named_scope("pt.serve.attend"):
+            qg = qb.reshape(C, KVH, groups, D)
+            s = jnp.einsum("chgd,lhd->chgl", qg, k,
+                           preferred_element_type=jnp.float32) * scale
+            pos_q = n0 + jnp.arange(C)
+            valid = jnp.arange(L)[None, :] <= pos_q[:, None]   # [C, L]
+            s = jnp.where(valid[:, None, None, :], s, -1e30)
+            p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+            o = jnp.einsum("chgl,lhd->chgd", p, v)
+            return o.reshape(C, H, D)
 
     return jax.vmap(one)(q, block_tables, base_lens)
 
@@ -409,22 +413,24 @@ def paged_attention_prefill_chunk(q, k_cache, v_cache, table_row, start,
     if scale is None:
         scale = 1.0 / math.sqrt(D)
     L = table_row.shape[0] * block_size
-    k = k_cache[table_row]
-    v = v_cache[table_row]
-    if fmt is not None and fmt.quantized:
-        k = fmt.decode(k, k_scale_cache[table_row])
-        v = fmt.decode(v, v_scale_cache[table_row])
-    k = k.reshape(L, KVH, D)
-    v = v.reshape(L, KVH, D)
-    qg = q.reshape(C, KVH, groups, D)
-    s = jnp.einsum("chgd,lhd->chgl", qg, k,
-                   preferred_element_type=jnp.float32) * scale
-    pos_q = start + jnp.arange(C)
-    valid = jnp.arange(L)[None, :] <= pos_q[:, None]          # [C, L]
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    o = jnp.einsum("chgl,lhd->chgd", p, v)
-    return o.reshape(C, H, D)
+    with jax.named_scope("pt.serve.gather"):
+        k = k_cache[table_row]
+        v = v_cache[table_row]
+        if fmt is not None and fmt.quantized:
+            k = fmt.decode(k, k_scale_cache[table_row])
+            v = fmt.decode(v, v_scale_cache[table_row])
+        k = k.reshape(L, KVH, D)
+        v = v.reshape(L, KVH, D)
+    with jax.named_scope("pt.serve.attend"):
+        qg = q.reshape(C, KVH, groups, D)
+        s = jnp.einsum("chgd,lhd->chgl", qg, k,
+                       preferred_element_type=jnp.float32) * scale
+        pos_q = start + jnp.arange(C)
+        valid = jnp.arange(L)[None, :] <= pos_q[:, None]      # [C, L]
+        s = jnp.where(valid[:, None, None, :], s, -1e30)
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        o = jnp.einsum("chgl,lhd->chgd", p, v)
+        return o.reshape(C, H, D)
 
 
 class BlockKVCacheManager:

@@ -521,6 +521,7 @@ def _flash_fwd_bhsd(q, k, v, causal, scale, block_q=128, block_k=128,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="fa_fwd",
     )(*operands)
     # collapse the lane-expanded lse back to (bh, sq_p) right away so the
     # autodiff residual is O(S), not O(S * 128)
@@ -597,6 +598,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, g, causal, scale, block_q=128,
         out_shape=_sds((bh, sq_p, d), q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="fa_bwd_dq",
     )(q_p, k_p, v_p, do_p, lse3, delta3)
 
     # dkv grid: (kv heads, kv blocks, group reps, q blocks) — i innermost,
@@ -647,6 +649,7 @@ def _flash_bwd_bhsd(q, k, v, o, lse, g, causal, scale, block_q=128,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="fa_bwd_dkv",
     )(q_p, k_p, v_p, do_p, lse3, delta3)
 
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]

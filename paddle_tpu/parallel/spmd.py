@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..framework.core import Tensor
+from ..observability import span as _span
 from .functional import make_loss_fn
 
 __all__ = ["create_mesh", "shard_params_by_rules", "SpmdTrainer",
@@ -225,16 +226,19 @@ class SpmdTrainer:
 
         def train_step(params, opt_state, batch, rng_key, step, lr):
             loss, grads = jax.value_and_grad(loss_pure)(params, batch, rng_key)
-            grads = apply_clip(grads)
-            if stage >= 2:
-                # ZeRO-2: dp grad psum becomes reduce-scatter; each device
-                # keeps only its slice of every gradient
-                grads = {
-                    name: jax.lax.with_sharding_constraint(
-                        g, NamedSharding(mesh, zero_specs[name]))
-                    for name, g in grads.items()}
-            new_params, new_opt = opt.tree_update(params, grads, opt_state,
-                                                  lr, step)
+            # component scope of the update (observability/catalog.py
+            # TRACE_SCOPES); the model enters its own in forward()
+            with jax.named_scope("pt.opt"):
+                grads = apply_clip(grads)
+                if stage >= 2:
+                    # ZeRO-2: dp grad psum becomes reduce-scatter; each
+                    # device keeps only its slice of every gradient
+                    grads = {
+                        name: jax.lax.with_sharding_constraint(
+                            g, NamedSharding(mesh, zero_specs[name]))
+                        for name, g in grads.items()}
+                new_params, new_opt = opt.tree_update(params, grads,
+                                                      opt_state, lr, step)
             return loss, new_params, new_opt
 
         param_shardings = {k: v.sharding for k, v in self.params.items()}
@@ -282,20 +286,30 @@ class SpmdTrainer:
                 for k in ("argument", "output", "alias", "temp")}
 
     def step(self, batch, rng_key=None):
-        """batch: (x, y) of Tensors or arrays. Returns float loss."""
-        batch_arrays = self._batch_arrays(batch)
-        if rng_key is None:
-            from ..framework.random import next_key
-            rng_key = next_key()
-        self.step_count += 1
-        # step/lr as device scalars so changing them never retraces
-        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-        step = jnp.asarray(self.step_count, jnp.int32)
-        # the mesh is ambient while the step traces: code that GSPMD cannot
-        # partition (Pallas kernels) finds it there and shard_maps itself
-        with jax.set_mesh(self.mesh):
-            loss, self.params, self.opt_state = self._compiled(
-                self.params, self.opt_state, batch_arrays, rng_key, step, lr)
+        """batch: (x, y) of Tensors or arrays. Returns float loss.
+
+        Host spans (observability/tracing.py; live when the tracer is on or
+        a profiler session runs, else three flag reads): `trainer.step`
+        with `step_num` — the step view of a device trace keys on it —
+        around `trainer.stage` (batch, rng key, the two scalars) and
+        `trainer.dispatch` (the call of the compiled step)."""
+        with _span("trainer.step", step_num=self.step_count + 1):
+            with _span("trainer.stage"):
+                batch_arrays = self._batch_arrays(batch)
+                if rng_key is None:
+                    from ..framework.random import next_key
+                    rng_key = next_key()
+                self.step_count += 1
+                # step/lr as device scalars so changing them never retraces
+                lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
+                step = jnp.asarray(self.step_count, jnp.int32)
+            # the mesh is ambient while the step traces: code that GSPMD
+            # cannot partition (Pallas kernels) finds it there and
+            # shard_maps itself
+            with _span("trainer.dispatch"), jax.set_mesh(self.mesh):
+                loss, self.params, self.opt_state = self._compiled(
+                    self.params, self.opt_state, batch_arrays, rng_key,
+                    step, lr)
         return loss
 
     def sync_to_model(self):

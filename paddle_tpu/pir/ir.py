@@ -117,17 +117,26 @@ class Operation:
     ``eqn.primitive.bind``) or a fused op (``fn`` set, a Python callable
     installed by a rewrite pattern; name prefixed ``pt.``)."""
 
-    __slots__ = ("name", "inputs", "outputs", "attrs", "eqn", "fn", "_canon")
+    __slots__ = ("name", "inputs", "outputs", "attrs", "eqn", "fn", "scope",
+                 "_canon")
 
     def __init__(self, name: str, inputs: list, outputs: list,
                  attrs: Optional[dict] = None, eqn=None,
-                 fn: Optional[Callable] = None):
+                 fn: Optional[Callable] = None, scope=None):
         self.name = name
         self.inputs = list(inputs)
         self.outputs = list(outputs)
         self.attrs = dict(attrs or {})
         self.eqn = eqn
         self.fn = fn
+        # the jax.named_scope stack the op was traced under (a replayed
+        # eqn's own; a rewrite hands a fused op its root's). Re-entered
+        # by evaluate(), so component scopes (observability/catalog.py
+        # TRACE_SCOPES) and kv.* effect scopes reach the lowered program.
+        # Metadata only: never part of attr_text / canonical_text.
+        if scope is None and eqn is not None:
+            scope = eqn.source_info.name_stack
+        self.scope = scope if getattr(scope, "stack", None) else None
         self._canon = None
         for o in self.outputs:
             o.op = self
@@ -145,7 +154,15 @@ class Operation:
         """Execute this op on concrete or traced arrays. Replayed eqns
         rebind exactly the way jax.core.eval_jaxpr does — through
         get_bind_params, so call-like primitives (pjit, custom_jvp/vjp,
-        scan, ...) reconstruct their callable sub-terms."""
+        scan, ...) reconstruct their callable sub-terms — and, like it,
+        under the eqn's own name stack (appended to the ambient one)."""
+        if self.scope is None:
+            return self._evaluate(in_vals)
+        from jax.extend import source_info_util as siu
+        with siu.set_name_stack(siu.current_name_stack() + self.scope):
+            return self._evaluate(in_vals)
+
+    def _evaluate(self, in_vals: list) -> list:
         if self.fn is not None:
             out = self.fn(*in_vals)
             return list(out) if isinstance(out, (tuple, list)) else [out]

@@ -302,7 +302,7 @@ class SdpaRoutePattern(RewritePattern):
             attrs={"causal": causal, "scale": scale, "route_fwd": route_fwd,
                    "route_source": getattr(dec, "source", "none"),
                    "shape": (b, sq, sk, h, d)},
-            fn=fn)
+            fn=fn, scope=out.op.scope)
         prog.replace_region(m["region"], new_op)
         return new_op
 
@@ -440,7 +440,7 @@ class RmsEpiloguePattern(RewritePattern):
                    "route_fwd": route_fwd,
                    "route_source": getattr(dec, "source", "none"),
                    "shape": (b, sq, sk, h, d)},
-            fn=fn)
+            fn=fn, scope=m["out"].op.scope)
         prog.replace_region(m["region"], new_op)
         return new_op
 

@@ -5,8 +5,10 @@ profiler_statistic.py, timer.py throughput benchmark).
 TPU-native: device tracing is jax.profiler (XPlane -> TensorBoard trace
 viewer), replacing the CUPTI tracer stack
 (paddle/fluid/platform/profiler/cuda_tracer.cc). Host-side annotated ranges
-use jax.profiler.TraceAnnotation so they interleave with XLA's device events
-in the same trace; a lightweight host-event table backs summary().
+are spans of the observability tracer, which enters a
+jax.profiler.TraceAnnotation for each while a session runs, so they
+interleave with XLA's device events in the same trace; the tracer's ring
+backs summary().
 """
 
 from __future__ import annotations
@@ -66,31 +68,28 @@ class SummaryView(enum.Enum):
 
 
 class RecordEvent:
-    """Annotated host range, visible in the device trace AND recorded as a
-    span in the observability tracer (observability/tracing.py) — so
-    summary() aggregates it and export_chrome_tracing's host trace shows
-    it with parent/child nesting.
+    """Annotated host range: a span of the observability tracer
+    (observability/tracing.py) opened through its UNGATED begin/end —
+    profiler users asked for recording explicitly, independent of the
+    global observability flag. summary() aggregates it,
+    export_chrome_tracing's host trace shows it with parent/child
+    nesting, and while a jax profiler session runs the tracer itself
+    puts it on the host plane of the device trace (one path for every
+    span, no event twice).
     reference: python/paddle/profiler/utils.py RecordEvent +
     C++ paddle/fluid/platform/profiler/event_tracing.h."""
 
     def __init__(self, name, event_type=None):
         self.name = name
-        self._ann = None
         self._span = None
 
     def begin(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
-        # ungated tracer path: profiler users asked for recording
-        # explicitly, independent of the global observability flag
         self._span = _host_tracer().begin(self.name)
 
     def end(self):
-        if self._ann is not None:
+        if self._span is not None:
             _host_tracer().end(self._span)
             self._span = None
-            self._ann.__exit__(None, None, None)
-            self._ann = None
 
     def __enter__(self):
         self.begin()

@@ -17,6 +17,15 @@ Disabled-mode contract (same as the flight recorder): every mutation
 starts with one attribute check and returns before allocating, so a
 disabled accountant costs one branch per call site. Call sites that
 would build kwargs guard with ``if acct.enabled:`` themselves.
+
+On the device trace's clock: while the accountant is enabled AND a jax
+profiler session runs, each segment is also one host event
+``serving.phase`` (a jax.profiler.TraceAnnotation) with the phase as
+its ``phase`` stat. A ``mark()`` names the segment that just ENDED, so
+the annotation is opened at the previous mark (or ``begin_step``) and
+given its phase on closing; the tail between the last mark and
+``end_step`` closes as ``unattributed``. The trace's seconds per phase
+and ``report()``'s agree (test-pinned, 2%).
 """
 
 from __future__ import annotations
@@ -24,6 +33,8 @@ from __future__ import annotations
 import os
 import threading
 import time
+
+from ..observability.tracing import session_annotation
 
 __all__ = ["PHASES", "PhaseAccountant", "get_phase_accountant"]
 
@@ -55,13 +66,14 @@ class PhaseAccountant:
 
     __slots__ = ("enabled", "_lock", "_t_step", "_last", "_wall", "_attr",
                  "_phase_s", "_phase_n", "_tenant_s", "_steps", "_hist",
-                 "_cov")
+                 "_cov", "_ann")
 
     def __init__(self, enabled=False):
         self.enabled = bool(enabled)
         self._lock = threading.Lock()
         self._hist = None       # phase -> bound catalog histogram child
         self._cov = None        # bound coverage gauge
+        self._ann = None        # open serving.phase TraceAnnotation
         self._zero()
 
     def _zero(self):
@@ -93,10 +105,25 @@ class PhaseAccountant:
             self._zero()
 
     # -- accounting ----------------------------------------------------------
+    def _segment(self, ended, opens=True):
+        """Close the open serving.phase event as `ended` and, while a
+        profiler session runs, open the next segment's."""
+        ann = self._ann
+        if ann is not None:
+            ann.set_metadata(phase=ended)
+            ann.__exit__(None, None, None)
+            ann = None
+        cls = session_annotation() if opens else None
+        if cls is not None:
+            ann = cls("serving.phase")
+            ann.__enter__()
+        self._ann = ann
+
     def begin_step(self):
         if not self.enabled:
             return
         self._t_step = self._last = time.perf_counter()
+        self._segment("unattributed")
 
     def mark(self, phase, tenant=None, dt=None):
         """Attribute wall time since the previous mark (or ``dt`` seconds
@@ -113,6 +140,7 @@ class PhaseAccountant:
         if self._hist is None:
             self._bind()
         now = time.perf_counter()
+        self._segment(phase)
         seg = now - self._last if dt is None else min(dt, now - self._last)
         self._last = now
         with self._lock:
@@ -129,6 +157,7 @@ class PhaseAccountant:
         if self._t_step is None:
             return
         now = time.perf_counter()
+        self._segment("unattributed", opens=False)
         with self._lock:
             self._wall += now - self._t_step
             self._steps += 1

@@ -32,7 +32,8 @@ def test_list_rules_names_the_closed_registry():
     for rule in ("metrics-in-catalog", "catalog-docs-sync", "fault-sites",
                  "recorder-kinds", "flags-registered", "host-sync",
                  "profiler-phases", "scheduler-actions", "pir-passes",
-                 "mesh-wiring", "recording-rules", "adapter-wiring"):
+                 "mesh-wiring", "recording-rules", "adapter-wiring",
+                 "trace-scopes"):
         assert rule in r.stdout
 
 
@@ -50,6 +51,11 @@ def test_unknown_rule_is_a_usage_error():
     ('rec.record("not_a_kind", x=1)\n', "recorder-kinds"),
     ('import os\n'
      'os.environ.get("FLAGS_totally_unregistered")\n', "flags-registered"),
+    ('import jax\n'
+     'with jax.named_scope("pt.not_a_scope"):\n    pass\n', "trace-scopes"),
+    ('from jax.experimental import pallas as pl\n'
+     'pl.pallas_call(k, out_shape=s, name="unnamed_kernel")(x)\n',
+     "trace-scopes"),
 ])
 def test_injected_violation_fails(tmp_path, source, rule):
     bad = tmp_path / "bad_module.py"
@@ -215,3 +221,17 @@ def test_host_sync_rule_catches_new_sync(tmp_path):
     found = json.loads(r.stdout)
     assert any(v["rule"] == "host-sync" and "_hot_loop" in v["message"]
                for v in found), found
+
+
+def test_trace_scopes_rule_passes_declared_names_and_other_prefixes(tmp_path):
+    # declared scopes and kernel names pass; scopes outside the pt.
+    # namespace (kv.write, pir.fuse.*) are not this rule's business; a
+    # one-file scan must not fire the "never entered" direction
+    ok = tmp_path / "ok_module.py"
+    ok.write_text(
+        'import jax\n'
+        'from jax.experimental import pallas as pl\n'
+        'with jax.named_scope("pt.mlp"), jax.named_scope("kv.write"):\n'
+        '    pl.pallas_call(k, out_shape=s, name="fa_fwd")(x)\n')
+    r = _run("--rule", "trace-scopes", "--paths", str(ok), "--json")
+    assert r.returncode == 0, r.stdout

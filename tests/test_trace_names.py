@@ -1,0 +1,328 @@
+"""The program names its own work on the device trace (ISSUE 26).
+
+(a) host spans: under a jax profiler session every tracer span, every
+    RecordEvent, the trainer's step spans and the engine's phase segments
+    land once on the host plane of the profiler's trace, children inside
+    parents, and the phase seconds read from the trace agree with
+    PhaseAccountant.report(); with the tracer off and no session, span()
+    is the shared no-op.
+(b) device names: every component scope of the closed set
+    (observability/catalog.py TRACE_SCOPES) is in the lowered text of the
+    tiny GPT train step, forward and backward, and of the engine's
+    pir_jit decode and prefill programs AFTER PIR replay; the flash
+    kernels carry their names.
+"""
+
+import glob
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import optimizer, profiler
+from paddle_tpu.inference import ContinuousBatchingEngine
+from paddle_tpu.models.gpt import gpt_tiny
+from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.observability import tracing
+from paddle_tpu.observability.catalog import KERNEL_NAMES, TRACE_SCOPES
+from paddle_tpu.parallel import GPT_SHARDING_RULES, SpmdTrainer, create_mesh
+from paddle_tpu.profiler.phases import PHASES, get_phase_accountant
+
+TRAIN_SCOPES = ("pt.embed", "pt.attn", "pt.mlp", "pt.head", "pt.loss",
+                "pt.opt")
+SERVE_SCOPES = ("pt.embed", "pt.attn", "pt.mlp", "pt.head",
+                "pt.serve.gather", "pt.serve.attend", "pt.serve.sample")
+
+
+def _trainer():
+    paddle.seed(0)
+    model = gpt_tiny()
+    opt = optimizer.AdamW(1e-3, parameters=model.parameters())
+    return SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
+                       GPT_SHARDING_RULES)
+
+
+def _engine(**kw):
+    cfg = LlamaConfig(vocab_size=128, hidden_size=64, intermediate_size=128,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      num_key_value_heads=2, max_position_embeddings=256)
+    paddle.seed(0)
+    return ContinuousBatchingEngine(
+        LlamaForCausalLM(cfg), num_blocks=64, block_size=8, max_batch=4,
+        prefill_buckets=(16,), decode_steps=4, **kw)
+
+
+def _serve(eng, n=3):
+    rs = np.random.RandomState(1)
+    for _ in range(n):
+        eng.add_request(rs.randint(0, 128, (11,)), max_new_tokens=9)
+    eng.run()
+
+
+def _host_events(trace_dir):
+    """[(name, start_ns, end_ns, stats)] of every host-plane event."""
+    path = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")[0]
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    out.append((e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+# -- (a) host spans on the trace's clock -------------------------------------
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One profiler session over a tracer span, a RecordEvent, two trainer
+    steps and a served batch; the accountant counts the same period."""
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    trainer, eng = _trainer(), _engine()
+    ids = np.random.RandomState(0).randint(0, 512, (2, 64)).astype(np.int32)
+    trainer.step((ids, ids)).block_until_ready()        # compile outside
+    _serve(eng)
+    acct = get_phase_accountant()
+    was = acct.enabled
+    acct.enable()
+    acct.reset()
+    tracer_was_on = tracing.enabled()     # another test's leftover
+    tracing.disable()
+    assert tracing.span("off") is tracing._NOOP        # tracer off, no session
+    marker = tracing.get_tracer().marker()
+    jax.profiler.start_trace(trace_dir)
+    try:
+        with tracing.span("x", rid=7, trace_id="t-1", lane=2):
+            with profiler.RecordEvent("rec"):
+                time.sleep(0.001)
+        for _ in range(2):
+            loss = trainer.step((ids, ids))
+        loss.block_until_ready()
+        _serve(eng)
+    finally:
+        jax.profiler.stop_trace()
+    report = acct.report()
+    acct.reset()
+    if not was:
+        acct.disable()
+    assert tracing.span("off") is tracing._NOOP        # the session is over
+    if tracer_was_on:
+        tracing.enable()
+    return {"events": _host_events(trace_dir), "report": report,
+            "step_count": trainer.step_count,
+            "ring": [sp.name for sp in
+                     tracing.get_tracer().spans_since(marker)]}
+
+
+def _named(traced, name):
+    return [e for e in traced["events"] if e[0] == name]
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def test_span_and_record_event_land_once_with_metadata(traced):
+    (x,), (rec,) = _named(traced, "x"), _named(traced, "rec")
+    assert x[3] == {"rid": 7, "trace_id": "t-1", "lane": 2}
+    assert _inside(rec, x)          # RecordEvent: one path, no event twice
+    assert rec[2] - rec[1] >= 1_000_000       # the 1 ms sleep, in ns
+
+
+def test_session_only_span_stays_out_of_the_ring(traced):
+    # the tracer was off: the annotation alone; RecordEvent is ungated
+    assert traced["ring"] == ["rec"]
+
+
+def test_trainer_step_spans_nest_and_carry_the_step_number(traced):
+    steps = _named(traced, "trainer.step")
+    stages = _named(traced, "trainer.stage")
+    dispatches = _named(traced, "trainer.dispatch")
+    assert len(steps) == len(stages) == len(dispatches) == 2
+    assert [s[3]["step_num"] for s in steps] == [
+        traced["step_count"] - 1, traced["step_count"]]
+    for step, stage, disp in zip(steps, stages, dispatches):
+        assert _inside(stage, step) and _inside(disp, step)
+        assert stage[2] <= disp[1]
+
+
+def test_engine_spans_nest(traced):
+    steps = _named(traced, "serving.step")
+    assert steps
+    for name in ("serving.prefill", "serving.decode_step"):
+        kids = _named(traced, name)
+        assert kids, name
+        assert all(any(_inside(k, s) for s in steps) for k in kids)
+    assert all("rid" in k[3] for k in _named(traced, "serving.prefill"))
+
+
+def test_phase_segments_agree_with_the_accountant(traced):
+    segs = _named(traced, "serving.phase")
+    steps = _named(traced, "serving.step")
+    report = traced["report"]
+    from_trace, marks = {}, {}
+    for _, t0, t1, stats in segs:
+        p = stats["phase"]
+        from_trace[p] = from_trace.get(p, 0.0) + (t1 - t0) * 1e-9
+        marks[p] = marks.get(p, 0) + 1
+    attributed = {p: v for p, v in from_trace.items() if p != "unattributed"}
+    assert set(attributed) == set(report["phases"]) <= set(PHASES)
+    for p, row in report["phases"].items():
+        assert marks[p] == row["marks"]                 # each segment once
+        # two clocks read a few microseconds apart at every boundary
+        assert attributed[p] == pytest.approx(
+            row["seconds"], rel=0.02, abs=20e-6 * row["marks"]), p
+    assert sum(attributed.values()) == pytest.approx(
+        report["attributed_s"], rel=0.02)
+    # a segment lies in the engine step it was marked in
+    assert all(any(s[1] - 50_000 <= g[1] and g[2] <= s[2] + 50_000
+                   for s in steps) for g in segs)
+
+
+def test_standalone_tracing_never_imports_jax():
+    """tracing.py is stdlib-only and loadable outside the package: without
+    jax in the process there is no session and span() is the no-op."""
+    import os
+    import subprocess
+    import sys
+    path = os.path.join(os.path.dirname(tracing.__file__), "tracing.py")
+    code = (
+        "import importlib.util, sys\n"
+        f"spec = importlib.util.spec_from_file_location('t', {path!r})\n"
+        "t = importlib.util.module_from_spec(spec)\n"
+        "spec.loader.exec_module(t)\n"
+        "assert t.span('x') is t._NOOP and t.session_annotation() is None\n"
+        "tr = t.Tracer(enabled=True)\n"
+        "with tr.span('y', rid=1):\n"
+        "    pass\n"
+        "assert [s.name for s in tr.spans_since()] == ['y']\n"
+        "assert 'jax' not in sys.modules\n")
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True)
+    assert r.returncode == 0, r.stderr
+
+
+# -- (b) names on device operations ------------------------------------------
+
+def _scope_hits(text, scope):
+    """Locations of the lowered text under `scope` as a whole component."""
+    return re.findall(r'loc\("(?:[^"]*[/(])?' + re.escape(scope)
+                      + r'(?:[/)][^"]*)?"', text)
+
+
+def test_train_step_lowers_with_every_scope_forward_and_backward():
+    from paddle_tpu.framework.random import get_rng_state
+    trainer = _trainer()
+    ids = np.zeros((2, 64), np.int32)
+    batch = trainer._batch_arrays((ids, ids))
+    with jax.set_mesh(trainer.mesh):
+        text = trainer._compiled.lower(
+            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
+            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
+        ).as_text(debug_info=True)
+    for scope in TRAIN_SCOPES:
+        hits = _scope_hits(text, scope)
+        assert hits, scope
+        if scope != "pt.opt":       # the update has no backward
+            assert any("transpose(jvp(" in h for h in hits), scope
+
+
+@pytest.fixture(scope="module")
+def served_engine():
+    eng = _engine()
+    _serve(eng, n=2)
+    return eng
+
+
+def _replayed_text(pir_fn):
+    """Lowered text of a pir_jit program as the pipeline compiles it: the
+    post-pass Program replayed eqn by eqn (pir/ir.py Operation.evaluate)."""
+    prog = pir_fn.report.program
+    avals = [jax.ShapeDtypeStruct(v.shape, v.dtype) for v in prog.inputs]
+    return jax.jit(prog.bind).lower(*avals).as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("which", ["decode", "prefill"])
+def test_pir_replay_keeps_the_scopes(served_engine, which):
+    table = (served_engine._decode_jit if which == "decode"
+             else served_engine._prefill_jit)
+    assert table
+    for fn in table.values():
+        assert fn.report.fallback is None
+        text = _replayed_text(fn)
+        for scope in SERVE_SCOPES:
+            if which == "prefill" and scope == "pt.serve.sample":
+                continue            # prefill samples on the host
+            assert _scope_hits(text, scope), (which, scope)
+        assert _scope_hits(text, "kv.write")        # effect scope, as before
+
+
+def test_scope_is_metadata_not_identity():
+    """A scope changes no canonical text or hash, so no compile-cache key
+    and no golden moves because of a name."""
+    from paddle_tpu.pir import capture
+
+    def body(x, w):
+        return jnp.tanh(x @ w) * 2.0
+
+    def scoped(x, w):
+        with jax.named_scope("pt.mlp"):
+            return body(x, w)
+
+    x, w = jnp.ones((4, 8)), jnp.ones((8, 8))
+    a, b = capture(body, x, w)[0], capture(scoped, x, w)[0]
+    assert a.canonical_text() == b.canonical_text()
+    assert a.canonical_hash() == b.canonical_hash()
+    assert all(op.scope is None for op in a.ops)
+    assert all(str(op.scope) == "pt.mlp" for op in b.ops)
+
+
+def test_fused_region_keeps_its_members_scope_inside_its_own():
+    from paddle_tpu.pir import pir_jit
+
+    def f(x, w):
+        with jax.named_scope("pt.mlp"):
+            return jnp.tanh((x @ w) * 2.0 + 1.0)
+
+    x, w = jnp.ones((4, 16)), jnp.ones((16, 16))
+    g = pir_jit(f, name="scoped")
+    g(x, w)
+    assert g.report.fusion_groups
+    text = _replayed_text(g)
+    assert re.search(r'pir\.fuse\.scoped\.g\d+/pt\.mlp/', text)
+
+
+def test_flash_kernels_carry_their_names():
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
+    q = jnp.ones((1, 256, 2, 128), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return flash_attention_bshd(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
+    names = []
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(eqn.params["name"])
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert sorted(set(names)) == sorted(KERNEL_NAMES)
+
+
+def test_scope_names_stay_clear_of_effect_scopes():
+    from paddle_tpu.pir.verifier import EFFECT_SCOPES
+    for name in list(TRACE_SCOPES) + list(KERNEL_NAMES):
+        assert not set(name.split("/")) & set(EFFECT_SCOPES)

@@ -18,6 +18,10 @@ Rules (closed registry, like everything else here):
   recorder-kinds       record("kind") literals  ⊆ recorder EVENT_KINDS
   profiler-phases      mark("phase") literals in profiler/ + serving.py
                        ⊆ phases.py PHASES == OBSERVABILITY.md phase rows
+  trace-scopes         named_scope("pt.*") literals == catalog.py
+                       TRACE_SCOPES, pallas_call(name=) literals ==
+                       KERNEL_NAMES, both == OBSERVABILITY.md scope/ and
+                       kernel/ rows
   scheduler-actions    brownout-level literals (level_index("x")) and
                        priority-class literals (priority= defaults /
                        keywords, .priority comparisons) in the serving +
@@ -263,6 +267,12 @@ class Context:
                                        _read(OBS_MD), re.M))
         self.phase_rows = set(re.findall(r"^\| `phase/([a-z_.]+)` \|",
                                          _read(OBS_MD), re.M))
+        self.trace_scopes = _dict_keys(CATALOG_PY, "TRACE_SCOPES")
+        self.kernel_names = _dict_keys(CATALOG_PY, "KERNEL_NAMES")
+        self.scope_rows = set(re.findall(r"^\| `scope/([a-z_.]+)` \|",
+                                         _read(OBS_MD), re.M))
+        self.kernel_rows = set(re.findall(r"^\| `kernel/([a-z_.]+)` \|",
+                                          _read(OBS_MD), re.M))
         self.res_ticks = set(re.findall(r"`([a-z_]+\.[a-z_]+)`",
                                         _read(RES_MD)))
         self.priority_classes = _dict_keys(SCHEDULER_PY, "PRIORITY_CLASSES")
@@ -409,6 +419,73 @@ def rule_profiler_phases(ctx):
             "profiler-phases", OBS_MD, 0,
             f"{OBS_MD} documents phase {name!r} which is not in "
             f"{PHASES_PY} PHASES"))
+    return out
+
+
+def rule_trace_scopes(ctx):
+    """The names the program gives its work on a device trace are closed
+    like the metric catalog (observability/catalog.py TRACE_SCOPES and
+    KERNEL_NAMES): every ``named_scope("pt. ...")`` literal must be a
+    declared component scope, every ``pallas_call(..., name="...")``
+    literal a declared kernel name, every declared name must be entered
+    somewhere, and each must have a `| \\`scope/NAME\\` |` /
+    `| \\`kernel/NAME\\` |` row in OBSERVABILITY.md — both directions.
+    A per-layer metric of the benchmark matches on these names; one that
+    drifts silently reads nothing."""
+    out = []
+    used_scopes, used_kernels = set(), set()
+    for path, tree in ctx.sources.items():
+        for node in ast.walk(tree):
+            if not isinstance(node, ast.Call):
+                continue
+            callee = _callee(node)
+            if callee == "named_scope" and node.args \
+                    and isinstance(node.args[0], ast.Constant) \
+                    and isinstance(node.args[0].value, str) \
+                    and node.args[0].value.startswith("pt."):
+                name = node.args[0].value
+                used_scopes.add(name)
+                if name not in ctx.trace_scopes:
+                    out.append(Violation(
+                        "trace-scopes", path, node.lineno,
+                        f"named_scope({name!r}) is not in {CATALOG_PY} "
+                        "TRACE_SCOPES"))
+            elif callee == "pallas_call":
+                for kw in node.keywords:
+                    if kw.arg == "name" \
+                            and isinstance(kw.value, ast.Constant) \
+                            and isinstance(kw.value.value, str):
+                        used_kernels.add(kw.value.value)
+                        if kw.value.value not in ctx.kernel_names:
+                            out.append(Violation(
+                                "trace-scopes", path, node.lineno,
+                                f"pallas_call(name={kw.value.value!r}) is "
+                                f"not in {CATALOG_PY} KERNEL_NAMES"))
+    # reverse direction only on a scan that includes the registry's own
+    # file (a --paths run on one module must not fire "never entered")
+    if any(p.replace(os.sep, "/").endswith(CATALOG_PY)
+           for p in ctx.sources):
+        for name in sorted(ctx.trace_scopes - used_scopes):
+            out.append(Violation(
+                "trace-scopes", CATALOG_PY, 0,
+                f"TRACE_SCOPES entry {name!r} is entered by no "
+                "named_scope() literal"))
+        for name in sorted(ctx.kernel_names - used_kernels):
+            out.append(Violation(
+                "trace-scopes", CATALOG_PY, 0,
+                f"KERNEL_NAMES entry {name!r} names no pallas_call()"))
+    for reg, rows, kind in ((ctx.trace_scopes, ctx.scope_rows, "scope"),
+                            (ctx.kernel_names, ctx.kernel_rows, "kernel")):
+        for name in sorted(reg - rows):
+            out.append(Violation(
+                "trace-scopes", OBS_MD, 0,
+                f"{kind} name {name!r} has no `| `{kind}/{name}` |` row "
+                f"in {OBS_MD}"))
+        for name in sorted(rows - reg):
+            out.append(Violation(
+                "trace-scopes", OBS_MD, 0,
+                f"{OBS_MD} documents {kind}/{name} which is not in "
+                f"{CATALOG_PY}"))
     return out
 
 
@@ -989,6 +1066,9 @@ RULES = {
     "profiler-phases": (rule_profiler_phases,
                         "mark() literals ⊆ profiler PHASES == "
                         "OBSERVABILITY.md phase rows"),
+    "trace-scopes": (rule_trace_scopes,
+                     "named_scope(pt.*) / pallas_call(name=) literals == "
+                     "TRACE_SCOPES / KERNEL_NAMES == OBSERVABILITY.md rows"),
     "scheduler-actions": (rule_scheduler_actions,
                           "brownout/priority literals ⊆ scheduler "
                           "registries == RESILIENCE.md rows"),

@@ -25,6 +25,7 @@ tiny sizes on the CPU so the script cannot rot.
 
 from __future__ import annotations
 
+import dataclasses
 import gc
 import json
 import os
@@ -191,13 +192,13 @@ def _compare(name, got, want):
             "grad_max_rel_err": grad_err}
 
 
-def _attention_case(name, bh, bh_kv, seq, d, blocks, packed, interpret, seed):
+def _attention_case(name, bh, bh_kv, seq, d, tiles, interpret, seed):
     """One flash fwd+bwd (bf16, causal) against the dense float32 reference.
-    Returns the max errors; raises if they exceed the tolerances."""
+    tiles: a flash_attention.Tiles, or None for the chooser's. Returns the
+    max errors; raises if they exceed the tolerances."""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from paddle_tpu.framework import flags as _flags
     from paddle_tpu.ops.pallas import flash_attention as fa
 
     rs = np.random.RandomState(seed)
@@ -207,26 +208,20 @@ def _attention_case(name, bh, bh_kv, seq, d, blocks, packed, interpret, seed):
     g = jnp.asarray(rs.randn(bh, seq, d), jnp.bfloat16)
     scale = 1.0 / d ** 0.5
     rep = bh // bh_kv
-    bq, bk = blocks
+    if tiles is None:
+        tiles = fa.choose_tiles(seq, seq, d, 2)
 
     @jax.jit
     def flash_fwd_bwd(q_, k_, v_, g_):
-        out, lse = fa._flash_fwd_bhsd(q_, k_, v_, True, scale, block_q=bq,
-                                      block_k=bk, interpret=interpret,
-                                      q_per_kv=rep)
+        out, lse = fa._flash_fwd_bhsd(q_, k_, v_, True, scale, tiles=tiles,
+                                      interpret=interpret, q_per_kv=rep)
         return (out,) + tuple(fa._flash_bwd_bhsd(
-            q_, k_, v_, out, lse, g_, True, scale, block_q=bq, block_k=bk,
+            q_, k_, v_, out, lse, g_, True, scale, tiles=tiles,
             interpret=interpret, q_per_kv=rep))
 
-    # the packing flag is read while tracing
-    prev = _flags.flag_value("flash_packed_grid")
-    _flags.set_flags({"flash_packed_grid": "on" if packed else "off"})
-    try:
-        t0 = time.perf_counter()
-        got = jax.block_until_ready(flash_fwd_bwd(q, k, v, g))
-        wall = time.perf_counter() - t0
-    finally:
-        _flags.set_flags({"flash_packed_grid": prev})
+    t0 = time.perf_counter()
+    got = jax.block_until_ready(flash_fwd_bwd(q, k, v, g))
+    wall = time.perf_counter() - t0
 
     def dense(q_, k_, v_):
         # GQA: the reference sees each kv head repeated over its group
@@ -251,9 +246,12 @@ def _attention_case(name, bh, bh_kv, seq, d, blocks, packed, interpret, seed):
         jnp.concatenate(x) for x in zip(*parts))
 
     res = _compare(name, got, want)
-    res.update(bh=bh, bh_kv=bh_kv, seq=seq, head_dim=d, blocks=[bq, bk],
-               packed=packed, first_call_s=round(wall, 2))
-    log(f"kernel {name}: blocks=({bq},{bk}) packed={packed} "
+    steps = tiles.grid_steps(bh, seq, seq)
+    res.update(bh=bh, bh_kv=bh_kv, seq=seq, head_dim=d,
+               tiles={"fwd": tiles.fwd, "dq": tiles.dq, "dkv": tiles.dkv},
+               grid_steps=steps, first_call_s=round(wall, 2))
+    log(f"kernel {name}: tiles (resident, streamed, sub) fwd={tiles.fwd} "
+        f"dq={tiles.dq} dkv={tiles.dkv} grid steps {steps} "
         f"out_err={res['out_max_abs_err']:.4f} "
         + " ".join(f"{n}_err={e:.4f}"
                    for n, e in res["grad_max_rel_err"].items())
@@ -289,15 +287,12 @@ def _pad96_case(b, seq, h):
                 *(a.astype(jnp.float32) for a in (q_, k_, v_)))
             return (ref,) + pull(g_.astype(jnp.float32))
 
-    prev = (_flags.flag_value("flash_packed_grid"),
-            _flags.flag_value("flash_attention_bwd"))
-    _flags.set_flags({"flash_packed_grid": "off",
-                      "flash_attention_bwd": "pallas"})
+    prev = _flags.flag_value("flash_attention_bwd")
+    _flags.set_flags({"flash_attention_bwd": "pallas"})
     try:
         got = jax.block_until_ready(flash(q, k, v, g))
     finally:
-        _flags.set_flags({"flash_packed_grid": prev[0],
-                          "flash_attention_bwd": prev[1]})
+        _flags.set_flags({"flash_attention_bwd": prev})
     res = _compare("d96_zero_pad", got, dense(q, k, v, g))
     log(f"kernel d96_zero_pad: out_err={res['out_max_abs_err']:.4f} "
         + " ".join(f"{n}_err={e:.4f}"
@@ -311,7 +306,6 @@ def _epilogue_case(b, seq, h, d):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from paddle_tpu.framework import flags as _flags
     from paddle_tpu.nn.functional.attention import _xla_attention
     from paddle_tpu.ops.pallas import flash_attention as fa
 
@@ -331,12 +325,7 @@ def _epilogue_case(b, seq, h, d):
                                     + 1e-6)) * w_.astype(jnp.float32)
         return jnp.max(jnp.abs(out.astype(jnp.float32) - ref))
 
-    prev = _flags.flag_value("flash_packed_grid")
-    _flags.set_flags({"flash_packed_grid": "off"})
-    try:
-        e = float(err(q, k, v, res, w))
-    finally:
-        _flags.set_flags({"flash_packed_grid": prev})
+    e = float(err(q, k, v, res, w))
     log(f"kernel rms_epilogue: out_err={e:.4f}")
     if not e <= 2 * KERNEL_OUT_TOL:     # one more bf16 rounding (the norm)
         raise AssertionError(f"rms-epilogue flash differs from dense by {e}")
@@ -345,43 +334,25 @@ def _epilogue_case(b, seq, h, d):
 
 def kernel_phase(bh=TRAIN_BATCH * 16, seq=TRAIN_SEQ, d=128, small_seq=512,
                  gqa=(16, 4), interpret=False):
-    """Compile (interpret=False on the chip) and run the rectangular bf16
-    flash kernels at the trainer's attention shape with the blocks the
-    ledger hands out and at (128, 128), GQA, the d=96 zero-pad and the RMS
-    epilogue once each; then try the triangle-packed grid, whose refusal by
-    the compiler is a finding (ROADMAP S4), not a failure."""
-    import jax.numpy as jnp
-    from paddle_tpu.ops.pallas.attention_router import ledger_blocks
+    """Compile (interpret=False on the chip) and run the bf16 flash kernels
+    at the trainer's attention shape with the tiles the chooser hands out,
+    then with one-lane-tile sub-blocks inside a streamed tile shorter than
+    the sequence (the clamped index maps and the loops' bounds both at
+    work), GQA, the d=96 zero-pad and the RMS epilogue once each."""
+    from paddle_tpu.ops.pallas.flash_attention import Tiles
 
-    led = ledger_blocks("fwd", bh, seq, seq, d, jnp.bfloat16, True)
-    log(f"kernel phase: bh={bh} seq={seq} d={d} bf16 causal; ledger blocks "
-        f"for this shape: {led}")
-    cases = []
-    if led is not None and tuple(led) != (128, 128):
-        cases.append(_attention_case("rect_ledger_blocks", bh, bh, seq, d,
-                                     tuple(led), False, interpret, 1))
-    cases.append(_attention_case("rect_128", bh, bh, seq, d, (128, 128),
-                                 False, interpret, 2))
-    cases.append(_attention_case("rect_gqa", gqa[0], gqa[1], small_seq, d,
-                                 (128, 128), False, interpret, 3))
-    cases.append(_pad96_case(2, small_seq, 4))
-    cases.append(_epilogue_case(2, small_seq, 4, d))
-    try:
-        packed = _attention_case("packed_128", bh, bh, seq, d, (128, 128),
-                                 True, interpret, 4)
-        packed_result = {"lowers": True, "matches": True, **packed}
-        log("packed grid: lowers and matches the dense reference")
-    except AssertionError as e:
-        packed_result = {"lowers": True, "matches": False, "error": str(e)}
-        log(f"packed grid lowers but does NOT match (finding, default "
-            f"stays off on TPU): {e}")
-    except Exception as e:  # noqa: BLE001 — the compiler's message, verbatim,
-        # is the finding for ROADMAP S4/D4
-        packed_result = {"lowers": False,
-                         "error": f"{type(e).__name__}: {str(e)[:1500]}"}
-        log(f"packed grid REFUSED (finding, default stays off on TPU): "
-            f"{packed_result['error']}")
-    return {"cases": cases, "packed_grid": packed_result}
+    log(f"kernel phase: bh={bh} seq={seq} d={d} bf16 causal")
+    small = (128, min(512, seq), 128)
+    cases = [
+        _attention_case("chooser_tiles", bh, bh, seq, d, None, interpret, 1),
+        _attention_case("small_tiles", bh, bh, seq, d,
+                        Tiles(fwd=small, dq=small, dkv=small), interpret, 2),
+        _attention_case("gqa", gqa[0], gqa[1], small_seq, d, None, interpret,
+                        3),
+        _pad96_case(2, small_seq, 4),
+        _epilogue_case(2, small_seq, 4, d),
+    ]
+    return {"cases": cases}
 
 
 # ---------------------------------------------------------------------------
@@ -570,13 +541,13 @@ def _trainer_attention(batch, heads, seq, head_dim, dtype, batch_split=1,
     attention — asked of the same predicates the model calls, so a cached
     router decision is reported too. The model asks for the forward at the
     global shape; under a mesh the kernel runs per shard (flash_attention
-    _mesh_spec), and its blocks and backward are routed at the shard's."""
+    _mesh_spec), and its tiles and backward are routed at the shard's."""
     import jax
     from paddle_tpu.nn.functional.attention import _use_pallas
     if jax.default_backend() != "tpu":
         return {"forward": "xla_dense",
                 "why": f"backend is {jax.default_backend()}"}
-    from paddle_tpu.ops.pallas.attention_router import ledger_blocks, route
+    from paddle_tpu.ops.pallas.attention_router import route
     if not _use_pallas((batch, seq, heads, head_dim), head_dim, False,
                        dtype=dtype, causal=True):
         return {"forward": "xla_dense", "backward": "xla_autodiff",
@@ -588,11 +559,8 @@ def _trainer_attention(batch, heads, seq, head_dim, dtype, batch_split=1,
             "forward_source": route(batch * heads, seq, seq, head_dim,
                                     dtype, True).source,
             "per_shard_bh": bh, "backward_source": local.source,
-            "blocks_fwd": ledger_blocks("fwd", bh, seq, seq, head_dim,
-                                        dtype, True),
-            "blocks_bwd": ledger_blocks("bwd", bh, seq, seq, head_dim,
-                                        dtype, True),
-            "packed_grid": local.packed_grid,
+            "tiles": dataclasses.asdict(local.tiles),
+            "grid_steps": local.grid_steps,
             "partitioned_by": ("shard_map over batch and heads"
                                if batch_split * head_split > 1 else None)}
 
@@ -815,7 +783,8 @@ def main(argv=None):
     from paddle_tpu.ops.pallas.attention_router import decision_log
     for key, dec in decision_log():
         log(f"router decision (bh, sq, sk, d, dtype, causal)={key}: "
-            f"fwd={dec.fwd} bwd={dec.bwd} source={dec.source}")
+            f"fwd={dec.fwd} bwd={dec.bwd} source={dec.source} "
+            f"grid steps {dec.grid_steps}")
     _cache_record(cache_dir, meter, "four-chip" if four_chip else "one-chip")
     log(f"total {time.perf_counter() - t_all:.1f}s")
     out_dir = os.path.join(REPO, "chiprun_out")

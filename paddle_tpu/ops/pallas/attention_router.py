@@ -3,10 +3,11 @@
 reference capability: paddle/phi/kernels/autotune/ (per-signature algorithm
 choice) + python/paddle/nn/functional/flash_attention.py's
 sdp_kernel-style backend selection — generalized into the shape-keyed
-dispatch the r5 hardware A/B demanded: the Pallas flash kernel LOSES to
-dense XLA at most production shapes (fwd 0.71-0.86x dense at s1024/s2048)
-and wins at others (1.23x at s4096), so a single fixed backend is wrong
-in both directions.
+dispatch the r5 hardware A/B demanded: the f32-operand flash kernels of
+that round lost to dense XLA at most production shapes (fwd 0.71-0.86x
+dense at s1024/s2048) and won at others (1.23x at s4096). The rows were
+re-measured in PR 27 with the two-level-tile kernels, which win all four
+(PERF.md section 6; ROADMAP D4 decides what is left of this module).
 
 Design (three sources, in priority order, every decision carrying
 provenance):
@@ -21,7 +22,10 @@ provenance):
    full-pallas backward WINNING end-to-end (0.4261 vs 0.4063 MFU) at the
    535m shape even though isolated timing favored the hybrid — HBM
    pressure from the O(S^2) remat buffer dominates the kernel gap.
-   Ledger entries are ignored on a different device_kind.
+   Ledger entries are ignored on a different device_kind.  The ledger
+   ranks backends only: the kernels' tiles come from
+   ``flash_attention.choose_tiles``, and every Decision carries them with
+   the grid steps they give.
 2. **Measurement fallback** — on a ledger miss when the live backend is
    a TPU, time flash-vs-dense directly (scan-amortized, like the block
    autotuner). A backend that fails to compile or run is disqualified
@@ -35,12 +39,13 @@ provenance):
    that has none (tests).
 
 The router covers fwd and bwd independently: fwd=pallas + bwd=xla is the
-hybrid (flash forward, dense-remat backward) that wins at zero-padded
-head dims (d96).  ``nn/functional`` attention, the flash custom-vjp
-backward, ``incubate`` fused ops, ``inference/serving`` prefill, and
-``bench.py`` all consult this module, so a backend choice is made once,
-per shape, from data — and a re-bake after a hardware session updates
-every call site at once.
+hybrid (flash forward, dense-remat backward), which round 5 measured
+winning at zero-padded head dims (d96) and PR 27's end-to-end A/B at that
+shape measured losing (0.4137 against 0.5775 MFU).  ``nn/functional``
+attention, the flash custom-vjp backward, ``incubate`` fused ops,
+``inference/serving`` prefill, and ``bench.py`` all consult this module,
+so a backend choice is made once, per shape, from data — and a re-bake
+after a hardware session updates every call site at once.
 """
 
 from __future__ import annotations
@@ -52,9 +57,8 @@ from typing import Any, Optional
 
 from ...framework import flags as _flags
 
-__all__ = ["Decision", "route", "load_ledger", "ledger_blocks",
-           "packed_grid_enabled", "decision_log", "clear_routing_cache",
-           "LEDGER_FORMAT"]
+__all__ = ["Decision", "route", "load_ledger", "decision_log",
+           "clear_routing_cache", "LEDGER_FORMAT"]
 
 LEDGER_FORMAT = 1
 
@@ -78,18 +82,22 @@ class Decision:
     """One routed choice for an attention shape.
 
     fwd/bwd: 'pallas' or 'xla'.  fwd=pallas + bwd=xla is the hybrid
-    (flash forward, dense-remat backward).  blocks_* are (block_q,
-    block_k) VMEM tilings when the ledger recorded them (None = use the
-    kernel default).  packed_grid: whether the triangle-packed causal
-    grid is enabled for this decision's device.  source is machine-
-    readable ('ledger-e2e' | 'ledger' | 'measured-tpu' | 'proxy' |
-    'heuristic'); provenance is the human-readable audit string."""
+    (flash forward, dense-remat backward).  tiles: the
+    flash_attention.Tiles the Pallas kernels take at this shape
+    (flash_attention.tiles_for_shape, the resolver the kernels' own entry
+    points use; (resident, streamed, sub) rows of fa_fwd, fa_bwd_dq,
+    fa_bwd_dkv) and grid_steps: each kernel's grid steps in one call, by
+    kernel name — whichever backend was chosen, so the count that says
+    what the kernels would do is always there to read
+    (tests/test_flash_attention.py holds it to the grids of traced
+    calls).  source is machine-readable ('ledger-e2e' | 'ledger' |
+    'measured-tpu' | 'proxy' | 'heuristic'); provenance is the
+    human-readable audit string."""
 
     fwd: str
     bwd: str
-    blocks_fwd: Optional[tuple] = None
-    blocks_bwd: Optional[tuple] = None
-    packed_grid: bool = False
+    tiles: Any = None
+    grid_steps: Optional[dict] = None
     source: str = "heuristic"
     provenance: str = ""
 
@@ -188,22 +196,6 @@ def _match_entries(ledger, bh, sq, sk, d, dtype, causal, device_kind):
     return e2e, isolated
 
 
-def ledger_blocks(kind: str, bh: int, sq: int, sk: int, d: int, dtype,
-                  causal: bool, device_kind: Optional[str] = None):
-    """(block_q, block_k) the ledger recorded for this shape, or None.
-    Consulted by the flash kernels' block resolution when runtime
-    autotune is off — the versioned successor of _SHIPPED_BLOCKS."""
-    dk = device_kind or _device_kind(None)
-    _, iso = _match_entries(load_ledger(), bh, sq, sk, d,
-                            _norm_dtype(dtype), causal, dk)
-    if iso is None:
-        return None
-    blocks = iso.get("blocks_fwd" if kind == "fwd" else "blocks_bwd")
-    if blocks and blocks[0] <= sq and blocks[1] <= sk:
-        return tuple(blocks)
-    return None
-
-
 def epilogue_fusion_wins(bh: int, sq: int, sk: int, d: int, dtype,
                          causal: bool = True,
                          device_kind: Optional[str] = None) -> bool:
@@ -216,32 +208,6 @@ def epilogue_fusion_wins(bh: int, sq: int, sk: int, d: int, dtype,
     _, iso = _match_entries(load_ledger(), bh, sq, sk, d,
                             _norm_dtype(dtype), causal, dk)
     return bool(iso and iso.get("fused_epilogue_wins"))
-
-
-def packed_grid_enabled(platform: Optional[str] = None) -> bool:
-    """Resolve FLAGS_flash_packed_grid for the current device.
-
-    'auto' (the shipped default): ON under the Pallas interpreter (the
-    packing is numerically exact there — pinned by tier-1), and on real
-    TPUs only when the baked ledger marks packed_grid_validated for this
-    device_kind (chip_smoke.py's kernel phase tries the non-affine index
-    maps on the chip and reports whether Mosaic lowers them)."""
-    v = _flags.flag_value("flash_packed_grid")
-    if isinstance(v, bool):
-        return v
-    s = str(v).lower()
-    if s in ("1", "true", "on", "yes"):
-        return True
-    if s in ("0", "false", "off", "no"):
-        return False
-    # auto
-    import jax
-    on_tpu = jax.default_backend() == "tpu" and platform != "cpu"
-    if not on_tpu:
-        return True
-    led = load_ledger()
-    return bool(led and led.get("packed_grid_validated")
-                and led.get("device_kind") == _device_kind(platform))
 
 
 # --------------------------------------------------------------------------
@@ -257,8 +223,7 @@ _PROXY = {"peak_flops": 197e12, "eff_dense": 0.068, "eff_flash": 0.068,
           "hbm_bps": 820e9}
 
 
-def _proxy_ms(kind, bh, sq, sk, d, dtype, causal, backend,
-              packed: bool) -> float:
+def _proxy_ms(kind, bh, sq, sk, d, dtype, causal, backend) -> float:
     """Analytic max(compute, memory) time in ms. Deterministic: pure
     arithmetic on the shape key, no clocks, no randomness."""
     nbytes = 2 if dtype == "bfloat16" else 4
@@ -268,7 +233,8 @@ def _proxy_ms(kind, bh, sq, sk, d, dtype, causal, backend,
         fwd_flops *= 2.5                          # dS, dQ, dK, dV dots
         io *= 2.0
     if backend == "pallas":
-        flops = fwd_flops * (0.5 if (causal and packed) else 1.0)
+        # the causal sweep visits only what the diagonal leaves
+        flops = fwd_flops * (0.5 if causal else 1.0)
         t = max(flops / (_PROXY["peak_flops"] * _PROXY["eff_flash"]),
                 io / _PROXY["hbm_bps"])
     else:
@@ -375,18 +341,18 @@ def route(batch_heads: int, seq_q: int, seq_k: int, head_dim: int, dtype,
     batch_heads = batch * num_query_heads (the flash grid's parallel
     axis).  platform/device_kind default to the live jax backend; tests
     pass them explicitly to route for a device they are not running on.
-    Decisions are cached per (key, ledger path, mode flag)."""
+    Decisions are cached per (key, ledger path, mode and autotune flags)."""
     dtype = _norm_dtype(dtype)
     mode = _flags.flag_value("attention_router")
     dk = device_kind or _device_kind(platform)
     plat = platform or ("tpu" if dk.lower().startswith("tpu") else "cpu")
+    from .autotune import autotune_enabled   # it changes Decision.tiles
     key = (batch_heads, seq_q, seq_k, head_dim, dtype, bool(causal),
-           plat, dk, _ledger_path(), mode)
+           plat, dk, _ledger_path(), mode, autotune_enabled())
     hit = _route_cache.get(key)
     if hit is not None:
         return hit
 
-    packed = packed_grid_enabled(plat)
     dec = None
 
     if mode != "heuristic":
@@ -396,11 +362,7 @@ def route(batch_heads: int, seq_q: int, seq_k: int, head_dim: int, dtype,
         if e2e is not None:
             dec = Decision(
                 fwd=e2e.get("fwd", "pallas"), bwd=e2e.get("bwd", "pallas"),
-                blocks_fwd=tuple(iso["blocks_fwd"]) if iso and
-                iso.get("blocks_fwd") else None,
-                blocks_bwd=tuple(iso["blocks_bwd"]) if iso and
-                iso.get("blocks_bwd") else None,
-                packed_grid=packed, source="ledger-e2e",
+                source="ledger-e2e",
                 provenance=(
                     f"ledger v{led.get('version')} r{led.get('round')} "
                     f"end-to-end [{e2e.get('config')}] on "
@@ -409,11 +371,7 @@ def route(batch_heads: int, seq_q: int, seq_k: int, head_dim: int, dtype,
         elif iso is not None:
             dec = Decision(
                 fwd=iso.get("fwd", "pallas"), bwd=iso.get("bwd", "pallas"),
-                blocks_fwd=tuple(iso["blocks_fwd"]) if
-                iso.get("blocks_fwd") else None,
-                blocks_bwd=tuple(iso["blocks_bwd"]) if
-                iso.get("blocks_bwd") else None,
-                packed_grid=packed, source="ledger",
+                source="ledger",
                 provenance=(
                     f"ledger v{led.get('version')} r{led.get('round')} "
                     f"measured on {led.get('device_kind')} at bh="
@@ -438,20 +396,19 @@ def route(batch_heads: int, seq_q: int, seq_k: int, head_dim: int, dtype,
             fwd = min(ran["fwd"], key=lambda b: ms[("fwd", b)])
             bwd = min(ran["bwd"], key=lambda b: ms[("bwd", b)])
             dec = Decision(
-                fwd=fwd, bwd=bwd, packed_grid=packed,
-                source="measured-tpu",
+                fwd=fwd, bwd=bwd, source="measured-tpu",
                 provenance=("measured live on "
                             f"{dk} (ledger miss): "
                             + json.dumps({f"{k[0]}_{k[1]}": round(v, 3)
                                           for k, v in ms.items()})))
         elif plat != "tpu" and not live_tpu:
             est = {(k, b): _proxy_ms(k, batch_heads, seq_q, seq_k,
-                                     head_dim, dtype, causal, b, packed)
+                                     head_dim, dtype, causal, b)
                    for k in ("fwd", "bwd") for b in ("pallas", "xla")}
             fwd = min(("pallas", "xla"), key=lambda b: est[("fwd", b)])
             bwd = min(("pallas", "xla"), key=lambda b: est[("bwd", b)])
             dec = Decision(
-                fwd=fwd, bwd=bwd, packed_grid=packed, source="proxy",
+                fwd=fwd, bwd=bwd, source="proxy",
                 provenance=("analytic roofline proxy (CPU backend; "
                             "NOT a measurement — assumes the bf16-operand "
                             "kernels reach dense-einsum MXU efficiency): "
@@ -460,13 +417,20 @@ def route(batch_heads: int, seq_q: int, seq_k: int, head_dim: int, dtype,
 
     if dec is None:
         b = _heuristic(batch_heads, seq_q, seq_k, head_dim)
-        dec = Decision(fwd=b, bwd="pallas", packed_grid=packed,
-                       source="heuristic",
+        dec = Decision(fwd=b, bwd="pallas", source="heuristic",
                        provenance=("legacy seq/head_dim thresholds "
                                    "(calibrated to the retired f32-operand "
                                    "kernels; no ledger entry, no "
                                    "measurement on this backend)"))
 
+    # what the flash kernels take at this shape, from the resolver their
+    # own entry points use
+    from .flash_attention import tiles_for_shape
+    tiles = tiles_for_shape(batch_heads, seq_q, seq_k, head_dim, dtype,
+                            causal)
+    dec = dataclasses.replace(
+        dec, tiles=tiles,
+        grid_steps=tiles.grid_steps(batch_heads, seq_q, seq_k))
     _route_cache[key] = dec
     _decision_log.append((key[:6], dec))
     del _decision_log[:-256]  # bound the audit log

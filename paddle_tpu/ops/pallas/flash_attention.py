@@ -4,30 +4,45 @@ reference capability: paddle/phi/kernels/gpu/flash_attn_kernel.cu and
 flash_attn_grad_kernel.cu (FlashAttention-2 via dynload) +
 python/paddle/nn/functional/flash_attention.py.
 
-TPU-native design (not a CUDA port):
-- Forward: grid (batch*heads, q_blocks, k_blocks). Q/K/V blocks are DMA'd
-  per grid step by BlockSpec — no whole-K/V-in-VMEM residency, so sequence
-  length is bounded by HBM, not VMEM. The online-softmax running
-  (m, l, acc) state lives in VMEM scratch that persists across the
-  (sequential, innermost) k-block grid dimension. The forward also emits
-  the per-row logsumexp for the backward.
+TPU-native design (not a CUDA port). Three kernels, `fa_fwd`, `fa_bwd_dq`
+and `fa_bwd_dkv`, share one geometry (`Tiles`, chosen from the shape by
+`choose_tiles`): a RESIDENT tile of one side stays in VMEM while the
+grid's innermost (sequential) axis streams large tiles of the other side
+past it, and the kernel loops over SUB-blocks of the streamed tile, so the
+score tile stays bounded while a call makes a few hundred grid steps. At
+the sizes `choose_tiles` hands out the streamed tile is the whole padded
+sequence whenever that fits VMEM: K and V (forward, dQ) or Q and dO (dKV)
+are then read from HBM once per head.
+
+- Forward: grid (batch*heads, q tiles, k tiles). The online-softmax
+  running (m, l, acc) state lives in VMEM scratch across the k axis; the
+  forward also emits the per-row logsumexp, as a (1, rows) row.
 - Backward: the FlashAttention-2 split. delta = rowsum(dO * O) is a cheap
-  XLA elementwise reduce. dQ kernel: grid (bh, q_blocks, k_blocks),
-  accumulates scale * dS @ K into VMEM scratch. dK/dV kernel: grid
-  (bh, k_blocks, q_blocks), accumulates dS^T @ Q and P^T @ dO. P is
-  rematerialized per block from (Q, K, lse) — nothing O(S^2) is ever
-  stored.
-- MXU does the matmuls with fp32 accumulation (preferred_element_type);
-  VPU does the softmax pieces. Causal: blocks strictly above the diagonal
-  skip compute via @pl.when; the diagonal block is masked with
-  broadcasted_iota. Cross-length causal uses the bottom-right-aligned
-  convention (offset = seq_k - seq_q), matching the dense reference.
+  XLA elementwise reduce. dQ: grid (bh, q tiles, k tiles), accumulates
+  dS @ K. dK/dV: grid (kv heads, k tiles, group reps, q tiles), computes
+  the TRANSPOSED score tile (keys on sublanes, queries on lanes) so that
+  lse and delta enter as rows and dV, dK are plain P^T @ dO, dS^T @ Q
+  products; GQA's reduction over the query-head group happens in its
+  scratch. P is rematerialized per sub-block from (Q, K, lse) — nothing
+  O(S^2) is ever stored, and no per-row scalar is broadcast in HBM.
+- MXU does the matmuls on native (bf16) operands with fp32 accumulation;
+  the softmax scale is folded into the resident tile once. VPU does the
+  softmax pieces in fp32.
+- Causal: ONE schedule. The rectangular grid's streamed index map is
+  clamped to the last (first, for dKV) tile the resident tile needs, so a
+  step above the diagonal repeats the previous block index and issues no
+  DMA; inside a tile the sub-block loops' bounds skip what the diagonal
+  hides, and the iota mask is built only on sub-blocks the diagonal (or
+  the padded key tail) crosses. Cross-length causal uses the
+  bottom-right-aligned convention (offset = seq_k - seq_q), matching the
+  dense reference.
 
 On non-TPU backends the kernels run under the Pallas interpreter (tests).
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -38,85 +53,209 @@ from jax.experimental.pallas import tpu as pltpu
 from ...framework import flags as _flags
 
 NEG_INF = -1e30
-_LANES = 128  # store per-row scalars broadcast across one lane tile
+_LANES = 128  # scratch holds per-row scalars broadcast across one lane tile
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
 
 
-def _causal_mask(s, qi, kj, block_q, block_k, offset):
-    rows = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return jnp.where(rows + offset >= cols, s, NEG_INF)
+# --------------------------------------------------------------------------
+# tile geometry
+# --------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class Tiles:
+    """Tile geometry of the three kernels at one attention shape. Each
+    triple is (resident, streamed, sub) rows: the resident side's tile,
+    the DMA'd tile of the side streamed past it, and the sub-block of the
+    streamed tile that one iteration of the in-kernel loop handles (sub
+    divides streamed). fwd and dq: resident = query rows, streamed = key
+    rows. dkv: resident = key rows, streamed = query rows."""
+
+    fwd: tuple
+    dq: tuple
+    dkv: tuple
+
+    def grid_steps(self, batch_heads: int, seq_q: int, seq_k: int) -> dict:
+        """Grid steps of one call of each kernel, by the kernel's name."""
+        def n(seq, tile):
+            return -(-seq // tile)
+        return {
+            "fa_fwd": batch_heads * n(seq_q, self.fwd[0])
+            * n(seq_k, self.fwd[1]),
+            "fa_bwd_dq": batch_heads * n(seq_q, self.dq[0])
+            * n(seq_k, self.dq[1]),
+            "fa_bwd_dkv": batch_heads * n(seq_k, self.dkv[0])
+            * n(seq_q, self.dkv[1]),
+        }
 
 
-def _ktail_mask(s, kj, block_q, block_k, seq_k):
-    cols = kj * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    return jnp.where(cols < seq_k, s, NEG_INF)
+# (resident, sub) rows a kernel takes when the sequence is long enough:
+# measured on a TPU v5e at bh 64, seq 2048, d 128, bf16, causal (PERF.md
+# section 6, PR 27). The forward's time follows the number of sub-blocks
+# it visits, not their size — every visit reduces each row across lanes
+# twice, for the running max and sum — so it takes the largest; the
+# backward kernels reduce nothing and lose beyond 512, to the diagonal's
+# waste. FLAGS_use_autotune times _ROW_CANDIDATES instead.
+_TILE_ROWS = {"fwd": (1024, 1024), "dq": (512, 512), "dkv": (512, 512)}
+_ROW_CANDIDATES = ((256, 256), (512, 256), (512, 512), (1024, 512),
+                   (1024, 1024))
+
+# what a kernel's buffers may take when the streamed tile is sized, and the
+# most Mosaic is ever asked for (a v5e core has 128 MiB of VMEM; the
+# compiler's default scoped limit is 16 MiB)
+_VMEM_BUDGET = 40 << 20
+_VMEM_LIMIT_MAX = 100 << 20
 
 
-def _block_needed(qi, kj, block_q, block_k, causal, offset):
-    if not causal:
-        return True
-    # any (row, col) with row + offset >= col in this block pair?
-    return (qi * block_q + block_q - 1 + offset) >= (kj * block_k)
+def _round_up(n, m):
+    return -(-n // m) * m
 
 
-_flags.define_flag(
-    "flash_packed_grid", "auto",
-    "causal flash kernels iterate only the lower-triangle (q,k) block "
-    "pairs instead of a rectangular grid with half the steps masked off "
-    "(saves the skipped steps' k/v DMAs and grid overhead). 'auto' (the "
-    "default since the bf16 finalization): ON under the Pallas "
-    "interpreter (numerically exact, pinned by tier-1) and on real TPUs "
-    "only when the baked attention ledger marks packed_grid_validated "
-    "for the device — the sqrt-based index maps are non-affine, so the "
-    "ledger flips this per-device once a chip run (chip_smoke.py's "
-    "kernel phase reports it) shows Mosaic lowers them and they match. "
-    "on/off force it either way. NOTE: read at TRACE time — set the env "
-    "var before process start (or clear jit caches); set_flags after a "
-    "shape compiled does not retrace it.")
+def _fit(seq, rows):
+    """The largest of rows, rows/2, ... (never under one lane tile) whose
+    padding of `seq` wastes at most an eighth of it."""
+    while rows > _LANES and (_round_up(seq, rows) - seq) * 8 > seq:
+        rows //= 2
+    return rows
 
 
-def _packing_on():
-    from .attention_router import packed_grid_enabled
-    return packed_grid_enabled()
+def vmem_bytes(kind, tile, head_dim, itemsize):
+    """Bytes of VMEM one grid step of kernel `kind` holds at `tile`:
+    double-buffered blocks, scratch, and the score-sized temporaries of one
+    sub-block. The limit handed to Mosaic is computed from this."""
+    res, streamed, sub = tile
+    f32 = 4
+    row = head_dim * itemsize
+    temps = 4 * res * sub * f32             # s, p, dp, ds of one sub-block
+    if kind == "fwd":
+        blocks = 2 * (2 * res * row         # q, out
+                      + 2 * streamed * row  # k, v
+                      + 8 * res * f32)      # lse row, one sublane tile
+        scratch = res * row + 2 * res * _LANES * f32 + res * head_dim * f32
+    elif kind == "dq":
+        blocks = 2 * (3 * res * row         # q, dO, dq
+                      + 2 * streamed * row  # k, v
+                      + 2 * 8 * res * f32)  # lse, delta rows
+        scratch = res * row + 2 * res * _LANES * f32 + res * head_dim * f32
+    else:
+        blocks = 2 * (4 * res * row         # k, v, dk, dv
+                      + 2 * streamed * row  # q, dO
+                      + 2 * 8 * streamed * f32)   # lse, delta rows
+        scratch = res * row + 2 * res * head_dim * f32
+    return blocks + scratch + temps
 
 
-def _tri_decode(p):
-    """Linear triangle index -> (qi, kj) with kj <= qi (row-major packing:
-    p = qi*(qi+1)/2 + kj). The causal-packed grid iterates ONLY the lower
-    triangle of (q block, k block) pairs — a full rectangular grid spends
-    half its steps (and their k/v block DMAs) on pairs the causal mask
-    fully discards. f32 sqrt is exact for the sizes involved (p < 2^23);
-    the +-1 correction guards the perfect-square boundary cases."""
-    pf = p.astype(jnp.float32)
-    qi = jnp.floor((jnp.sqrt(8.0 * pf + 1.0) - 1.0) * 0.5).astype(jnp.int32)
-    tri = qi * (qi + 1) // 2
-    qi = jnp.where(p < tri, qi - 1, qi)
-    qi = jnp.where(p >= (qi + 1) * (qi + 2) // 2, qi + 1, qi)
-    kj = p - qi * (qi + 1) // 2
-    return qi, kj
+def choose_tiles(seq_q, seq_k, head_dim, itemsize,
+                 vmem_budget=_VMEM_BUDGET, rows=None) -> Tiles:
+    """The one place tiles are chosen, from the shape alone. Resident and
+    sub tiles are the kernel's `_TILE_ROWS`, halved while padding a short
+    or ragged sequence to them would waste more than an eighth of it; the
+    streamed tile is the whole padded sequence, halved while the kernel's
+    buffers exceed `vmem_budget`. `rows` overrides `_TILE_ROWS` for some
+    kernels (the autotuner's candidates)."""
+    rows = {**_TILE_ROWS, **(rows or {})}
+
+    def one(kind, seq_res, seq_str):
+        res = _fit(seq_res, rows[kind][0])
+        sub = _fit(seq_str, rows[kind][1])
+        streamed = _round_up(seq_str, sub)
+        while streamed > sub and vmem_bytes(
+                kind, (res, streamed, sub), head_dim, itemsize) > vmem_budget:
+            streamed = _round_up(streamed // 2, sub)
+        return (res, streamed, sub)
+
+    return Tiles(fwd=one("fwd", seq_q, seq_k), dq=one("dq", seq_q, seq_k),
+                 dkv=one("dkv", seq_k, seq_q))
 
 
-def _tri_maps(g):
-    """(qmap, kmap) BlockSpec index maps for the packed (bh, tri) grid —
-    shared by the fwd and dQ kernels (the dKV kernel's reversed-row
-    staircase variant lives at its call site)."""
-    def qmap(b, p):
-        qi, _ = _tri_decode(p)
-        return (b, qi, 0)
+# --------------------------------------------------------------------------
+# the sweep: which sub-blocks a resident tile visits, and which need a mask
+# --------------------------------------------------------------------------
 
-    def kmap(b, p):
-        _, kj = _tri_decode(p)
-        return (b // g, kj, 0)
-    return qmap, kmap
+def _fdiv(x, n):
+    """floor(max(x, 0) / n) on traced int32 scalars."""
+    return jax.lax.div(jnp.maximum(x, 0), jnp.int32(n))
 
 
-def _fa_fwd_kernel(q_ref, k_ref, v_ref, *refs,
-                   causal: bool, scale: float, seq_k: int, block_q: int,
-                   block_k: int, offset: int, mask_k_tail: bool,
-                   packed: bool = False, epilogue: bool = False,
+def _key_bounds(row0, block_q, col0, block_k, n_sub, causal, offset, seq_k):
+    """Query rows [row0, row0 + block_q) against key sub-blocks s = 0..n_sub
+    of block_k columns from col0: -> (n_full, hi). Sub-blocks [0, n_full)
+    are attended whole, [n_full, hi) need the iota mask (the diagonal or
+    the padded key tail crosses them), [hi, n_sub) hold nothing."""
+    n_full = hi = n_sub
+    if causal:
+        hi = jnp.minimum(hi, _fdiv(row0 + block_q - 1 + offset - col0
+                                   + block_k, block_k))
+        n_full = jnp.minimum(n_full, _fdiv(row0 + offset - col0 + 1,
+                                           block_k))
+    if seq_k is not None:
+        hi = jnp.minimum(hi, _fdiv(seq_k - col0 + block_k - 1, block_k))
+        n_full = jnp.minimum(n_full, _fdiv(seq_k - col0, block_k))
+    return n_full, hi
+
+
+def _query_bounds(col0, block_k, row0, block_q, n_sub, causal, offset,
+                  seq_q):
+    """Key rows [col0, col0 + block_k) against query sub-blocks s = 0..n_sub
+    of block_q rows from row0: -> (lo, first_full, hi). [lo, first_full)
+    cross the diagonal, [first_full, hi) are attended whole; below lo the
+    keys are in the future of every query, from hi on the rows are pad."""
+    lo = first_full = 0
+    hi = n_sub
+    if seq_q is not None:
+        hi = jnp.minimum(hi, _fdiv(seq_q - row0 + block_q - 1, block_q))
+    if causal:
+        lo = jnp.minimum(hi, _fdiv(col0 - offset - row0, block_q))
+        first_full = jnp.minimum(hi, _fdiv(
+            col0 + block_k - 1 - offset - row0 + block_q - 1, block_q))
+    return lo, first_full, hi
+
+
+def _keep(shape, q_axis, row0, col0, causal, offset, seq_k):
+    """Attended pairs of one score tile whose queries run along `q_axis`
+    from row0 and whose keys run along the other axis from col0."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    keep = None
+    if causal:
+        keep = rows + offset >= cols
+    if seq_k is not None:
+        tail = cols < seq_k
+        keep = tail if keep is None else keep & tail
+    return keep
+
+
+def _loop(start, stop, body):
+    """body(s) for s in [start, stop); the bounds may be traced."""
+    def step(s, carry):
+        body(s)
+        return carry
+    jax.lax.fori_loop(start, stop, step, 0)
+
+
+def _scaled(ref, scale):
+    """The resident tile with the softmax scale folded in, once, in the
+    operand dtype (the MXU takes it as it is). Where the scale is no power
+    of two (d 128: 2**-3.5) a bf16 tile takes one more rounding than
+    scaling the f32 scores would give it: 2**-9 relative at most on each
+    element of q (of k in dKV), the size of the rounding q and k already
+    carry."""
+    x = ref[0]
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+def _column(row_ref, rows):
+    """A (1, rows) block of per-row scalars as a lane-broadcast
+    (rows, _LANES) column tile."""
+    return jnp.transpose(jnp.broadcast_to(row_ref[0], (_LANES, rows)))
+
+
+# --------------------------------------------------------------------------
+# kernels
+# --------------------------------------------------------------------------
+
+def _fa_fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
+                   seq_k, tile: tuple, offset: int, epilogue: bool = False,
                    rms_eps: float = 1e-6, rms_d: int = 0):
     # optional fused epilogue (FlashFuser-style widened fusion): two extra
     # inputs — residual block + lane-broadcast RMSNorm gamma — and the
@@ -125,58 +264,52 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *refs,
     # axis is the head dim (rms_d = TRUE d, so zero-pad columns don't
     # skew the mean).
     if epilogue:
-        res_ref, w_ref, o_ref, lse_ref, m_s, l_s, acc_s = refs
+        res_ref, w_ref, o_ref, lse_ref, qs_s, m_s, l_s, acc_s = refs
     else:
-        o_ref, lse_ref, m_s, l_s, acc_s = refs
-    if packed:   # causal lower-triangle grid: (bh, tri(nq))
-        qi, kj = _tri_decode(pl.program_id(1))
-        is_last = kj == qi   # kj_max(qi) == qi when block_q == block_k
-    else:
-        qi = pl.program_id(1)
-        kj = pl.program_id(2)
-        is_last = kj == pl.num_programs(2) - 1
+        o_ref, lse_ref, qs_s, m_s, l_s, acc_s = refs
+    block_q, block_km, block_k = tile
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
+    row0 = qi * block_q
+    col0 = kj * block_km
 
     @pl.when(kj == 0)
     def _init():
+        qs_s[...] = _scaled(q_ref, scale)
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    def _compute():
+    def visit(s, masked):
         # dots run on NATIVE (bf16) operands with f32 accumulation — the
         # MXU's full-rate mode and exactly the dense XLA path's precision
-        # (einsum + preferred_element_type=f32). Upcasting operands to
-        # f32 first quarters MXU throughput; r5 measured the f32-operand
-        # flavor of this kernel at 0.86x dense fwd / 0.52x dense bwd.
-        q = q_ref[0]                              # (block_q, d)
-        k = k_ref[0]                              # (block_k, d)
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if mask_k_tail:
-            s = _ktail_mask(s, kj, block_q, block_k, seq_k)
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, offset)
+        # (einsum + preferred_element_type=f32)
+        c = pl.multiple_of(s * block_k, block_k)
+        k = k_ref[0, pl.ds(c, block_k), :]
+        v = v_ref[0, pl.ds(c, block_k), :]
+        sc = jax.lax.dot_general(qs_s[...], k, _NT,
+                                 preferred_element_type=jnp.float32)
+        if masked:
+            sc = jnp.where(_keep(sc.shape, 0, row0, col0 + c, causal, offset,
+                                 seq_k), sc, NEG_INF)
         m_prev = m_s[...][:, :1]
         l_prev = l_s[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc_s[...] = acc_s[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, _NN, preferred_element_type=jnp.float32)
         m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
         l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
 
-    if causal and not packed:
-        pl.when(_block_needed(qi, kj, block_q, block_k, causal, offset))(
-            _compute)
-    else:
-        _compute()   # packed grid contains only needed blocks
+    n_full, hi = _key_bounds(row0, block_q, col0, block_k,
+                             block_km // block_k, causal, offset, seq_k)
+    _loop(0, n_full, functools.partial(visit, masked=False))
+    if causal or seq_k is not None:
+        _loop(n_full, hi, functools.partial(visit, masked=True))
 
-    @pl.when(is_last)
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _flush():
         l = jnp.maximum(l_s[...][:, :1], 1e-30)
         out = acc_s[...] / l
@@ -188,132 +321,119 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *refs,
             out = h * jax.lax.rsqrt(ms + rms_eps) * \
                 w_ref[...][:1, :].astype(jnp.float32)
         o_ref[0] = out.astype(o_ref.dtype)
-        # lane-expanded (block_q, _LANES) write: TPU block shapes need the
-        # last two dims tiled (8, 128); a (1, block_q) row per grid step is
-        # unlowerable. m_s/l_s already hold the row value in every lane.
-        # (Same layout as jax's official TPU flash kernel's l/m outputs.)
-        lse_ref[0] = m_s[...] + jnp.log(jnp.maximum(l_s[...], 1e-30))
+        # m_s/l_s hold the row's value in every lane: one transpose turns
+        # the column into the (1, block_q) row the backward reads
+        lse = m_s[...] + jnp.log(jnp.maximum(l_s[...], 1e-30))
+        lse_ref[0] = jnp.transpose(lse)[:1, :]
 
 
 def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                  dq_s, *, causal: bool, scale: float, seq_k: int,
-                  block_q: int, block_k: int, offset: int,
-                  mask_k_tail: bool, packed: bool = False):
-    if packed:   # causal lower-triangle grid: (bh, tri(nq))
-        qi, kj = _tri_decode(pl.program_id(1))
-        is_last = kj == qi
-    else:
-        qi = pl.program_id(1)
-        kj = pl.program_id(2)
-        is_last = kj == pl.num_programs(2) - 1
+                  qs_s, lse_s, delta_s, dq_s, *, causal: bool, scale: float,
+                  seq_k, tile: tuple, offset: int):
+    block_q, block_km, block_k = tile
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
+    row0 = qi * block_q
+    col0 = kj * block_km
 
     @pl.when(kj == 0)
     def _init():
+        qs_s[...] = _scaled(q_ref, scale)
+        # the row scalars change only with the resident q tile: turned
+        # into columns once here, not per sub-block
+        lse_s[...] = _column(lse_ref, block_q)
+        delta_s[...] = _column(delta_ref, block_q)
         dq_s[...] = jnp.zeros_like(dq_s)
 
-    def _compute():
+    def visit(s, masked):
         # bf16 operands + f32 accumulation on every dot (see fwd kernel)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
+        c = pl.multiple_of(s * block_k, block_k)
+        k = k_ref[0, pl.ds(c, block_k), :]
+        v = v_ref[0, pl.ds(c, block_k), :]
         do = do_ref[0]
-        lse = lse_ref[0][:, :1]                   # (block_q, 1) of lanes
-        delta = delta_ref[0][:, :1]
-        s = scale * jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if mask_k_tail:
-            s = _ktail_mask(s, kj, block_q, block_k, seq_k)
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, offset)
-        p = jnp.exp(s - lse)                      # (block_q, block_k)
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(k.dtype)
-        dq_s[...] += scale * jax.lax.dot_general(
-            ds, k, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        sc = jax.lax.dot_general(qs_s[...], k, _NT,
+                                 preferred_element_type=jnp.float32)
+        if masked:
+            sc = jnp.where(_keep(sc.shape, 0, row0, col0 + c, causal, offset,
+                                 seq_k), sc, NEG_INF)
+        p = jnp.exp(sc - lse_s[...][:, :1])       # (block_q, block_k)
+        dp = jax.lax.dot_general(do, v, _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_s[...][:, :1])).astype(k.dtype)
+        dq_s[...] += jax.lax.dot_general(ds, k, _NN,
+                                         preferred_element_type=jnp.float32)
 
-    if causal and not packed:
-        pl.when(_block_needed(qi, kj, block_q, block_k, causal, offset))(
-            _compute)
-    else:
-        _compute()   # packed grid contains only needed blocks
+    n_full, hi = _key_bounds(row0, block_q, col0, block_k,
+                             block_km // block_k, causal, offset, seq_k)
+    _loop(0, n_full, functools.partial(visit, masked=False))
+    if causal or seq_k is not None:
+        _loop(n_full, hi, functools.partial(visit, masked=True))
 
-    @pl.when(is_last)
+    @pl.when(kj == pl.num_programs(2) - 1)
     def _flush():
-        dq_ref[0] = dq_s[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_s[...] * scale).astype(dq_ref.dtype)
 
 
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dk_ref, dv_ref, dk_s, dv_s, *, causal: bool, scale: float,
-                   seq_k: int, block_q: int, block_k: int, offset: int,
-                   mask_k_tail: bool, n_rep: int = 1, packed_nq: int = 0):
-    # grid (bh_kv, k blocks, q-head group reps, q blocks): the scratch
-    # accumulates over BOTH the group axis and the q blocks, flushing once
-    # per kv block — this is how GQA's dK/dV reduction happens in-kernel.
-    # Packed (causal, square blocks): grid (bh_kv, tri(nq), reps) where the
-    # triangle index runs (kj, qi >= kj) pairs via u = nq-1-kj, w = qi-kj
-    # (so per-kj pairs are consecutive and the scratch flushes per kv block)
-    if packed_nq:
-        u, w = _tri_decode(pl.program_id(1))
-        kj = packed_nq - 1 - u
-        qi = kj + w
-        rr = pl.program_id(2)
-        first = (w == 0) & (rr == 0)
-        last = (w == u) & (rr == n_rep - 1)
-    else:
-        kj = pl.program_id(1)
-        rr = pl.program_id(2)
-        qi = pl.program_id(3)
-        first = (qi == 0) & (rr == 0)
-        last = (qi == pl.num_programs(3) - 1) & (rr == n_rep - 1)
+                   dk_ref, dv_ref, ks_s, dk_s, dv_s, *, causal: bool,
+                   scale: float, seq_q, tile: tuple, offset: int,
+                   n_rep: int = 1):
+    # grid (bh_kv, k tiles, q-head group reps, q tiles): the scratch
+    # accumulates over BOTH the group axis and the q tiles, flushing once
+    # per kv tile — this is how GQA's dK/dV reduction happens in-kernel.
+    # The score tile is TRANSPOSED (keys on sublanes, queries on lanes):
+    # lse and delta broadcast down sublanes as the (1, block_q) rows they
+    # arrive as, and dV, dK are plain (block_k, block_q) @ (block_q, d).
+    # Padded key rows need no mask here: a key row's dK and dV depend on
+    # that row alone, and the pad rows are sliced off.
+    block_k, block_qm, block_q = tile
+    kj = pl.program_id(1)
+    rr = pl.program_id(2)
+    qi = pl.program_id(3)
+    col0 = kj * block_k
+    row0 = qi * block_qm
 
-    @pl.when(first)
+    @pl.when((qi == 0) & (rr == 0))
     def _init():
+        ks_s[...] = _scaled(k_ref, scale)
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    def _compute():
+    def visit(s, masked):
         # bf16 operands + f32 accumulation on every dot (see fwd kernel)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0][:, :1]
-        delta = delta_ref[0][:, :1]
-        s = scale * jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        if mask_k_tail:
-            s = _ktail_mask(s, kj, block_q, block_k, seq_k)
-        if causal:
-            s = _causal_mask(s, qi, kj, block_q, block_k, offset)
-        p = jnp.exp(s - lse)
-        p_lo = p.astype(do.dtype)
+        r = pl.multiple_of(s * block_q, block_q)
+        q = q_ref[0, pl.ds(r, block_q), :]
+        do = do_ref[0, pl.ds(r, block_q), :]
+        st = jax.lax.dot_general(ks_s[...], q, _NT,
+                                 preferred_element_type=jnp.float32)
+        if masked:
+            st = jnp.where(_keep(st.shape, 1, row0 + r, col0, causal, offset,
+                                 None), st, NEG_INF)
+        pt = jnp.exp(st - lse_ref[0, s])          # (block_k, block_q)
         dv_s[...] += jax.lax.dot_general(
-            p_lo, do, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)   # (block_k, d)
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(q.dtype)
-        dk_s[...] += scale * jax.lax.dot_general(
-            ds, q, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            pt.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(v_ref[0], do, _NT,
+                                  preferred_element_type=jnp.float32)
+        dst = (pt * (dpt - delta_ref[0, s])).astype(q.dtype)
+        dk_s[...] += jax.lax.dot_general(dst, q, _NN,
+                                         preferred_element_type=jnp.float32)
 
-    if causal and not packed_nq:
-        pl.when(_block_needed(qi, kj, block_q, block_k, causal, offset))(
-            _compute)
-    else:
-        _compute()   # packed grid contains only needed blocks
+    lo, first_full, hi = _query_bounds(col0, block_k, row0, block_q,
+                                       block_qm // block_q, causal, offset,
+                                       seq_q)
+    if causal:
+        _loop(lo, first_full, functools.partial(visit, masked=True))
+    _loop(first_full, hi, functools.partial(visit, masked=False))
 
-    @pl.when(last)
+    @pl.when((qi == pl.num_programs(3) - 1) & (rr == n_rep - 1))
     def _flush():
-        dk_ref[0] = dk_s[...].astype(dk_ref.dtype)
+        dk_ref[0] = (dk_s[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_s[...].astype(dv_ref.dtype)
 
+
+# --------------------------------------------------------------------------
+# calls
+# --------------------------------------------------------------------------
 
 def _pad_to(x, axis, multiple):
     n = x.shape[axis]
@@ -336,107 +456,29 @@ def _sds(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
-def _block_sizes(sq, sk, block_q, block_k):
-    return min(block_q, sq), min(block_k, sk)
+def _params(kind, tile, head_dim, itemsize, semantics):
+    """Mosaic's parameters of one call: the grid axes' semantics, and a
+    VMEM limit of twice the buffers' arithmetic (the compiler's own
+    temporaries are not in it), never under 32 MiB."""
+    est = vmem_bytes(kind, tile, head_dim, itemsize)
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics,
+        vmem_limit_bytes=min(max(2 * est, 32 << 20), _VMEM_LIMIT_MAX))
 
 
-# candidate (block_q, block_k) VMEM tilings for the autotuner — the TPU
-# analog of the reference's per-algorithm candidate list (auto_tune_base.h).
-# Large tiles are cheap in VMEM (512x512: ~1.3MB of block buffers vs the
-# ~128MB budget) and cut grid-iteration overhead 8-16x vs 128x128.
-_BLOCK_CANDIDATES = ((128, 128), (256, 128), (128, 256), (256, 256),
-                     (512, 128), (128, 512), (256, 512), (512, 256),
-                     (512, 512))
+def _last_key_tile(i, block_q, block_km, n_km, offset):
+    """The last streamed key tile query tile i attends under the causal
+    mask: the index map clamps to it, so the steps beyond repeat its block
+    index and fetch nothing."""
+    return jnp.minimum(_fdiv(i * block_q + block_q - 1 + offset, block_km),
+                       n_km - 1)
 
 
-# Shipped block-size table keyed by (kind, seq bucket, head_dim),
-# consulted when autotune is off so production gets measured tiles
-# without paying a tuning pass. Populate from a hardware autotune run:
-# tools/flash_vs_xla.py (on the chip) then tools/bake_flash_blocks.py
-# prints the literal. Empty or missing entries fall back to (128, 128).
-_SHIPPED_BLOCKS = {}
-
-
-def _shipped_blocks(kind, sq, d, device_kind):
-    if "v5 lite" not in device_kind:
-        return None
-    bucket = 1024 if sq <= 1024 else (2048 if sq <= 2048 else 4096)
-    return _SHIPPED_BLOCKS.get((kind, bucket, d))
-
-
-def _tuned_blocks(kind, bh, sq, sk, d, dtype, causal, interpret):
-    """Resolve (block_q, block_k): the baked attention ledger (versioned,
-    device-tagged — tools/bake_flash_blocks.py --ledger), the legacy
-    _SHIPPED_BLOCKS literal, the runtime-timed winner when
-    FLAGS_use_autotune is on, else (128, 128). Timing runs on synthetic
-    zeros, so this works even while the caller is being traced."""
-    from .autotune import autotune, autotune_enabled
-    if not autotune_enabled():
-        if not interpret:
-            from .attention_router import ledger_blocks
-            hit = ledger_blocks(kind, bh, sq, sk, d, dtype, causal)
-            if hit:
-                return hit
-        if _SHIPPED_BLOCKS and not interpret:
-            hit = _shipped_blocks(kind, sq, d,
-                                  getattr(jax.devices()[0], "device_kind", ""))
-            if hit and hit[0] <= sq and hit[1] <= sk:
-                return hit
-        return 128, 128
-    dev = jax.devices()[0]
-    # tb (the clamped tuning batch*heads) is part of the key: block ranking
-    # depends on grid parallelism, so a winner timed at 2 heads must not be
-    # served to a 64-head caller
-    tb = min(bh, 64)
-    key = (kind, tb, sq, sk, d, str(dtype), bool(causal), dev.device_kind)
-
-    def make_runner(cfg):
-        bq, bk = cfg
-        if bq > sq or bk > sk:
-            raise ValueError("block larger than sequence")
-        # tune at (close to) the caller's real batch*heads: block choice
-        # interacts with grid parallelism, and a 2-head proxy ranked
-        # candidates differently from the bh=64 train shape on v5e
-        q = jnp.zeros((tb, sq, d), dtype)
-        k = jnp.zeros((tb, sk, d), dtype)
-        v = jnp.zeros((tb, sk, d), dtype)
-        # each candidate runs 8 iterations inside ONE compiled scan so
-        # per-dispatch launch overhead does not rank the candidates. The
-        # carry feeds q so the body can't be hoisted.
-        if kind == "fwd":
-            def step(qq):
-                o, _ = _flash_fwd_bhsd(qq, k, v, causal, 1.0, block_q=bq,
-                                       block_k=bk, interpret=interpret)
-                return jnp.sum(o.astype(jnp.float32))
-        else:
-            # o / lse only need the forward's shapes: timing is on zeros
-            lse = jnp.zeros((tb, sq), jnp.float32)
-
-            def step(qq):
-                outs = _flash_bwd_bhsd(qq, k, v, q, lse, q, causal, 1.0,
-                                       block_q=bq, block_k=bk,
-                                       interpret=interpret)
-                return sum(jnp.sum(x.astype(jnp.float32)) for x in outs)
-
-        @jax.jit
-        def loop():
-            def body(c, _):
-                s = step(q + c)
-                return (s * 0).astype(q.dtype), None
-            c, _ = jax.lax.scan(body, jnp.zeros((), q.dtype), None, length=8)
-            return c
-
-        def run():
-            jax.block_until_ready(loop())
-        return run
-
-    return autotune(key, _BLOCK_CANDIDATES, make_runner, default=(128, 128))
-
-
-def _flash_fwd_bhsd(q, k, v, causal, scale, block_q=128, block_k=128,
-                    interpret=None, q_per_kv=1, residual=None,
-                    rms_weight=None, rms_eps=1e-6, rms_d=None):
-    """q: (BH, Sq, D), k/v: (BH // q_per_kv, Sk, D) -> (out, lse).
+def _flash_fwd_bhsd(q, k, v, causal, scale, tiles=None, interpret=None,
+                    q_per_kv=1, residual=None, rms_weight=None,
+                    rms_eps=1e-6, rms_d=None):
+    """q: (BH, Sq, D), k/v: (BH // q_per_kv, Sk, D) -> (out, lse), lse
+    (BH, Sq) float32. tiles: a `Tiles` (None = `choose_tiles` of the shape).
 
     residual/rms_weight (both given or neither): fuse the
     rmsnorm(attn + residual) * weight epilogue into the kernel's flush —
@@ -444,7 +486,7 @@ def _flash_fwd_bhsd(q, k, v, causal, scale, block_q=128, block_k=128,
     (BH, Sq, D); rms_weight: (D,). rms_d = the TRUE head dim when D is
     zero-padded (the mean divisor). Forward-only (no VJP).
 
-    Ragged sequence lengths are padded to block multiples; padded K columns
+    Ragged sequence lengths are padded to tile multiples; padded K columns
     are masked in-kernel, padded Q rows sliced off on return (so results
     are exact for any length).
 
@@ -452,205 +494,238 @@ def _flash_fwd_bhsd(q, k, v, causal, scale, block_q=128, block_k=128,
     folds the head grouping (q index b -> kv index b // q_per_kv), so no
     (B, S, H, D) broadcast of KV ever materializes in HBM. With batch-major
     bh layout (bi*h + hq), b // q_per_kv == bi*kvh + hq // rep exactly."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    block_q, block_k = _block_sizes(sq, sk, block_q, block_k)
-    q_p = _pad_to(q, 1, block_q)
-    k_p = _pad_to(k, 1, block_k)
-    v_p = _pad_to(v, 1, block_k)
-    sq_p, sk_p = q_p.shape[1], k_p.shape[1]
-    mask_k_tail = sk_p != sk
+    if tiles is None:
+        tiles = choose_tiles(q.shape[1], k.shape[1], q.shape[2],
+                             q.dtype.itemsize)
     if interpret is None:
         interpret = _interpret_default()
+    return _fwd_call(q, k, v, residual, rms_weight, causal=causal,
+                     scale=scale, tiles=tiles, interpret=interpret,
+                     q_per_kv=q_per_kv, rms_eps=rms_eps, rms_d=rms_d)
+
+
+# the calls are jitted so that a model's layers, which call with one
+# signature, trace and lower each kernel once instead of once a layer (a
+# third of a second of set-up a layer at the trainer's shape); XLA inlines
+# them like any call. Every default is resolved before, so what the jit
+# caches on is what the kernel is built from.
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "tiles", "interpret", "q_per_kv", "rms_eps", "rms_d"))
+def _fwd_call(q, k, v, residual, rms_weight, *, causal, scale, tiles,
+              interpret, q_per_kv, rms_eps, rms_d):
+    bh, sq, d = q.shape
+    sk = k.shape[1]
+    tile = block_q, block_km, block_k = tiles.fwd
+    q_p = _pad_to(q, 1, block_q)
+    k_p = _pad_to(k, 1, block_km)
+    v_p = _pad_to(v, 1, block_km)
+    sq_p, sk_p = q_p.shape[1], k_p.shape[1]
     g = q_per_kv
-    nq, nk = sq_p // block_q, sk_p // block_k
-    # causal + square blocks + equal (padded) lengths: pack the grid to
-    # the lower triangle of (q block, k block) pairs — the rectangular
-    # grid spends half its steps and k/v DMAs on fully-masked pairs
-    packed = (causal and sk == sq and sq_p == sk_p
-              and block_q == block_k and _packing_on())
+    nq, nk = sq_p // block_q, sk_p // block_km
+    offset = sk - sq
     epilogue = residual is not None
     kernel = functools.partial(
-        _fa_fwd_kernel, causal=causal, scale=scale, seq_k=sk,
-        block_q=block_q, block_k=block_k, offset=sk - sq,
-        mask_k_tail=mask_k_tail, packed=packed, epilogue=epilogue,
-        rms_eps=rms_eps, rms_d=(rms_d or d))
-    if packed:
-        grid = (bh, nq * (nq + 1) // 2)
-        qmap, kmap = _tri_maps(g)
-        in_maps = [qmap, kmap, kmap]
-        out_maps = [qmap, qmap]
-        wmap = lambda b, p: (0, 0)   # noqa: E731
-    else:
-        grid = (bh, nq, nk)
-        in_maps = [lambda b, i, j: (b, i, 0),
-                   lambda b, i, j: (b // g, j, 0),
-                   lambda b, i, j: (b // g, j, 0)]
-        out_maps = [lambda b, i, j: (b, i, 0), lambda b, i, j: (b, i, 0)]
-        wmap = lambda b, i, j: (0, 0)   # noqa: E731
+        _fa_fwd_kernel, causal=causal, scale=scale,
+        seq_k=sk if sk_p != sk else None, tile=tile, offset=offset,
+        epilogue=epilogue, rms_eps=rms_eps, rms_d=(rms_d or d))
+
+    def qmap(b, i, j):
+        return (b, i, 0)
+
+    def kmap(b, i, j):
+        if causal:
+            j = jnp.minimum(j, _last_key_tile(i, block_q, block_km, nk,
+                                              offset))
+        return (b // g, j, 0)
+
     in_specs = [
-        pl.BlockSpec((1, block_q, d), in_maps[0]),
-        pl.BlockSpec((1, block_k, d), in_maps[1]),
-        pl.BlockSpec((1, block_k, d), in_maps[2]),
+        pl.BlockSpec((1, block_q, d), qmap),
+        pl.BlockSpec((1, block_km, d), kmap),
+        pl.BlockSpec((1, block_km, d), kmap),
     ]
     operands = [q_p, k_p, v_p]
     if epilogue:
         # residual rides the q index map; gamma is one (8, d) sublane-
         # tiled block (a bare (1, d) block is unlowerable on TPU), f32 so
         # bf16 gammas don't hit the (16, 128) bf16 tile minimum
-        in_specs.append(pl.BlockSpec((1, block_q, d), in_maps[0]))
-        in_specs.append(pl.BlockSpec((8, d), wmap))
+        in_specs.append(pl.BlockSpec((1, block_q, d), qmap))
+        in_specs.append(pl.BlockSpec((8, d), lambda b, i, j: (0, 0)))
         operands.append(_pad_to(residual, 1, block_q))
         operands.append(jnp.broadcast_to(
             rms_weight.astype(jnp.float32)[None, :], (8, d)))
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), out_maps[0]),
-            pl.BlockSpec((1, block_q, _LANES), out_maps[1]),
+            pl.BlockSpec((1, block_q, d), qmap),
+            # (bh, 1, sq_p) in (1, 1, block_q) blocks: a row per q tile,
+            # the second-minor block dim being the array's own
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_shape=[
             _sds((bh, sq_p, d), q.dtype, q),
-            _sds((bh, sq_p, _LANES), jnp.float32, q),
+            _sds((bh, 1, sq_p), jnp.float32, q),
         ],
         scratch_shapes=[
+            pltpu.VMEM((block_q, d), q.dtype),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
+        compiler_params=_params("fwd", tile, d, q.dtype.itemsize,
+                                ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="fa_fwd",
     )(*operands)
-    # collapse the lane-expanded lse back to (bh, sq_p) right away so the
-    # autodiff residual is O(S), not O(S * 128)
-    return out[:, :sq], lse[..., 0]
+    return out[:, :sq], lse[:, 0, :sq]
 
 
-def _flash_bwd_bhsd(q, k, v, o, lse, g, causal, scale, block_q=128,
-                    block_k=128, interpret=None, q_per_kv=1):
+def _flash_bwd_bhsd(q, k, v, o, lse, g, causal, scale, tiles=None,
+                    interpret=None, q_per_kv=1):
     """FlashAttention-2 backward: returns (dq, dk, dv), all in input dtype.
-    GQA: k/v carry BH // q_per_kv heads; dk/dv come back already reduced
-    over the query-head group (the rep axis rides the grid, accumulating
-    into the same VMEM scratch — no XLA-side segment-sum needed)."""
-    bh, sq, d = q.shape
-    sk = k.shape[1]
-    block_q, block_k = _block_sizes(sq, sk, block_q, block_k)
+    lse: (BH, Sq) from the forward. GQA: k/v carry BH // q_per_kv heads;
+    dk/dv come back already reduced over the query-head group (the rep
+    axis rides the grid, accumulating into the same VMEM scratch — no
+    XLA-side segment-sum needed)."""
+    if tiles is None:
+        tiles = choose_tiles(q.shape[1], k.shape[1], q.shape[2],
+                             q.dtype.itemsize)
     if interpret is None:
         interpret = _interpret_default()
+    return _bwd_call(q, k, v, o, lse, g, causal=causal, scale=scale,
+                     tiles=tiles, interpret=interpret, q_per_kv=q_per_kv)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "scale", "tiles", "interpret", "q_per_kv"))
+def _bwd_call(q, k, v, o, lse, g, *, causal, scale, tiles, interpret,
+              q_per_kv):
+    bh, sq, d = q.shape
+    sk = k.shape[1]
+    itemsize = q.dtype.itemsize
+    offset = sk - sq
+    grp = q_per_kv
+    bh_kv = bh // grp
 
     # delta = rowsum(dO * O): cheap XLA elementwise+reduce, fp32
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
 
+    # ---- dQ: resident q tile, K/V streamed -------------------------------
+    tile = block_q, block_km, block_k = tiles.dq
     q_p = _pad_to(q, 1, block_q)
     do_p = _pad_to(g, 1, block_q)
-    delta_p = _pad_to(delta, 1, block_q)
-    k_p = _pad_to(k, 1, block_k)
-    v_p = _pad_to(v, 1, block_k)
+    k_p = _pad_to(k, 1, block_km)
+    v_p = _pad_to(v, 1, block_km)
     sq_p, sk_p = q_p.shape[1], k_p.shape[1]
-    # lse from the forward is already padded to a block_q multiple of the
-    # forward's padding; re-pad defensively (values for pad rows are finite,
-    # and pad-row contributions vanish because dO pad rows are zero).
-    lse_p = _pad_to(lse, 1, block_q)[:, :sq_p]
-    mask_k_tail = sk_p != sk
-    offset = sk - sq
-    common = dict(causal=causal, scale=scale, seq_k=sk, block_q=block_q,
-                  block_k=block_k, offset=offset, mask_k_tail=mask_k_tail)
+    nq, nk = sq_p // block_q, sk_p // block_km
+    # per-row scalars travel as (bh, 1, sq_p) rows; pad rows are finite
+    # and their contributions vanish because dO's pad rows are zero
+    rows = [_pad_to(x, 1, block_q)[:, None, :] for x in (lse, delta)]
 
-    nq, nk = sq_p // block_q, sk_p // block_k
+    def dq_qmap(b, i, j):
+        return (b, i, 0)
 
-    # lane-expand the per-row scalars: a (1, block_q) block is unlowerable
-    # on TPU (last-two-dims tiling), so feed (1, block_q, _LANES) blocks
-    lse3 = jnp.broadcast_to(lse_p[..., None], (bh, sq_p, _LANES))
-    delta3 = jnp.broadcast_to(delta_p[..., None], (bh, sq_p, _LANES))
+    def dq_kmap(b, i, j):
+        if causal:
+            j = jnp.minimum(j, _last_key_tile(i, block_q, block_km, nk,
+                                              offset))
+        return (b // grp, j, 0)
 
-    grp = q_per_kv
-    bh_kv = bh // grp
-    # same lower-triangle packing as the forward (see _flash_fwd_bhsd):
-    # dq accumulates over kj <= qi only, so the rectangular grid's upper
-    # half is pure skipped-step overhead for causal self-attention
-    packed = (causal and sk == sq and sq_p == sk_p
-              and block_q == block_k and _packing_on())
-    if packed:
-        dq_grid = (bh, nq * (nq + 1) // 2)
-        dq_qmap, dq_kmap = _tri_maps(grp)
-        dq_in = [dq_qmap, dq_kmap, dq_kmap, dq_qmap, dq_qmap, dq_qmap]
-        dq_out = dq_qmap
-    else:
-        dq_grid = (bh, nq, nk)
-        dq_qm = lambda b, i, j: (b, i, 0)   # noqa: E731
-        dq_km = lambda b, i, j: (b // grp, j, 0)   # noqa: E731
-        dq_in = [dq_qm, dq_km, dq_km, dq_qm, dq_qm, dq_qm]
-        dq_out = dq_qm
+    def dq_rmap(b, i, j):
+        return (b, 0, i)
+
     dq = pl.pallas_call(
-        functools.partial(_fa_dq_kernel, packed=packed, **common),
-        grid=dq_grid,
+        functools.partial(_fa_dq_kernel, causal=causal, scale=scale,
+                          seq_k=sk if sk_p != sk else None, tile=tile,
+                          offset=offset),
+        grid=(bh, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), dq_in[0]),
-            pl.BlockSpec((1, block_k, d), dq_in[1]),
-            pl.BlockSpec((1, block_k, d), dq_in[2]),
-            pl.BlockSpec((1, block_q, d), dq_in[3]),
-            pl.BlockSpec((1, block_q, _LANES), dq_in[4]),
-            pl.BlockSpec((1, block_q, _LANES), dq_in[5]),
+            pl.BlockSpec((1, block_q, d), dq_qmap),
+            pl.BlockSpec((1, block_km, d), dq_kmap),
+            pl.BlockSpec((1, block_km, d), dq_kmap),
+            pl.BlockSpec((1, block_q, d), dq_qmap),
+            pl.BlockSpec((1, 1, block_q), dq_rmap),
+            pl.BlockSpec((1, 1, block_q), dq_rmap),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), dq_out),
+        out_specs=pl.BlockSpec((1, block_q, d), dq_qmap),
         out_shape=_sds((bh, sq_p, d), q.dtype, q),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((block_q, d), q.dtype),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
+        ],
+        compiler_params=_params("dq", tile, d, itemsize,
+                                ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="fa_bwd_dq",
-    )(q_p, k_p, v_p, do_p, lse3, delta3)
+    )(q_p, k_p, v_p, do_p, *rows)
 
-    # dkv grid: (kv heads, kv blocks, group reps, q blocks) — i innermost,
-    # then r, so for a fixed kv block the scratch accumulates over the
-    # whole query-head group before flushing (n_rep=grp in the kernel).
-    # Packed: (kv heads, tri(nq), reps) — see _fa_dkv_kernel
-    if packed:
-        def dkv_qmap(b, p, r):
-            u, w = _tri_decode(p)
-            return (b * grp + r, (nq - 1 - u) + w, 0)
+    # ---- dK/dV: resident k tile, Q/dO streamed ---------------------------
+    # grid (kv heads, k tiles, group reps, q tiles) — q tiles innermost,
+    # then reps, so for a fixed kv tile the scratch accumulates over the
+    # whole query-head group before flushing (n_rep=grp in the kernel)
+    tile = block_k, block_qm, block_q = tiles.dkv
+    k_p = _pad_to(k, 1, block_k)
+    v_p = _pad_to(v, 1, block_k)
+    q_p = _pad_to(q, 1, block_qm)
+    do_p = _pad_to(g, 1, block_qm)
+    sq_p, sk_p = q_p.shape[1], k_p.shape[1]
+    nqm, nk = sq_p // block_qm, sk_p // block_k
+    # a (1, block_q) row per q sub-block, picked by its leading index
+    rows = [_pad_to(x, 1, block_qm).reshape(bh, sq_p // block_q, 1, block_q)
+            for x in (lse, delta)]
 
-        def dkv_kmap(b, p, r):
-            u, _ = _tri_decode(p)
-            return (b, nq - 1 - u, 0)
-        dkv_grid = (bh_kv, nq * (nq + 1) // 2, grp)
-        dkv_in = [dkv_qmap, dkv_kmap, dkv_kmap, dkv_qmap, dkv_qmap,
-                  dkv_qmap]
-        dkv_out = dkv_kmap
-        dkv_extra = {"packed_nq": nq}
-    else:
-        dkv_qm = lambda b, j, r, i: (b * grp + r, i, 0)   # noqa: E731
-        dkv_km = lambda b, j, r, i: (b, j, 0)   # noqa: E731
-        dkv_grid = (bh_kv, nk, grp, nq)
-        dkv_in = [dkv_qm, dkv_km, dkv_km, dkv_qm, dkv_qm, dkv_qm]
-        dkv_out = dkv_km
-        dkv_extra = {}
+    def first_q_tile(j, i):
+        # the first streamed query tile key tile j is visible to
+        if causal:
+            i = jnp.maximum(i, jnp.minimum(
+                _fdiv(j * block_k - offset, block_qm), nqm - 1))
+        return i
+
+    def dkv_qmap(b, j, r, i):
+        return (b * grp + r, first_q_tile(j, i), 0)
+
+    def dkv_rmap(b, j, r, i):
+        return (b * grp + r, first_q_tile(j, i), 0, 0)
+
+    def dkv_kmap(b, j, r, i):
+        return (b, j, 0)
+
+    n_sub = block_qm // block_q
     dk, dv = pl.pallas_call(
-        functools.partial(_fa_dkv_kernel, n_rep=grp, **dkv_extra, **common),
-        grid=dkv_grid,
+        functools.partial(_fa_dkv_kernel, causal=causal, scale=scale,
+                          seq_q=sq if sq_p != sq else None, tile=tile,
+                          offset=offset, n_rep=grp),
+        grid=(bh_kv, nk, grp, nqm),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), dkv_in[0]),
-            pl.BlockSpec((1, block_k, d), dkv_in[1]),
-            pl.BlockSpec((1, block_k, d), dkv_in[2]),
-            pl.BlockSpec((1, block_q, d), dkv_in[3]),
-            pl.BlockSpec((1, block_q, _LANES), dkv_in[4]),
-            pl.BlockSpec((1, block_q, _LANES), dkv_in[5]),
+            pl.BlockSpec((1, block_qm, d), dkv_qmap),
+            pl.BlockSpec((1, block_k, d), dkv_kmap),
+            pl.BlockSpec((1, block_k, d), dkv_kmap),
+            pl.BlockSpec((1, block_qm, d), dkv_qmap),
+            pl.BlockSpec((1, n_sub, 1, block_q), dkv_rmap),
+            pl.BlockSpec((1, n_sub, 1, block_q), dkv_rmap),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), dkv_out),
-            pl.BlockSpec((1, block_k, d), dkv_out),
+            pl.BlockSpec((1, block_k, d), dkv_kmap),
+            pl.BlockSpec((1, block_k, d), dkv_kmap),
         ],
         out_shape=[
             _sds((bh_kv, sk_p, d), k.dtype, k),
             _sds((bh_kv, sk_p, d), v.dtype, v),
         ],
         scratch_shapes=[
+            pltpu.VMEM((block_k, d), k.dtype),
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_params(
+            "dkv", tile, d, itemsize,
+            ("parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
         name="fa_bwd_dkv",
-    )(q_p, k_p, v_p, do_p, lse3, delta3)
+    )(q_p, k_p, v_p, do_p, *rows)
 
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]
 
@@ -667,45 +742,114 @@ def _xla_attention_bhsd(q, k, v, causal, scale):
     return jnp.einsum("bqk,bkd->bqd", p, v)
 
 
-def _fwd_blocks(q, k, causal):
-    bh, sq, d = q.shape
-    return _tuned_blocks("fwd", bh, sq, k.shape[1], d, q.dtype, causal,
-                         _interpret_default())
+def _tiles_for(kind, bh, sq, sk, d, dtype, causal, interpret):
+    """`choose_tiles` of the shape; with FLAGS_use_autotune on, the
+    (resident, sub) rows of kernel(s) `kind` ('fwd' | 'bwd') are the timed
+    winner of `_ROW_CANDIDATES` instead of `_TILE_ROWS`. Timing runs on
+    synthetic zeros, so this works even while the caller is being traced."""
+    from .autotune import autotune, autotune_enabled
+    itemsize = jnp.dtype(dtype).itemsize
+    if not autotune_enabled():
+        return choose_tiles(sq, sk, d, itemsize)
+    dev = jax.devices()[0]
+    # tb (the clamped tuning batch*heads) is part of the key: tile ranking
+    # depends on grid parallelism, so a winner timed at 2 heads must not be
+    # served to a 64-head caller
+    tb = min(bh, 64)
+    key = (kind, tb, sq, sk, d, str(dtype), bool(causal), dev.device_kind)
+    kernels = ("fwd",) if kind == "fwd" else ("dq", "dkv")
+
+    def tiles_of(rows):
+        return choose_tiles(sq, sk, d, itemsize,
+                            rows={name: rows for name in kernels})
+
+    def make_runner(rows):
+        if rows[0] > _round_up(sq, _LANES) or rows[1] > _round_up(sk, _LANES):
+            raise ValueError("tile larger than the sequence")
+        tiles = tiles_of(rows)
+        q = jnp.zeros((tb, sq, d), dtype)
+        k = jnp.zeros((tb, sk, d), dtype)
+        v = jnp.zeros((tb, sk, d), dtype)
+        # each candidate runs 8 iterations inside ONE compiled scan so
+        # per-dispatch launch overhead does not rank the candidates. The
+        # carry feeds q so the body can't be hoisted.
+        if kind == "fwd":
+            def step(qq):
+                o, _ = _flash_fwd_bhsd(qq, k, v, causal, 1.0, tiles=tiles,
+                                       interpret=interpret)
+                return jnp.sum(o.astype(jnp.float32))
+        else:
+            # o / lse only need the forward's shapes: timing is on zeros
+            lse = jnp.zeros((tb, sq), jnp.float32)
+
+            def step(qq):
+                outs = _flash_bwd_bhsd(qq, k, v, q, lse, q, causal, 1.0,
+                                       tiles=tiles, interpret=interpret)
+                return sum(jnp.sum(x.astype(jnp.float32)) for x in outs)
+
+        @jax.jit
+        def loop():
+            def body(c, _):
+                s = step(q + c)
+                return (s * 0).astype(q.dtype), None
+            c, _ = jax.lax.scan(body, jnp.zeros((), q.dtype), None, length=8)
+            return c
+
+        def run():
+            jax.block_until_ready(loop())
+        return run
+
+    return tiles_of(autotune(key, _ROW_CANDIDATES, make_runner,
+                             default=_TILE_ROWS[kernels[0]]))
 
 
-def _bwd_blocks(q, k, causal):
+def _tiles(kind, q, k, causal):
     bh, sq, d = q.shape
-    return _tuned_blocks("bwd", bh, sq, k.shape[1], d, q.dtype, causal,
-                         _interpret_default())
+    return _tiles_for(kind, bh, sq, k.shape[1], d, q.dtype, causal,
+                      _interpret_default())
+
+
+def tiles_for_shape(batch_heads, seq_q, seq_k, head_dim, dtype,
+                    causal) -> Tiles:
+    """The tiles the entry points of this module hand the three kernels
+    for attention of this shape, resolved the way they resolve them
+    (`_tiles_for`: the head dim padded to the lane width, and
+    FLAGS_use_autotune's timed winners where it is on). The router's
+    Decision records these."""
+    d = _round_up(head_dim, _LANES)
+    fwd, bwd = (_tiles_for(kind, batch_heads, seq_q, seq_k, d,
+                           jnp.dtype(dtype), causal, _interpret_default())
+                for kind in ("fwd", "bwd"))
+    return Tiles(fwd=fwd.fwd, dq=bwd.dq, dkv=bwd.dkv)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_attention_bhsd(q, k, v, causal, scale, q_per_kv=1):
-    bq, bk = _fwd_blocks(q, k, causal)
-    out, _ = _flash_fwd_bhsd(q, k, v, causal, scale, block_q=bq, block_k=bk,
+    out, _ = _flash_fwd_bhsd(q, k, v, causal, scale,
+                             tiles=_tiles("fwd", q, k, causal),
                              q_per_kv=q_per_kv)
     return out
 
 
 def _fa_fwd(q, k, v, causal, scale, q_per_kv=1):
-    bq, bk = _fwd_blocks(q, k, causal)
-    out, lse = _flash_fwd_bhsd(q, k, v, causal, scale, block_q=bq, block_k=bk,
+    out, lse = _flash_fwd_bhsd(q, k, v, causal, scale,
+                               tiles=_tiles("fwd", q, k, causal),
                                q_per_kv=q_per_kv)
     return out, (q, k, v, out, lse)
 
 
 def _dense_remat_bwd(q, k, v, causal, scale, q_per_kv, g):
-    """Backward via XLA-dense rematerialization (GQA-grouped).
-
-    Measured on TPU v5e (r5): ISOLATED-kernel timing favors this hybrid
-    over the Pallas dQ/dKV split (9.0ms vs 12.9ms fwd+bwd at s2048 d128
-    with the f32-operand kernels), but END-TO-END the 535m train step
-    measured the opposite — 0.406 MFU hybrid vs 0.426 full-pallas — the
+    """Backward via XLA-dense rematerialization (GQA-grouped): the hybrid
+    (flash forward, dense backward), selectable with
+    FLAGS_flash_attention_bwd=xla and by a ledger row that measured it
+    winning. On a TPU v5e no measured row does any more: with the
+    two-level-tile kernels the Pallas backward wins every isolated row
+    (forward+backward 1.47-2.05 ms against 5.1-7.3 ms) and both end-to-end
+    A/Bs — the 535m step in round 5 (0.426 against 0.406 MFU: the
     transient (bh, sq, sk) fp32 buffer's HBM pressure costs the scheduled
-    step more than the kernel gap saves. It remains the better backward
-    for zero-padded head dims (d96: 6.7ms vs 13.8ms per-kernel, the pad
-    taxes the Pallas bwd twice) and is selectable via
-    FLAGS_flash_attention_bwd=xla."""
+    step more than any kernel gap), and llama_780m, head dim 96 padded to
+    128, in PR 27 (0.5775 against 0.4137 MFU), the shape where round 5's
+    f32-operand kernels had lost to it both ways."""
     def f(q_, k_, v_):
         if q_per_kv == 1:
             return _xla_attention_bhsd(q_, k_, v_, causal, scale)
@@ -731,12 +875,10 @@ _flags.define_flag(
     "flash-attention backward: 'pallas' (FA-2 dQ/dKV kernels), 'xla' "
     "(dense rematerialization, XLA-differentiated), or 'auto' (routed "
     "per shape by ops/pallas/attention_router from the baked hardware "
-    "ledger: the r5 end-to-end A/B on v5e measured the full-pallas bwd "
-    "at 0.426 MFU vs 0.406 for the xla-remat hybrid on the 535m train "
-    "step even though isolated-kernel timing favors the hybrid — the "
-    "dense remat's O(S^2) buffer costs more in HBM pressure than it "
-    "saves in kernel time once the whole step is scheduled — while the "
-    "zero-padded d96 shapes measured the hybrid winning both ways)")
+    "ledger, whose end-to-end rows outrank its isolated ones: on a v5e "
+    "the Pallas backward won both end-to-end A/Bs, 0.426 vs 0.406 MFU on "
+    "the 535m train step in round 5 and 0.5775 vs 0.4137 on llama_780m, "
+    "head dim 96, in PR 27, and every isolated row of round 27)")
 
 
 def _fa_bwd(causal, scale, q_per_kv, res, g):
@@ -750,9 +892,9 @@ def _fa_bwd(causal, scale, q_per_kv, res, g):
                      q.dtype, causal).bwd
     if mode == "xla":
         return _dense_remat_bwd(q, k, v, causal, scale, q_per_kv, g)
-    bq, bk = _bwd_blocks(q, k, causal)
     return _flash_bwd_bhsd(q, k, v, o, lse, g, causal, scale,
-                           block_q=bq, block_k=bk, q_per_kv=q_per_kv)
+                           tiles=_tiles("bwd", q, k, causal),
+                           q_per_kv=q_per_kv)
 
 
 _flash_attention_bhsd.defvjp(_fa_fwd, _fa_bwd)
@@ -876,9 +1018,9 @@ def flash_attention_rms_epilogue_bshd(q, k, v, residual, rms_weight,
     kt = jnp.swapaxes(k, 1, 2).reshape(b * kvh, sk, dp)
     vt = jnp.swapaxes(v, 1, 2).reshape(b * kvh, sk, dp)
     rt = jnp.swapaxes(residual, 1, 2).reshape(b * h, sq, dp)
-    bq, bk = _fwd_blocks(qt, kt, causal)
-    out, _ = _flash_fwd_bhsd(qt, kt, vt, causal, scale, block_q=bq,
-                             block_k=bk, q_per_kv=h // kvh, residual=rt,
+    out, _ = _flash_fwd_bhsd(qt, kt, vt, causal, scale,
+                             tiles=_tiles("fwd", qt, kt, causal),
+                             q_per_kv=h // kvh, residual=rt,
                              rms_weight=rms_weight, rms_eps=eps, rms_d=d)
     out = jnp.swapaxes(out.reshape(b, h, sq, dp), 1, 2)
     return out[..., :d] if d_pad else out

@@ -81,7 +81,7 @@ def _ring_flash(q, k, v, axis_name: str, causal: bool, scale: float):
             return out, (m + jnp.log(l))[..., 0]
         out, lse = _flash_fwd_bhsd(qf, kf, vf, block_causal, scale,
                                    interpret=False)
-        return out.astype(jnp.float32), lse[:, :s_local]
+        return out.astype(jnp.float32), lse
 
     def merge(carry, part):
         out, lse = carry

@@ -35,14 +35,17 @@ class TestPhasesAtTinySizes:
         assert out.out == "" and "no TPU" in out.err
 
     def test_kernel_phase(self):
-        res = chip_smoke.kernel_phase(bh=1, seq=128, small_seq=128,
+        res = chip_smoke.kernel_phase(bh=1, seq=256, small_seq=128,
                                       gqa=(4, 2), interpret=True)
         names = [c["case"] for c in res["cases"]]
-        assert names == ["rect_128", "rect_gqa", "d96_zero_pad",
-                         "rms_epilogue"]
-        assert all(not c.get("packed") for c in res["cases"])
-        # the interpreter evaluates the sqrt-based index maps exactly
-        assert res["packed_grid"]["lowers"] and res["packed_grid"]["matches"]
+        assert names == ["chooser_tiles", "small_tiles", "gqa",
+                         "d96_zero_pad", "rms_epilogue"]
+        # one causal schedule, whatever the tiles: each case names the
+        # tiles it ran and the grid steps they gave
+        assert res["cases"][0]["grid_steps"] == {
+            "fa_fwd": 1, "fa_bwd_dq": 1, "fa_bwd_dkv": 1}
+        assert res["cases"][1]["tiles"]["dkv"] == (128, 256, 128)
+        assert res["cases"][1]["grid_steps"]["fa_fwd"] == 2
 
     def test_kernel_case_fails_on_a_wrong_kernel(self, monkeypatch):
         from paddle_tpu.ops.pallas import flash_attention as fa
@@ -53,8 +56,8 @@ class TestPhasesAtTinySizes:
             return out * 1.2, lse
         monkeypatch.setattr(fa, "_flash_fwd_bhsd", skewed)
         with pytest.raises(AssertionError, match="forward differs"):
-            chip_smoke._attention_case("skew", 1, 1, 128, 128, (128, 128),
-                                       False, True, 0)
+            chip_smoke._attention_case("skew", 1, 1, 128, 128, None, True,
+                                       0)
 
     def test_server_phase(self):
         meter = chip_smoke.CompileMeter()

@@ -5,33 +5,55 @@ flash_attn_grad_kernel.cu, test/legacy_test/test_flash_attention.py.
 Runs under the Pallas interpreter on CPU; same kernels compile on TPU.
 """
 
+import re
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas.flash_attention import (
-    _flash_attention_bhsd, _flash_fwd_bhsd, _xla_attention_bhsd,
-    flash_attention_bshd)
+    Tiles, _flash_attention_bhsd, _flash_bwd_bhsd, _flash_fwd_bhsd,
+    _xla_attention_bhsd, choose_tiles, flash_attention_bshd)
 
 
-class _BothGridModes:
-    """Run every test in the subclass under BOTH causal-grid layouts: the
-    triangle-packed grid (the default under the interpreter since the
-    bf16 finalization — 'auto' resolves to ON off-TPU) and the
-    rectangular grid (the shipped default on unvalidated hardware).
-    ADVICE r5 #2: forcing packed-only cost the rectangular path its
-    direct numeric coverage."""
+def _same(res, streamed, sub):
+    return Tiles(fwd=(res, streamed, sub), dq=(res, streamed, sub),
+                 dkv=(res, streamed, sub))
 
-    @pytest.fixture(autouse=True, params=[True, False],
-                    ids=["packed", "rect"])
-    def _grid_mode(self, request):
-        from paddle_tpu.framework import flags as _flags
-        old = _flags.flag_value("flash_packed_grid")
-        _flags.set_flags({"FLAGS_flash_packed_grid": request.param})
+
+# the tile regimes the chooser produces, at sizes the interpreter runs in
+# seconds (a tile is (resident, streamed, sub) rows): every streamed tile
+# one sub-block, so the grid does the sweep and the clamped index maps
+# skip; several sub-blocks a tile with the resident, streamed and sub sizes
+# all different between kernels, so the in-kernel loops' bounds do
+TILE_REGIMES = {
+    "one_sub": _same(64, 64, 64),
+    "many_sub": Tiles(fwd=(128, 256, 32), dq=(32, 128, 64),
+                      dkv=(64, 256, 128)),
+}
+
+
+class _BothTileRegimes:
+    """Run every test in the subclass under both regimes of TILE_REGIMES
+    (the chooser itself, at the sizes it hands out, is TestTileChooser's
+    and TestProductionKernelSmoke's)."""
+
+    @pytest.fixture(autouse=True, params=list(TILE_REGIMES))
+    def _tile_regime(self, request, monkeypatch):
+        tiles = TILE_REGIMES[request.param]
+        monkeypatch.setattr(fa, "choose_tiles", lambda *a, **k: tiles)
+        # the regime has to reach the kernels: the jitted calls key on the
+        # tiles they are handed, and every call of the test is spied on
+        used = []
+        for name in ("_fwd_call", "_bwd_call"):
+            real = getattr(fa, name)
+            monkeypatch.setattr(fa, name, lambda *a, _real=real, **k: (
+                used.append(k["tiles"]), _real(*a, **k))[1])
         yield
-        _flags.set_flags({"FLAGS_flash_packed_grid": old})
+        assert used and all(t == tiles for t in used), (tiles, used)
 
 
 def _rand(rs, *shape, dtype=np.float32):
@@ -39,9 +61,9 @@ def _rand(rs, *shape, dtype=np.float32):
 
 
 CASES = [
-    # (seq_q, seq_k, causal): aligned, ragged (pad-masked), cross-length.
-    # Causal sq==sk cases run the triangle-PACKED grid; 384/520 stress the
-    # multi-block linear-index decode (nq=3 and nq=5-with-padded-tail)
+    # (seq_q, seq_k, causal): aligned, ragged (pad-masked), cross-length
+    # (sk > sq causal is bottom-right aligned); 384/520 are not a multiple
+    # of every tile
     (256, 256, False),
     (256, 256, True),
     (200, 200, True),
@@ -52,14 +74,16 @@ CASES = [
 ]
 
 
-class TestFlashForward(_BothGridModes):
+class TestFlashForward(_BothTileRegimes):
     @pytest.mark.parametrize("sq,sk,causal", CASES)
     def test_matches_dense(self, sq, sk, causal):
         rs = np.random.RandomState(0)
         q, k, v = (_rand(rs, 2, sq, 64), _rand(rs, 2, sk, 64),
                    _rand(rs, 2, sk, 64))
-        out = jax.jit(_flash_attention_bhsd, static_argnums=(3, 4))(
-            q, k, v, causal, 0.125)
+        # a fresh function a call: jit's cache is keyed on the function,
+        # and the second regime must not be served the first one's trace
+        out = jax.jit(lambda *a: _flash_attention_bhsd(*a, causal, 0.125))(
+            q, k, v)
         ref = _xla_attention_bhsd(q, k, v, causal, 0.125)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
@@ -135,7 +159,7 @@ class TestHeadDimPadding:
             assert a.shape[-1] == d  # pad columns sliced off
 
 
-class TestFlashBackward(_BothGridModes):
+class TestFlashBackward(_BothTileRegimes):
     """The handwritten Pallas backward (dQ kernel + dK/dV kernel) must match
     autodiff of the dense reference at fp32 tolerance. The bwd-mode flag is
     pinned to 'pallas': 'auto' is routed per shape by the attention-backend
@@ -279,32 +303,30 @@ class TestGQAFlash:
         return jnp.repeat(kv.reshape(bhkv, 1, s, d), rep, 1).reshape(
             bhkv * rep, s, d)
 
+    @pytest.mark.parametrize("tiles", [_same(32, 32, 32), _same(32, 64, 16)],
+                             ids=["one_sub", "many_sub"])
     @pytest.mark.parametrize("causal", [False, True])
-    def test_forward_matches_dense_expanded(self, causal):
-        from paddle_tpu.ops.pallas.flash_attention import (
-            _flash_fwd_bhsd, _xla_attention_bhsd)
+    def test_forward_matches_dense_expanded(self, causal, tiles):
         q, k, v, rep = self._make()
-        out, lse = _flash_fwd_bhsd(q, k, v, causal, 0.25, block_q=32,
-                                   block_k=32, interpret=True,
-                                   q_per_kv=rep)
+        out, lse = _flash_fwd_bhsd(q, k, v, causal, 0.25, tiles=tiles,
+                                   interpret=True, q_per_kv=rep)
         ref = _xla_attention_bhsd(q, self._expand(k, rep),
                                   self._expand(v, rep), causal, 0.25)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-5)
 
-    @pytest.mark.parametrize("sq", [64, 100])  # 100: nq=4 + padded tail
-    def test_backward_matches_dense_expanded(self, sq):
-        from paddle_tpu.ops.pallas.flash_attention import (
-            _flash_fwd_bhsd, _flash_bwd_bhsd, _xla_attention_bhsd)
+    @pytest.mark.parametrize("tiles", [_same(32, 32, 32), _same(32, 64, 16)],
+                             ids=["one_sub", "many_sub"])
+    @pytest.mark.parametrize("sq", [64, 100])  # 100: 4 tiles + padded tail
+    def test_backward_matches_dense_expanded(self, sq, tiles):
         q, k, v, rep = self._make(sq=sq, sk=sq)
         causal, scale = True, 0.25
-        out, lse = _flash_fwd_bhsd(q, k, v, causal, scale, block_q=32,
-                                   block_k=32, interpret=True,
-                                   q_per_kv=rep)
+        out, lse = _flash_fwd_bhsd(q, k, v, causal, scale, tiles=tiles,
+                                   interpret=True, q_per_kv=rep)
         g = jnp.ones_like(out)
         dq, dk, dv = _flash_bwd_bhsd(q, k, v, out, lse, g, causal, scale,
-                                     block_q=32, block_k=32,
-                                     interpret=True, q_per_kv=rep)
+                                     tiles=tiles, interpret=True,
+                                     q_per_kv=rep)
         assert dk.shape == k.shape and dv.shape == v.shape
 
         def ref_loss(q_, k_, v_):
@@ -320,8 +342,6 @@ class TestGQAFlash:
                                    rtol=2e-3, atol=2e-4)
 
     def test_bshd_wrapper_gqa_and_ragged(self):
-        from paddle_tpu.ops.pallas.flash_attention import (
-            flash_attention_bshd)
         r = np.random.RandomState(3)
         b, sq, h, kvh, d = 1, 50, 4, 2, 16   # ragged seq: pads internally
         q = jnp.asarray(r.randn(b, sq, h, d), jnp.float32)
@@ -413,62 +433,142 @@ class TestBackwardModeSelection:
 
 class TestProductionKernelSmoke:
     """Tier-1 pin of the PRODUCTION kernel flavor on CPU (ISSUE r6 CI
-    satellite): bf16 operands + f32 accumulation + triangle-packed
-    causal grid, forward AND backward, under TPU interpret mode
+    satellite): bf16 operands + f32 accumulation at the tiles the chooser
+    hands the trainer's head dim (the forward's 1024-row tile under the
+    diagonal's mask; the backward's two resident tiles of 512 rows with
+    the whole sequence streamed in 512-row sub-blocks, the causal sweep),
+    forward AND backward, under TPU interpret mode
     (pltpu.force_tpu_interpret_mode where this jax ships it, else the
-    Pallas interpreter — the same kernels either way). r5 shipped this
-    exact flavor with zero direct bf16+packed fwd+bwd coverage; this
-    keeps the path pinned on the CPU, and chip_smoke.py's kernel phase
-    compiles the same kernels (interpret=False) on the chip."""
+    Pallas interpreter — the same kernels either way). chip_smoke.py's
+    kernel phase compiles the same kernels (interpret=False) on the
+    chip."""
 
-    def test_bf16_packed_fwd_bwd_interpret_mode(self):
+    def test_bf16_fwd_bwd_interpret_mode(self):
         import contextlib
         from jax.experimental.pallas import tpu as pltpu
-        from paddle_tpu.framework import flags as _flags
-        from paddle_tpu.ops.pallas import flash_attention as fa
 
         ctx = (pltpu.force_tpu_interpret_mode()
                if hasattr(pltpu, "force_tpu_interpret_mode")
                else contextlib.nullcontext())
-        old = _flags.flag_value("flash_packed_grid")
-        _flags.set_flags({"FLAGS_flash_packed_grid": True})
-        try:
-            with ctx:
-                rs = np.random.RandomState(9)
-                bh, s, d = 2, 512, 128    # production block/lane geometry
-                scale = d ** -0.5
-                q = jnp.asarray(rs.randn(bh, s, d), jnp.bfloat16)
-                k = jnp.asarray(rs.randn(bh, s, d), jnp.bfloat16)
-                v = jnp.asarray(rs.randn(bh, s, d), jnp.bfloat16)
-                out, lse = fa._flash_fwd_bhsd(q, k, v, True, scale,
-                                              interpret=True)
-                assert out.dtype == jnp.bfloat16
-                g = jnp.ones_like(out)
-                dq, dk, dv = fa._flash_bwd_bhsd(q, k, v, out, lse, g,
-                                                True, scale,
-                                                interpret=True)
-                ref = fa._xla_attention_bhsd(q.astype(jnp.float32),
-                                             k.astype(jnp.float32),
-                                             v.astype(jnp.float32),
-                                             True, scale)
-                np.testing.assert_allclose(
-                    np.asarray(out, np.float32), np.asarray(ref),
-                    rtol=0.06, atol=0.06)
+        with ctx:
+            rs = np.random.RandomState(9)
+            bh, s, d = 2, 1024, 128    # production tile/lane geometry
+            assert choose_tiles(s, s, d, 2) == Tiles(
+                fwd=(1024, 1024, 1024), dq=(512, 1024, 512),
+                dkv=(512, 1024, 512))
+            scale = d ** -0.5
+            q = jnp.asarray(rs.randn(bh, s, d), jnp.bfloat16)
+            k = jnp.asarray(rs.randn(bh, s, d), jnp.bfloat16)
+            v = jnp.asarray(rs.randn(bh, s, d), jnp.bfloat16)
+            out, lse = fa._flash_fwd_bhsd(q, k, v, True, scale,
+                                          interpret=True)
+            assert out.dtype == jnp.bfloat16
+            g = jnp.ones_like(out)
+            dq, dk, dv = fa._flash_bwd_bhsd(q, k, v, out, lse, g,
+                                            True, scale, interpret=True)
+            ref = fa._xla_attention_bhsd(q.astype(jnp.float32),
+                                         k.astype(jnp.float32),
+                                         v.astype(jnp.float32),
+                                         True, scale)
+            np.testing.assert_allclose(
+                np.asarray(out, np.float32), np.asarray(ref),
+                rtol=0.06, atol=0.06)
 
-                def ref_loss(q_, k_, v_):
-                    return jnp.sum(fa._xla_attention_bhsd(
-                        q_, k_, v_, True, scale))
-                rdq, rdk, rdv = jax.grad(ref_loss, argnums=(0, 1, 2))(
-                    q.astype(jnp.float32), k.astype(jnp.float32),
-                    v.astype(jnp.float32))
-                for a, b, nm in ((dq, rdq, "dq"), (dk, rdk, "dk"),
-                                 (dv, rdv, "dv")):
-                    assert a.dtype == jnp.bfloat16, nm
-                    np.testing.assert_allclose(
-                        np.asarray(a, np.float32), np.asarray(b),
-                        rtol=0.1, atol=0.1, err_msg=nm)
-        finally:
-            _flags.set_flags({"FLAGS_flash_packed_grid": old})
+            def ref_loss(q_, k_, v_):
+                return jnp.sum(fa._xla_attention_bhsd(
+                    q_, k_, v_, True, scale))
+            rdq, rdk, rdv = jax.grad(ref_loss, argnums=(0, 1, 2))(
+                q.astype(jnp.float32), k.astype(jnp.float32),
+                v.astype(jnp.float32))
+            for a, b, nm in ((dq, rdq, "dq"), (dk, rdk, "dk"),
+                             (dv, rdv, "dv")):
+                assert a.dtype == jnp.bfloat16, nm
+                np.testing.assert_allclose(
+                    np.asarray(a, np.float32), np.asarray(b),
+                    rtol=0.1, atol=0.1, err_msg=nm)
+
+
+class TestTileChooser:
+    """One function chooses every kernel's tiles from the shape, and the
+    router's Decision carries them with the grid steps they give."""
+
+    TRAIN = (64, 2048, 2048, 128)      # bh, sq, sk, d of the trainer's cell
+
+    def test_train_shape_grid_steps_and_vmem(self):
+        from paddle_tpu.ops.pallas import attention_router as ar
+        bh, sq, sk, d = self.TRAIN
+        ar.clear_routing_cache()
+        dec = ar.route(bh, sq, sk, d, "bfloat16", True, platform="tpu",
+                       device_kind="TPU v5 lite")
+        assert dec.tiles == choose_tiles(sq, sk, d, 2)
+        assert set(dec.grid_steps) == {"fa_fwd", "fa_bwd_dq", "fa_bwd_dkv"}
+        # 8,192 a kernel before the two-level tiles
+        assert all(n <= 1024 for n in dec.grid_steps.values()), dec
+        assert ar.decision_log()[-1][1].grid_steps == dec.grid_steps
+        # and the count is the grid of the calls the entry point makes
+        q = jax.ShapeDtypeStruct((bh, sq, d), jnp.bfloat16)
+        text = str(jax.make_jaxpr(jax.grad(lambda q_, k_, v_: jnp.sum(
+            _flash_attention_bhsd(q_, k_, v_, True, 0.1).astype(
+                jnp.float32)), argnums=(0, 1, 2)))(q, q, q))
+        # each pallas_call prints its grid first and its name last
+        grids = re.findall(r"GridMapping\(grid=\(([\d, ]+)\)", text)
+        names = re.findall(r"name=(fa_\w+)", text)
+        assert dict(zip(names, (int(np.prod([int(n) for n in g.split(",")]))
+                                for g in grids))) == dec.grid_steps
+        for kind in ("fwd", "dq", "dkv"):
+            res, streamed, sub = getattr(dec.tiles, kind)
+            assert streamed % sub == 0 and res % 128 == 0 and sub % 128 == 0
+            est = fa.vmem_bytes(kind, (res, streamed, sub), d, 2)
+            limit = fa._params(kind, (res, streamed, sub), d, 2,
+                               ("arbitrary",)).vmem_limit_bytes
+            assert est <= fa._VMEM_BUDGET
+            assert est < limit <= fa._VMEM_LIMIT_MAX
+
+    @pytest.mark.parametrize("sq,sk", [(16, 16), (100, 260), (200, 200),
+                                       (2048 + 128, 2048 + 128)])
+    def test_tiny_and_ragged_sequences_clamp(self, sq, sk):
+        tiles = choose_tiles(sq, sk, 128, 2)
+        for (res, streamed, sub), s_res, s_str in (
+                (tiles.fwd, sq, sk), (tiles.dq, sq, sk), (tiles.dkv, sk, sq)):
+            # a lane tile at least, and padding never past an eighth of
+            # the sequence once a tile is above that minimum
+            for tile, s in ((res, s_res), (sub, s_str)):
+                assert tile >= 128 and tile % 128 == 0
+                pad = -(-s // tile) * tile - s
+                assert tile == 128 or pad * 8 <= s
+            assert streamed % sub == 0
+            assert streamed == -(-s_str // sub) * sub   # whole, it fits
+
+    def test_streamed_tile_shrinks_to_the_vmem_budget(self):
+        long = choose_tiles(1 << 17, 1 << 17, 128, 2)
+        for kind in ("fwd", "dq", "dkv"):
+            tile = getattr(long, kind)
+            assert tile[1] < (1 << 17) and tile[1] % tile[2] == 0
+            assert fa.vmem_bytes(kind, tile, 128, 2) <= fa._VMEM_BUDGET
+        tight = choose_tiles(2048, 2048, 128, 2, vmem_budget=4 << 20)
+        assert tight.fwd[1] < 2048
+
+    def test_backward_builds_no_lane_broadcast_scalars(self):
+        """lse and delta reach the kernels as rows: nothing of shape
+        f32[bh, S, 128] is built in HBM (67 MB each a layer at the train
+        shape before)."""
+        bh, s, d = 2, 256, 256     # d != 128: delta's dO * O is not it
+        q = jnp.zeros((bh, s, d), jnp.bfloat16)
+        lse = jnp.zeros((bh, s), jnp.float32)
+        # str() prints the nested jaxprs too: the jitted call's and, under
+        # the interpreter, the kernels' own
+        bwd = str(jax.make_jaxpr(lambda q_, l_: _flash_bwd_bhsd(
+            q_, q_, q_, q_, l_, q_, True, 0.1, interpret=True))(q, lse))
+        fwd = str(jax.make_jaxpr(lambda q_: _flash_fwd_bhsd(
+            q_, q_, q_, True, 0.1, interpret=True))(q))
+        for text in (bwd, fwd):
+            assert "pallas_call" in text
+            assert f"f32[{bh},1,{s}]" in text          # the scalars, as rows
+            assert f"f32[{bh},{s},128]" not in text
+        # the check sees what it is looking for when it is there
+        wide = str(jax.make_jaxpr(lambda l_: jax.jit(
+            lambda x: jnp.broadcast_to(x[..., None], (bh, s, 128)))(l_))(lse))
+        assert f"f32[{bh},{s},128]" in wide
 
 
 class TestMeshPartitioning:

@@ -1,0 +1,122 @@
+"""The flash kernels compile for a TPU v5e that is described, not attached.
+
+The interpreter (tests/test_flash_attention.py) proves the arithmetic; it
+cannot see what Mosaic refuses: a slice off the tiling, a relayout it has
+no rule for, more VMEM than a kernel may take. The chip's compiler is
+installed here and compiles for a described chip (on-chip-measurement
+guide, section 2), so every tile regime the chooser produces at real
+widths is compiled here at no chip time. Nothing runs and nothing is
+timed. All such compiles live in this one file: only the worker that is
+handed it loads the TPU's library, inside the fixture.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.ops.pallas import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever the plugin raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    # a described compile is written to the cache and cannot be read back
+    # without a chip: the next one would warn and compile again
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    old = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", old)
+    cc.reset_cache()
+
+
+def _small(rows):
+    tile = (rows, 4 * rows, rows)
+    return fa.Tiles(fwd=tile, dq=tile, dkv=tile)
+
+
+# (id, bh, seq_q, seq_k, head_dim, q_per_kv, causal, dtype, tiles)
+CASES = [
+    ("train_cell", 64, 2048, 2048, 128, 1, True, jnp.bfloat16, None),
+    ("four_chip_shard", 16, 2048, 2048, 128, 1, True, jnp.bfloat16, None),
+    ("gqa", 32, 2048, 2048, 128, 4, True, jnp.bfloat16, None),
+    ("ragged", 8, 2000, 2000, 128, 1, True, jnp.bfloat16, None),
+    ("ragged_small_tiles", 8, 2176, 2176, 128, 1, True, jnp.bfloat16, None),
+    ("cross_length", 8, 1024, 2048, 128, 1, True, jnp.bfloat16, None),
+    ("noncausal_ragged", 8, 100, 260, 128, 1, False, jnp.bfloat16, None),
+    ("short", 8, 16, 16, 128, 1, True, jnp.bfloat16, None),
+    ("float32", 4, 2048, 2048, 128, 1, True, jnp.float32, None),
+    ("long_streamed_in_pieces", 2, 65536, 65536, 128, 1, True, jnp.bfloat16,
+     None),
+    ("clamped_index_maps", 8, 2048, 2048, 128, 1, True, jnp.bfloat16,
+     _small(128)),
+]
+
+
+@pytest.mark.parametrize("case", CASES, ids=[c[0] for c in CASES])
+def test_forward_and_backward_compile(one_chip, case):
+    _, bh, sq, sk, d, rep, causal, dtype, tiles = case
+
+    def sds(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, kv = sds(bh, sq, d), sds(bh // rep, sk, d)
+
+    def fwd(q_, k_, v_):
+        return fa._flash_fwd_bhsd(q_, k_, v_, causal, 0.088, tiles=tiles,
+                                  interpret=False, q_per_kv=rep)
+
+    def bwd(q_, k_, v_, o_, lse_, g_):
+        return fa._flash_bwd_bhsd(q_, k_, v_, o_, lse_, g_, causal, 0.088,
+                                  tiles=tiles, interpret=False, q_per_kv=rep)
+
+    text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1
+    text = jax.jit(bwd).lower(q, kv, kv, q, sds(bh, sq, dt=jnp.float32),
+                              q).compile().as_text()
+    assert "fa_bwd_dq" in text and "fa_bwd_dkv" in text
+
+
+def test_wrappers_compile(one_chip):
+    """head_dim 96 through the public wrapper (zero-padded to 128) with its
+    gradient, and the RMSNorm epilogue riding the forward's flush."""
+    from paddle_tpu.framework import flags as _flags
+
+    def sds(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def grads(q, k, v):
+        return jax.grad(lambda a, b, c: jnp.sum(fa.flash_attention_bshd(
+            a, b, c, causal=True).astype(jnp.float32)), (0, 1, 2))(q, k, v)
+
+    old = _flags.flag_value("flash_attention_bwd")
+    _flags.set_flags({"FLAGS_flash_attention_bwd": "pallas"})
+    try:
+        x = sds(2, 512, 4, 96)
+        text = jax.jit(grads).lower(x, x, x).compile().as_text()
+    finally:
+        _flags.set_flags({"FLAGS_flash_attention_bwd": old})
+    assert "fa_fwd" in text and "fa_bwd_dkv" in text
+
+    def epilogue(q, k, v, res, w):
+        return fa.flash_attention_rms_epilogue_bshd(q, k, v, res, w)
+
+    q, kv = sds(2, 2048, 8, 128), sds(2, 2048, 2, 128)
+    text = jax.jit(epilogue).lower(q, kv, kv, q, sds(128)).compile().as_text()
+    assert "fa_fwd" in text

@@ -1,10 +1,14 @@
 """Measure Pallas flash attention vs XLA dense attention on real hardware.
 
-VERDICT r4 #2: the flash kernel (ops/pallas/flash_attention.py) had never
-executed on a TPU. This tool times fwd and fwd+bwd for the dense path, the
-full-Pallas path, and the hybrid (Pallas fwd + XLA-remat bwd — the r5
-`flash_attention_bwd` modes) across seq 1024-4096 (causal, bf16), runs the
-block-size autotuner on hardware, and writes .flash_vs_xla.json.
+This tool times fwd and fwd+bwd for the dense path, the full-Pallas path
+(ops/pallas/flash_attention.py: two-level tiles chosen from the shape by
+`choose_tiles`, one causal sweep), and the hybrid (Pallas fwd + XLA-remat
+bwd — the `flash_attention_bwd` modes) across seq 1024-4096 (causal,
+bf16); times each of the three kernels alone (fa_fwd, fa_bwd_dq,
+fa_bwd_dkv) at the tiles the chooser hands the shape, with their grid
+steps; with --tune also times the autotuner's (resident, sub) row
+candidates; and writes the table that tools/bake_flash_blocks.py bakes
+into the attention ledger.
 
 Timing method: each measurement runs N iterations INSIDE one compiled
 lax.scan so per-dispatch launch overhead is amortized out of the kernel
@@ -69,6 +73,43 @@ def timeit(run, *args, reps=3):
     return best / N_ITERS
 
 
+def kernel_ms(b, h, seq, d, q_per_kv=1):
+    """Each flash kernel alone at the chooser's tiles: {kernel name: ms a
+    call} and the tiles and grid steps behind them. One kernel's output is
+    all a timed program uses, so XLA drops the other calls."""
+    from paddle_tpu.ops.pallas import flash_attention as fa
+    bh = b * h
+    rng = np.random.RandomState(1)
+    q, g = (jnp.asarray(rng.randn(bh, seq, d), jnp.bfloat16)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(bh // q_per_kv, seq, d), jnp.bfloat16)
+            for _ in range(2))
+    scale = d ** -0.5
+    tiles = fa.choose_tiles(seq, seq, d, 2)
+    out, lse = jax.jit(lambda a, b_, c: fa._flash_fwd_bhsd(
+        a, b_, c, True, scale, q_per_kv=q_per_kv))(q, k, v)
+
+    def fsum(*xs):
+        return sum(jnp.sum(x.astype(jnp.float32)) for x in xs)
+
+    def bwd(pick):
+        def step(q_, k_, v_):
+            return fsum(*pick(fa._flash_bwd_bhsd(
+                q_, k_, v_, out, lse, g, True, scale, q_per_kv=q_per_kv)))
+        return step
+
+    steps = {
+        "fa_fwd": lambda q_, k_, v_: fsum(fa._flash_fwd_bhsd(
+            q_, k_, v_, True, scale, q_per_kv=q_per_kv)[0]),
+        "fa_bwd_dq": bwd(lambda o: o[:1]),
+        "fa_bwd_dkv": bwd(lambda o: o[1:]),
+    }
+    return {"ms": {name: round(timeit(amortized(step), q, k, v) * 1e3, 3)
+                   for name, step in steps.items()},
+            "tiles": {"fwd": tiles.fwd, "dq": tiles.dq, "dkv": tiles.dkv},
+            "grid_steps": tiles.grid_steps(bh, seq, seq)}
+
+
 def attention_flops(b, h, sq, sk, d, causal, bwd=False):
     """Matmul FLOPs of attention (2*bhs^2*d for QK^T, same for PV);
     backward re-does ~2.5x the forward matmuls (dQ, dK, dV, P remat)."""
@@ -95,9 +136,10 @@ def main():
     # <= ~512 MB. head_dim 96 rows measure the zero-pad path (llama_780m)
     shapes = [(1024, 8, 16, 128), (2048, 4, 8, 128), (4096, 1, 8, 128),
               (2048, 4, 8, 96)]
-    # autotuned separately (no dense A/B, so no logits-buffer cap):
-    # (2048, 4, 16, 128) is THE bench shape (llama_535m b4, 16 heads,
-    # d128) — its blocks are the ones worth shipping as defaults
+    # timed a kernel at a time (no dense A/B, so no logits-buffer cap):
+    # (2048, 4, 16, 128) is the benchmark's training shape (gpt3-xl-d12
+    # and llama_535m: batch 4, 16 heads, d 128) — `_TILE_ROWS` in
+    # flash_attention.py was measured there
     tune_shapes = shapes + [(2048, 4, 16, 128)]
     if not on_tpu:
         shapes = [(256, 1, 2, 128), (256, 1, 2, 96)]
@@ -167,31 +209,36 @@ def main():
             f"({td/tf:.2f}x) | fwd+bwd ms: pallas {tg['pallas']*1e3:.2f} "
             f"hybrid {tg['hybrid']*1e3:.2f} dense {tg['dense']*1e3:.2f}")
 
-    # hardware autotune: winners for each training shape
+    # each kernel alone, at the chooser's tiles
+    kernels = {}
+    for seq, b, h, d in tune_shapes:
+        if d % 128:
+            continue    # the wrapper pads such head dims; the A/B rows do
+        res = kernel_ms(b, h, seq, d)
+        kernels[f"s{seq}_d{d}_bh{b * h}"] = res
+        log(f"kernels seq={seq} bh={b * h}: {res['ms']} ms, grid steps "
+            f"{res['grid_steps']}, tiles {res['tiles']}")
+
+    # --tune: the autotuner's (resident, sub) row candidates on hardware,
+    # ~5 x fwd/bwd compiles a shape; the table alone needs none of it
     tuned = {}
-    # FLASH_TABLE_SKIP_AUTOTUNE: the 9-candidate x fwd/bwd x 5-shape sweep
-    # is ~90 compiles; set it to run the A/B table alone when chip
-    # minutes are short, leaving the sweep for the run whose config ships.
-    skip_tune = os.environ.get(
-        "FLASH_TABLE_SKIP_AUTOTUNE", "").lower() in ("1", "true", "yes")
-    if on_tpu and not skip_tune:
-        from paddle_tpu.ops.pallas.flash_attention import _tuned_blocks
+    if "--tune" in sys.argv:
+        from paddle_tpu.ops.pallas.flash_attention import _tiles_for
         at.enable_autotune()
         for seq, b, h, d in tune_shapes:
             for kind in ("fwd", "bwd"):
-                win = _tuned_blocks(kind, b * h, seq, seq, d,
-                                    jnp.bfloat16, True, False)
-                tuned[f"{kind}_s{seq}_d{d}_bh{b * h}"] = list(win)
+                win = _tiles_for(kind, b * h, seq, seq, d, jnp.bfloat16,
+                                 True, not on_tpu)
+                tuned[f"{kind}_s{seq}_d{d}_bh{b * h}"] = {
+                    "fwd": win.fwd, "dq": win.dq, "dkv": win.dkv}
                 log(f"autotune {kind} seq={seq} bh={b * h}: winner {win}")
         at.disable_autotune()
-
-    if on_tpu and getattr(at, "timing_log", None):
         tuned["candidate_ms"] = {str(k): v for k, v in at.timing_log.items()}
 
     out = {"device": str(dev),
            "device_kind": getattr(dev, "device_kind", "?"),
            "causal": causal, "dtype": "bfloat16",
-           "rows": rows, "autotuned_blocks": tuned}
+           "rows": rows, "kernels": kernels, "autotuned_tiles": tuned}
     if on_tpu:
         os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
         path = os.path.join(REPO, "chiprun_out", "flash_vs_xla.json")

@@ -106,10 +106,19 @@ def is_grad_enabled_():
 
 
 class LazyGuard:
+    """Layers built inside create their parameters as zeros and put the
+    initializers' draws off until `Parameter.initialize()`: a model whose
+    weights are about to be loaded or installed pays for no draw
+    (reference: python/paddle/base/lazy_init.py LazyGuard)."""
+
     def __enter__(self):
+        from .nn.layer import layers
+        self._was, layers._lazy_init = layers._lazy_init, True
         return self
 
     def __exit__(self, *a):
+        from .nn.layer import layers
+        layers._lazy_init = self._was
         return False
 from . import geometric  # noqa: F401
 from . import utils  # noqa: F401

@@ -933,6 +933,14 @@ class Parameter(Tensor):
     def trainable(self, v):
         self.stop_gradient = not v
 
+    def initialize(self):
+        """Draw the initial value a `paddle.LazyGuard` put off (reference:
+        EagerParamBase.initialize). A no-op for any other parameter."""
+        pending = self.__dict__.pop("_lazy_initializer", None)
+        if pending is not None:
+            init, shape, dtype = pending
+            self._data = init._init(shape, dtype)
+
     def __repr__(self):
         return "Parameter containing:\n" + super().__repr__()
 

@@ -5,3 +5,7 @@ from .llama import (LlamaConfig, LlamaModel, LlamaForCausalLM, llama_tiny,  # no
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, gpt_tiny, gpt3_1p3b  # noqa: F401
 from .bert import (BertConfig, BertModel, BertForPretraining,  # noqa: F401
                    BertForSequenceClassification, bert_tiny, bert_base)
+from .granite_moe_hybrid import (GraniteMoeHybridConfig,  # noqa: F401
+                                 GraniteMoeHybridModel,
+                                 GraniteMoeHybridForCausalLM,
+                                 granite_hybrid_tiny)

@@ -21,7 +21,7 @@ from ...framework.core import Tensor, execute
 from ...framework.random import next_key
 
 __all__ = ["scaled_dot_product_attention", "flash_attention",
-           "flash_attn_unpadded", "sdp_kernel"]
+           "flash_attn_unpadded", "sdp_kernel", "attention_bshd"]
 
 
 def _xla_attention(q, k, v, bias=None, causal=False, scale=None, dropout_p=0.0,
@@ -85,6 +85,21 @@ def _use_pallas(q_shape, head_dim, has_bias, dtype=None, causal=True):
     dec = route(b * heads, seq, seq, head_dim,
                 dtype if dtype is not None else "bfloat16", causal)
     return dec.fwd == "pallas"
+
+
+def attention_bshd(q, k, v, is_causal=True, scale=None):
+    """Routed attention on raw arrays, (batch, seq, heads, head_dim) with
+    GQA-native k/v, for code that is already a pure jax function (a
+    rematerialised sub-block): the per-shape router picks the flash
+    kernels or dense XLA attention exactly as
+    scaled_dot_product_attention does. scale: the softmax scale
+    (default 1/sqrt(head_dim))."""
+    if _use_pallas(tuple(q.shape), q.shape[-1], False, dtype=q.dtype,
+                   causal=is_causal):
+        from ...ops.pallas.flash_attention import flash_attention_bshd
+        return flash_attention_bshd(q, k, v, causal=is_causal, scale=scale)
+    k, v = _expand_kv(k, v, q.shape[2])
+    return _xla_attention(q, k, v, causal=is_causal, scale=scale)
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

@@ -18,6 +18,8 @@ from ...framework.core import Parameter, Tensor
 
 __all__ = ["Layer", "LayerList", "ParameterList", "Sequential", "LayerDict"]
 
+_lazy_init = False      # True inside `with paddle.LazyGuard():`
+
 
 class HookRemoveHelper:
     _next_id = 0
@@ -71,8 +73,16 @@ class Layer:
             init = I._global_bias_init if is_bias else I._global_weight_init
         if init is None:
             init = I.Constant(0.0) if is_bias else I.XavierUniform()
-        data = init._init(tuple(int(s) for s in shape), dtype)
-        p = Parameter(data, name=name, trainable=trainable)
+        shape = tuple(int(s) for s in shape)
+        if _lazy_init:
+            # paddle.LazyGuard: zeros now, the draw when (if) the caller
+            # asks for it with Parameter.initialize()
+            p = Parameter(jnp.zeros(shape, dtype), name=name,
+                          trainable=trainable)
+            p._lazy_initializer = (init, shape, dtype)
+        else:
+            p = Parameter(init._init(shape, dtype), name=name,
+                          trainable=trainable)
         p.optimize_attr = {"learning_rate": lr}
         return p
 

@@ -268,6 +268,16 @@ CATALOG = {
     "train_mfu": (
         "gauge", "online model-FLOPs utilization (needs flops_per_token "
         "and peak_flops)", (), None),
+    "moe_held_assignment_share": (
+        "gauge", "routed (token, choice) assignments that landed on the "
+        "experts this program holds / tokens x top-k, over every layer's "
+        "own input on one sequence (held / routed experts under even "
+        "routing); a statistic of the weights it was taken at, set by "
+        "whoever builds the model, outside the step", (), None),
+    "moe_expert_load_max_over_mean": (
+        "gauge", "largest / mean number of assignments over the held "
+        "experts, same sequence, the layers' mean (1.0 = even load)", (),
+        None),
     "train_nonfinite_skips_total": (
         "counter", "batches skipped by the TrainSupervisor for a "
         "non-finite loss", (), None),
@@ -515,7 +525,15 @@ TRACE_SCOPES = {
     "pt.embed": "token (+ position) embedding lookup",
     "pt.attn": "attention sub-block: norm, QKV projections, rope, the "
                "attention kernel or paged attention, output projection",
-    "pt.mlp": "MLP sub-block with its norm",
+    "pt.mlp": "MLP sub-block with its norm (in an expert layer: the norm, "
+              "the shared expert and the residual)",
+    "pt.ssm": "state-space (Mamba-2) mixer: norm, in/out projections, "
+              "causal conv, the scan, gated norm, residual",
+    "pt.ssm.scan": "the chunked state-space scan alone (ops/mamba2.py)",
+    "pt.moe": "routed experts: router, dispatch, grouped expert matmuls "
+              "with their activation, combine",
+    "pt.moe.route": "what in pt.moe is no expert work: router logits, "
+                    "top-k, sort, the dispatch and combine gathers",
     "pt.head": "final norm + LM head matmul",
     "pt.loss": "cross entropy over the vocabulary",
     "pt.opt": "gradient clipping + optimizer update of the train step",

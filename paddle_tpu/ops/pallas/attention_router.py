@@ -38,6 +38,14 @@ provenance):
    measurement is disabled, or when routing for a TPU from a process
    that has none (tests).
 
+Before the second and third, on a ledger miss: **dense-too-large**. Where
+dense attention's float32 scores (batch_heads x seq_q x seq_k) would pass
+``_DENSE_SCORES_LIMIT`` (4 GiB, a quarter of a v5e's memory), dense is no
+candidate on a TPU: the kernels are chosen, nothing is measured (the
+measurement would itself allocate those scores beside whatever the
+process already holds) and the decision says why. Causal GQA 32/8 at seq
+8192 (8.6 GB of scores) is routed so.
+
 The router covers fwd and bwd independently: fwd=pallas + bwd=xla is the
 hybrid (flash forward, dense-remat backward), which round 5 measured
 winning at zero-padded head dims (d96) and PR 27's end-to-end A/B at that
@@ -91,8 +99,8 @@ class Decision:
     what the kernels would do is always there to read
     (tests/test_flash_attention.py holds it to the grids of traced
     calls).  source is machine-readable ('ledger-e2e' | 'ledger' |
-    'measured-tpu' | 'proxy' | 'heuristic'); provenance is the
-    human-readable audit string."""
+    'dense-too-large' | 'measured-tpu' | 'proxy' | 'heuristic');
+    provenance is the human-readable audit string."""
 
     fwd: str
     bwd: str
@@ -321,6 +329,11 @@ def _measure_tpu(bh, sq, sk, d, dtype, causal):
     return out
 
 
+# dense attention holds batch_heads x seq_q x seq_k float32 scores; past
+# this many bytes it is not a candidate on a TPU (a quarter of a v5e's HBM)
+_DENSE_SCORES_LIMIT = 4 * 2 ** 30
+
+
 def _heuristic(bh, sq, sk, d) -> str:
     """The legacy _use_pallas thresholds (calibrated to the r4/r5
     f32-operand kernels; kept only as the last-resort fallback)."""
@@ -379,6 +392,15 @@ def route(batch_heads: int, seq_q: int, seq_k: int, head_dim: int, dtype,
                     f"({json.dumps(iso.get('fwd_ms', {}))}) "
                     f"bwd={iso.get('bwd')} "
                     f"({json.dumps(iso.get('bwd_ms', {}))})"))
+
+    scores = 4 * batch_heads * seq_q * seq_k
+    if dec is None and plat == "tpu" and scores > _DENSE_SCORES_LIMIT:
+        dec = Decision(
+            fwd="pallas", bwd="pallas", source="dense-too-large",
+            provenance=(f"no ledger row; dense attention would hold "
+                        f"{scores / 2 ** 30:.1f} GiB of float32 scores "
+                        f"(limit {_DENSE_SCORES_LIMIT / 2 ** 30:.0f} GiB): "
+                        "the flash kernels, nothing measured"))
 
     if dec is None and mode == "auto":
         import jax

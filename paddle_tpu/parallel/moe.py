@@ -17,6 +17,12 @@ shard_map each ep-rank holds E/ep experts and B/ep tokens:
   5. all_to_all back + combine with gate weights
 
 Dropped tokens (over capacity) contribute zero — GShard semantics.
+
+`dropless_moe` is the other kind: a routed layer that is told which
+experts it holds (`experts_held = (first, count)`), routes over all of
+them and computes its own experts' part of the result, with room for every
+assignment that can land on a held expert. One shard of an expert-parallel
+group runs it as it stands; the exchange between shards is not written yet.
 """
 
 from __future__ import annotations
@@ -28,7 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-__all__ = ["moe_dispatch_combine", "ExpertParallelMoE", "gshard_dispatch"]
+__all__ = ["moe_dispatch_combine", "ExpertParallelMoE", "gshard_dispatch",
+           "route_top_k", "dropless_moe"]
 
 
 def gshard_dispatch(x, gate_logits, num_experts, capacity, top_k=2):
@@ -182,3 +189,124 @@ class ExpertParallelMoE:
             jax.nn.one_hot(top1, self.num_experts, dtype=probs.dtype), axis=0)
         aux = self.num_experts * jnp.sum(me * ce)
         return out, aux
+
+
+# -- dropless routed experts ---------------------------------------------------
+
+def route_top_k(x, router_w, top_k):
+    """(expert ids [T, k], gates [T, k] float32): the top-k of the router's
+    logits over ALL its outputs, gates = softmax over the k chosen logits.
+    Logits accumulate in float32 so that near-ties break as they would in
+    a float32 reference."""
+    logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
+    top_logits, top_ids = jax.lax.top_k(logits, top_k)
+    return top_ids, jax.nn.softmax(top_logits, axis=-1)
+
+
+def sorted_assignments(top_ids, experts_held):
+    """The (token, choice) assignments that land on held experts, sorted by
+    expert into `rows` = T * min(k, count) rows: no token can have more
+    held choices than that, so every one of them has a row, whatever the
+    routing. Returns (source [rows]: the flat assignment t * k + j a row
+    holds; slot [T, k]: the row of each assignment, `rows` where its
+    expert is not held; sizes [count]: rows of each held expert). Rows
+    past sum(sizes) hold assignments to experts that are not held."""
+    first, count = experts_held
+    tokens, k = top_ids.shape
+    rows = tokens * min(k, count)
+    local = top_ids - first
+    # an expert that is not held sorts last, under the id `count`
+    flat = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
+    slot = jnp.where(flat < count, jnp.argsort(order), rows)
+    return order[:rows], slot.reshape(tokens, k).astype(jnp.int32), sizes
+
+
+def _gather_rows(y, slot):
+    """out[i] = sum_j y[slot[i, j]], leaving out slots past y's rows. One
+    gather a column of `slot`, summed in float32: the [T, k, D] array a
+    single gather would make is k times the tokens' own size."""
+    rows = y.shape[0]
+    out = jnp.zeros((slot.shape[0], y.shape[1]), jnp.float32)
+    for j in range(slot.shape[1]):
+        s = slot[:, j]
+        out = out + jnp.where((s < rows)[:, None],
+                              y[jnp.minimum(s, rows - 1)], 0)
+    return out.astype(y.dtype)
+
+
+# Dispatch (tokens into expert order) and combine (expert order back onto
+# tokens) are each other's transposes, and both are gathers: a row belongs
+# to one assignment and an assignment has one row. Autodiff would write
+# either transpose as a scatter-add of tens of thousands of rows, which a
+# TPU runs far slower than the gather.
+
+@jax.custom_vjp
+def _dispatch(x, index, slot):
+    return x[index]
+
+
+def _dispatch_fwd(x, index, slot):
+    return x[index], (index, slot)
+
+
+def _dispatch_bwd(res, g):
+    index, slot = res
+    return _combine(g, index, slot), None, None
+
+
+@jax.custom_vjp
+def _combine(y, index, slot):
+    return _gather_rows(y, slot)
+
+
+def _combine_fwd(y, index, slot):
+    return _gather_rows(y, slot), (index, slot)
+
+
+def _combine_bwd(res, g):
+    index, slot = res
+    return _dispatch(g, index, slot), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held):
+    """The held experts' part of a routed gated-MLP layer.
+
+    x [T, D]; router_w [D, E] over ALL E experts; w_in [count, D, 2 * I]
+    and w_out [count, I, D] of the `count` experts held here, which are
+    experts `first .. first + count - 1` of the E; experts_held = (first,
+    count).
+
+        out[t] = sum, over those of t's top-k experts e that are held, of
+                 gate[t, e] * (silu(x W_in[e][:, :I]) * x W_in[e][:, I:]) W_out[e]
+
+    What the experts that are not held would add is left out: over the
+    shards of an expert-parallel group the parts add up to the whole layer
+    (tests/test_granite_moe_hybrid.py). Nothing is dropped: assignments
+    are sorted by expert, and the grouped product (`lax.ragged_dot`, which
+    the TPU compiler turns into a grouped-matmul kernel that visits only
+    the tiles that hold rows) has a row for every assignment that can land
+    here. Component scope `pt.moe.route` holds what is not expert work."""
+    tokens, k = x.shape[0], top_k
+    with jax.named_scope("pt.moe.route"):
+        top_ids, gates = route_top_k(x, router_w, k)
+        source, slot, sizes = sorted_assignments(top_ids, experts_held)
+        rows = source.shape[0]
+        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        token = source // k
+        xs = _dispatch(x, token, slot)
+        gate_rows = _dispatch(gates.reshape(tokens * k, 1), source,
+                              slot.reshape(tokens * k, 1))
+    inter = w_out.shape[1]
+    # rows past the last assignment hold whatever the kernel left there
+    h = jnp.where(live, jax.lax.ragged_dot(xs, w_in, sizes), 0)
+    act = (jax.nn.silu(h[:, :inter].astype(jnp.float32))
+           * h[:, inter:].astype(jnp.float32) * gate_rows).astype(x.dtype)
+    y = jax.lax.ragged_dot(act, w_out, sizes)
+    with jax.named_scope("pt.moe.route"):
+        return _combine(y, token, slot)

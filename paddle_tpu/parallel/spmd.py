@@ -267,23 +267,33 @@ class SpmdTrainer:
 
     def step_memory(self, batch):
         """XLA's buffer assignment for the compiled step at this batch, in
-        bytes per device: arguments, outputs, donated aliases, temporaries.
-        A step holds argument + output - alias + temp. On a TPU
+        bytes per device: arguments, outputs, donated aliases, temporaries,
+        and `peak`, the most the step holds at one time by the compiler's
+        own count (the figure it holds to the chip's memory; 0 where the
+        backend gives none). A step holds at most argument + output -
+        alias + temp; `peak` can be well under that sum (2.1 GiB under for
+        models/granite_moe_hybrid.py at 8k tokens, 0.05 for GPT). On a TPU
         device.memory_stats() counts live arrays only — a running
         program's temporaries (the activations) show up nowhere else.
-        Changes no state, but compiles the step a second time: jax keys
-        its compile caches on call-site metadata too, so this call never
-        hits the executable step() built."""
+        Changes no state. Lowers the step again; the lowering is the one
+        step() made, so the compile is a hit in jax's persistent cache
+        where one is set up (tests/test_spmd_trainer.py)."""
         from ..framework.random import get_rng_state
         batch_arrays = self._batch_arrays(batch)
+        # the scalars are made outside the mesh, as step() makes them: made
+        # under set_mesh they carry a sharding, the lowering differs by
+        # those two annotations, and neither compile cache is hit
+        step = jnp.asarray(self.step_count, jnp.int32)
+        lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         with jax.set_mesh(self.mesh):
             compiled = self._compiled.lower(
                 self.params, self.opt_state, batch_arrays,
-                get_rng_state()[0], jnp.asarray(self.step_count, jnp.int32),
-                jnp.asarray(self.optimizer.get_lr(), jnp.float32)).compile()
+                get_rng_state()[0], step, lr).compile()
         mem = compiled.memory_analysis()
-        return {k: int(getattr(mem, f"{k}_size_in_bytes"))
-                for k in ("argument", "output", "alias", "temp")}
+        out = {k: int(getattr(mem, f"{k}_size_in_bytes"))
+               for k in ("argument", "output", "alias", "temp")}
+        out["peak"] = int(getattr(mem, "peak_memory_in_bytes", 0))
+        return out
 
     def step(self, batch, rng_key=None):
         """batch: (x, y) of Tensors or arrays. Returns float loss.

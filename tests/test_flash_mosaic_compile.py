@@ -11,6 +11,7 @@ handed it loads the TPU's library, inside the fixture.
 """
 
 import os
+import re
 
 import pytest
 
@@ -120,3 +121,84 @@ def test_wrappers_compile(one_chip):
     q, kv = sds(2, 2048, 8, 128), sds(2, 2048, 2, 128)
     text = jax.jit(epilogue).lower(q, kv, kv, q, sds(128)).compile().as_text()
     assert "fa_fwd" in text
+
+
+def test_nope_gqa_at_8k_compiles_and_routes_to_the_kernels(one_chip):
+    """The attention layer of granite-4.0-h-small at the benchmark cell's
+    shape: causal GQA 32/8, d 128, seq 8192, softmax scale 1/128 (the
+    config's attention_multiplier, not 1/sqrt(d)), no rotary. The ledger
+    has no row above 4096; dense attention would hold 8 GiB of float32
+    scores, so the router names the kernels without measuring (source
+    `dense-too-large`), and they compile for a described v5e, forward and
+    backward, at the tiles the router's Decision records."""
+    from paddle_tpu.ops.pallas.attention_router import (
+        clear_routing_cache, route)
+    bh, seq, d, rep, scale = 32, 8192, 128, 4, 0.0078125
+    clear_routing_cache()
+    dec = route(bh, seq, seq, d, jnp.bfloat16, True, platform="tpu",
+                device_kind="TPU v5 lite")
+    assert (dec.fwd, dec.bwd, dec.source) == ("pallas", "pallas",
+                                              "dense-too-large")
+    tiles = fa.tiles_for_shape(bh, seq, seq, d, jnp.bfloat16, True)
+    assert dec.tiles == tiles
+    # rows resident per grid step: 1024 (forward), 512 (backward kernels);
+    # the whole sequence streamed past them: 32 x 8192 / rows steps a call
+    assert tiles.fwd == (1024, 8192, 1024) and tiles.dq == tiles.dkv \
+        == (512, 8192, 512)
+    assert dec.grid_steps == {"fa_fwd": 256, "fa_bwd_dq": 512,
+                              "fa_bwd_dkv": 512}
+    # a shape dense attention can hold is still the ledger's to rank
+    assert route(64, 2048, 2048, d, jnp.bfloat16, True, platform="tpu",
+                 device_kind="TPU v5 lite").source != "dense-too-large"
+
+    def sds(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, kv = sds(bh, seq, d), sds(bh // rep, seq, d)
+    text = jax.jit(lambda q_, k_, v_: fa._flash_fwd_bhsd(
+        q_, k_, v_, True, scale, tiles=tiles, interpret=False,
+        q_per_kv=rep)).lower(q, kv, kv).compile().as_text()
+    assert "fa_fwd" in text
+    text = jax.jit(lambda q_, k_, v_, o_, lse_, g_: fa._flash_bwd_bhsd(
+        q_, k_, v_, o_, lse_, g_, True, scale, tiles=tiles, interpret=False,
+        q_per_kv=rep)).lower(q, kv, kv, q, sds(bh, seq, dt=jnp.float32),
+                             q).compile().as_text()
+    assert "fa_bwd_dq" in text and "fa_bwd_dkv" in text
+
+
+def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(one_chip):
+    """`lax.ragged_dot` in parallel/moe.py dropless_moe becomes the TPU
+    compiler's own grouped-matmul kernel, whose op_name is the kernel's
+    name in place of the program's name stack, so the scope `pt.moe` is
+    lost on it. benchmark/layer_metrics/moe_time_share.json and
+    moe_grouped_matmul_roofline.json find it by that name: if a compiler
+    gives it another, this fails here, and the metrics do not go short in
+    silence on the chip. One FFN block of the published widths."""
+    import json
+    import os
+    from paddle_tpu.parallel.moe import dropless_moe
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    def routed(x, router, w_in, w_out):
+        with jax.named_scope("pt.moe"):
+            return dropless_moe(x, router, w_in, w_out, 10, (0, 9))
+
+    text = jax.jit(routed).lower(
+        sds(2048, 4096), sds(4096, 72), sds(9, 4096, 1536),
+        sds(9, 768, 4096)).compile().as_text()
+    kernels = [line for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    # x W_in, act W_out, and the kernel that lays the groups out for them
+    assert len(kernels) >= 2
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "layer_metrics")
+    for name in ("moe_time_share", "moe_grouped_matmul_roofline"):
+        with open(os.path.join(metrics, name + ".json")) as f:
+            rx = re.compile(json.load(f)["params"]["regex"])
+        for line in kernels:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert rx.search(op_name), (name, op_name)
+            assert "pt.moe" not in op_name      # or the first alternative
+            # of moe_time_share's pattern would count the kernel twice

@@ -682,10 +682,17 @@ def test_places_construct():
     paddle.CUDAPinnedPlace()
 
 
-def test_lazy_guard_defers_nothing_on_cpu():
+def test_lazy_guard_puts_the_draw_off_until_initialize():
     from paddle_tpu import LazyGuard
+    import paddle_tpu.nn as nn
     with LazyGuard():
-        import paddle_tpu.nn as nn
         lin = nn.Linear(3, 2)
+    assert not np.asarray(lin.weight._data).any()       # zeros, no draw
     y = lin(T(rs.randn(2, 3).astype(np.float32)))
     assert list(y.shape) == [2, 2]
+    lin.weight.initialize()
+    assert np.asarray(lin.weight._data).any()
+    drawn = np.asarray(lin.weight._data).copy()
+    lin.weight.initialize()                             # once only
+    assert (np.asarray(lin.weight._data) == drawn).all()
+    assert np.asarray(nn.Linear(3, 2).weight._data).any()   # guard is off

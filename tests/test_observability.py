@@ -229,6 +229,32 @@ class TestCatalog:
             f"docs-only={documented - set(obs_catalog.CATALOG)}, "
             f"code-only={set(obs_catalog.CATALOG) - documented}")
 
+    def test_moe_gauges_are_declared_and_set_from_the_routing_function(self):
+        """The two routed-expert gauges: gauges without labels in the
+        catalog and in OBSERVABILITY.md's table, and what the model's own
+        routing function (parallel/moe.py) gives is what they hold: even
+        routing over a share of the experts reads held / routed and 1.0."""
+        import jax.numpy as jnp
+        from paddle_tpu.parallel.moe import sorted_assignments
+        text = open(os.path.join(REPO, "OBSERVABILITY.md")).read()
+        names = ("moe_held_assignment_share", "moe_expert_load_max_over_mean")
+        for name in names:
+            assert obs_catalog.CATALOG[name][0] == "gauge"
+            assert obs_catalog.CATALOG[name][2] == ()
+            assert re.search(rf"^\| `{name}` \| gauge \| - \|", text,
+                             re.MULTILINE)
+        # 16 tokens, top-2 of 8: token t takes experts t % 8 and (t + 1) % 8
+        ids = jnp.stack([jnp.arange(16) % 8, (jnp.arange(16) + 1) % 8], 1)
+        _, _, sizes = sorted_assignments(ids, (2, 4))
+        sizes = [int(n) for n in sizes]
+        assert sizes == [4, 4, 4, 4]
+        r = obs_metrics.MetricRegistry(enabled=True)
+        obs_catalog.register_all(r)
+        r.get(names[0]).set(sum(sizes) / 32.0)
+        r.get(names[1]).set(max(sizes) / (sum(sizes) / 4.0))
+        assert r.get(names[0]).value == 4 / 8.0
+        assert r.get(names[1]).value == 1.0
+
     def test_metric_refuses_unknown_names(self):
         with pytest.raises(KeyError, match="catalog"):
             obs.metric("not_a_registered_name_total")

@@ -36,6 +36,8 @@ TRAIN_SCOPES = ("pt.embed", "pt.attn", "pt.mlp", "pt.head", "pt.loss",
                 "pt.opt")
 SERVE_SCOPES = ("pt.embed", "pt.attn", "pt.mlp", "pt.head",
                 "pt.serve.gather", "pt.serve.attend", "pt.serve.sample")
+# what models/granite_moe_hybrid.py adds to a train step's names
+HYBRID_SCOPES = ("pt.ssm", "pt.ssm.scan", "pt.moe", "pt.moe.route")
 
 
 def _trainer():
@@ -230,6 +232,44 @@ def test_train_step_lowers_with_every_scope_forward_and_backward():
         assert hits, scope
         if scope != "pt.opt":       # the update has no backward
             assert any("transpose(jvp(" in h for h in hits), scope
+
+
+def test_hybrid_train_step_lowers_with_the_mixer_and_expert_scopes():
+    """State-space and routed-expert work carries its scope through the
+    per-sub-block rematerialisation, forward and backward, nested as the
+    readers' patterns expect (pt.ssm.scan inside pt.ssm, pt.moe.route
+    inside pt.moe); attention and the shared expert keep pt.attn, pt.mlp."""
+    from paddle_tpu.framework.random import get_rng_state
+    from paddle_tpu.models.granite_moe_hybrid import granite_hybrid_tiny
+    from paddle_tpu.parallel import DP_ONLY_RULES
+    paddle.seed(0)
+    model = granite_hybrid_tiny()
+    opt = optimizer.AdamW(1e-3, parameters=model.parameters())
+    trainer = SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
+                          DP_ONLY_RULES)
+    ids = np.zeros((1, 16), np.int32)
+    batch = trainer._batch_arrays((ids, ids))
+    with jax.set_mesh(trainer.mesh):
+        text = trainer._compiled.lower(
+            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
+            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
+        ).as_text(debug_info=True)
+    for scope in HYBRID_SCOPES + TRAIN_SCOPES:
+        hits = _scope_hits(text, scope)
+        assert hits, scope
+        if scope != "pt.opt":
+            assert any("transpose(jvp(" in h for h in hits), scope
+    assert any("pt.ssm/pt.ssm.scan" in h
+               for h in _scope_hits(text, "pt.ssm.scan"))
+    assert any("pt.moe/pt.moe.route" in h
+               for h in _scope_hits(text, "pt.moe.route"))
+
+
+def test_every_declared_scope_is_held_by_a_test_and_none_else():
+    """catalog.py TRACE_SCOPES against the scopes these tests look for in
+    lowered programs, both directions."""
+    assert set(TRAIN_SCOPES) | set(SERVE_SCOPES) | set(HYBRID_SCOPES) \
+        == set(TRACE_SCOPES)
 
 
 @pytest.fixture(scope="module")

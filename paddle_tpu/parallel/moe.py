@@ -199,7 +199,11 @@ def route_top_k(x, router_w, top_k):
     Logits accumulate in float32 so that near-ties break as they would in
     a float32 reference."""
     logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
-    top_logits, top_ids = jax.lax.top_k(logits, top_k)
+    _, top_ids = jax.lax.top_k(logits, top_k)
+    # the chosen logits picked out by comparison, not taken from top_k:
+    # the gradient is then a sum over a mask and not a scatter-add
+    chosen = top_ids[..., None] == jnp.arange(logits.shape[-1])
+    top_logits = jnp.sum(jnp.where(chosen, logits[..., None, :], 0), axis=-1)
     return top_ids, jax.nn.softmax(top_logits, axis=-1)
 
 
@@ -218,7 +222,10 @@ def sorted_assignments(top_ids, experts_held):
     # an expert that is not held sorts last, under the id `count`
     flat = jnp.where((local >= 0) & (local < count), local, count).reshape(-1)
     order = jnp.argsort(flat, stable=True)
-    sizes = jnp.bincount(flat, length=count + 1)[:count].astype(jnp.int32)
+    # counted by comparison: a bincount is a scatter-add, which a TPU runs
+    # one assignment after the other
+    sizes = jnp.sum(flat[:, None] == jnp.arange(count), axis=0,
+                    dtype=jnp.int32)
     slot = jnp.where(flat < count, jnp.argsort(order), rows)
     return order[:rows], slot.reshape(tokens, k).astype(jnp.int32), sizes
 
@@ -274,6 +281,36 @@ _dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
+# One number an assignment (its gate) goes into expert order by a sort and
+# comes back by a sort: a gather of single numbers costs a TPU as much an
+# index as a gather of whole rows does.
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _in_slot_order(values, slot, rows):
+    """out[slot[a]] = values[a] for the flat assignments a that have one of
+    the `rows` rows, and after them the others' values, in their own order
+    (`sorted_assignments`' order). Only rows that hold an assignment send
+    a gradient back: the others' is whatever the kernels left there."""
+    return _in_slot_order_fwd(values, slot, rows)[0]
+
+
+def _in_slot_order_fwd(values, slot, rows):
+    _, order, out = jax.lax.sort(
+        (slot, jnp.arange(slot.shape[0], dtype=slot.dtype), values),
+        num_keys=1)
+    return out[:rows], (order, slot)
+
+
+def _in_slot_order_bwd(rows, res, g):
+    order, slot = res
+    g = jnp.pad(g, (0, order.shape[0] - rows))
+    return jnp.where(slot < rows,
+                     jax.lax.sort((order, g), num_keys=1)[1], 0), None
+
+
+_in_slot_order.defvjp(_in_slot_order_fwd, _in_slot_order_bwd)
+
+
 def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held):
     """The held experts' part of a routed gated-MLP layer.
 
@@ -290,8 +327,14 @@ def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held):
     (tests/test_granite_moe_hybrid.py). Nothing is dropped: assignments
     are sorted by expert, and the grouped product (`lax.ragged_dot`, which
     the TPU compiler turns into a grouped-matmul kernel that visits only
-    the tiles that hold rows) has a row for every assignment that can land
-    here. Component scope `pt.moe.route` holds what is not expert work."""
+    the tiles that hold rows: on the chip a call costs the same in a
+    buffer of 4,096 rows as in one of 18,432, and its rate follows the
+    rows a group has, PERF.md section 6, PR 29) has a row for every
+    assignment that can land here. Rows past the last assignment are not
+    written by that kernel and may hold anything, NaN too: nothing may
+    carry them on, forward or backward. No part of the layer is a
+    scatter, and no gather fetches single numbers: a TPU pays both by the
+    index. Component scope `pt.moe.route` holds what is not expert work."""
     tokens, k = x.shape[0], top_k
     with jax.named_scope("pt.moe.route"):
         top_ids, gates = route_top_k(x, router_w, k)
@@ -299,9 +342,14 @@ def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held):
         rows = source.shape[0]
         live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
         token = source // k
+        gate_rows = _in_slot_order(gates.reshape(-1), slot.reshape(-1),
+                                   rows)[:, None]
+        held = min(k, experts_held[1])
+        if held < k:
+            # a token's rows first: it has `held` at most, so the combine
+            # gathers that many columns and not one a choice
+            slot = jnp.sort(slot, axis=1)[:, :held]
         xs = _dispatch(x, token, slot)
-        gate_rows = _dispatch(gates.reshape(tokens * k, 1), source,
-                              slot.reshape(tokens * k, 1))
     inter = w_out.shape[1]
     # rows past the last assignment hold whatever the kernel left there
     h = jnp.where(live, jax.lax.ragged_dot(xs, w_in, sizes), 0)

@@ -173,7 +173,11 @@ def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(one_chip):
     lost on it. benchmark/layer_metrics/moe_time_share.json and
     moe_grouped_matmul_roofline.json find it by that name: if a compiler
     gives it another, this fails here, and the metrics do not go short in
-    silence on the chip. One FFN block of the published widths."""
+    silence on the chip. One FFN block of the published widths, forward
+    and backward. What is not the kernel holds no scatter: the counts, the
+    gates' way into expert order and back and the chosen logits' gradient
+    are comparisons and sorts, which the chip does not run one index
+    after the other."""
     import json
     import os
     from paddle_tpu.parallel.moe import dropless_moe
@@ -185,13 +189,18 @@ def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(one_chip):
         with jax.named_scope("pt.moe"):
             return dropless_moe(x, router, w_in, w_out, 10, (0, 9))
 
-    text = jax.jit(routed).lower(
+    def grads(*arrays):
+        return jax.value_and_grad(lambda *a: jnp.sum(
+            routed(*a).astype(jnp.float32)), (0, 1, 2, 3))(*arrays)
+
+    text = jax.jit(grads).lower(
         sds(2048, 4096), sds(4096, 72), sds(9, 4096, 1536),
         sds(9, 768, 4096)).compile().as_text()
     kernels = [line for line in text.splitlines()
                if "custom-call(" in line and "tpu_custom_call" in line]
-    # x W_in, act W_out, and the kernel that lays the groups out for them
-    assert len(kernels) >= 2
+    # x W_in and act W_out, and the two products of each in the backward
+    assert len(kernels) >= 6
+    assert not re.search(r" scatter\(", text)
     metrics = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark", "layer_metrics")
     for name in ("moe_time_share", "moe_grouped_matmul_roofline"):

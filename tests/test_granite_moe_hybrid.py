@@ -210,6 +210,141 @@ def test_dropless_under_skew_loses_nothing():
                                atol=1e-5)
 
 
+@pytest.mark.parametrize("held", [(0, 8), (4, 2), (7, 1)],
+                         ids=["all_experts", "two_of_eight", "the_last_one"])
+def test_sorted_assignments_against_numpy(held):
+    """Rows, slots and sizes against a stable numpy sort and a bincount,
+    for a routing that sends some experts nothing."""
+    rs = np.random.RandomState(8)
+    first, count = held
+    k, tokens = 3, 50
+    ids = np.stack([rs.choice([0, 1, 2, 4, 6, 7], k, replace=False)
+                    for _ in range(tokens)]).astype(np.int32)  # never 3, 5
+    source, slot, sizes = jax.jit(sorted_assignments, static_argnums=1)(
+        jnp.asarray(ids), held)
+    rows = tokens * min(k, count)
+    local = ids.reshape(-1) - first
+    flat = np.where((local >= 0) & (local < count), local, count)
+    order = np.argsort(flat, kind="stable")
+    np.testing.assert_array_equal(np.asarray(source), order[:rows])
+    np.testing.assert_array_equal(
+        np.asarray(sizes), np.bincount(flat, minlength=count + 1)[:count])
+    want = np.where(flat < count, np.argsort(order), rows)
+    np.testing.assert_array_equal(np.asarray(slot).reshape(-1), want)
+
+
+def test_gates_reach_their_rows_by_a_sort_and_come_back_by_one():
+    """`_in_slot_order` is the gather values[source], and its transpose
+    is the gather's for the rows that hold an assignment. The rows that
+    hold none send nothing back, whatever they hold: on the chip the
+    grouped-matmul kernels leave them unwritten, and a NaN there reached
+    the router through the gates' gradient (PR 29's first chip run)."""
+    rs = np.random.RandomState(9)
+    ids = jnp.asarray(np.stack([rs.choice(8, 3, replace=False)
+                                for _ in range(40)]).astype(np.int32))
+    held = (2, 4)
+    source, slot, sizes = sorted_assignments(ids, held)
+    rows, live = source.shape[0], int(sizes.sum())
+    assert 0 < live < rows
+    values = jnp.asarray(rs.randn(120).astype(np.float32))
+    weights = rs.randn(rows).astype(np.float32)
+    np.testing.assert_array_equal(
+        np.asarray(moe._in_slot_order(values, slot.reshape(-1), rows)),
+        np.asarray(values[source]))
+
+    def by_sort(v, w):
+        return jnp.sum(moe._in_slot_order(v, slot.reshape(-1), rows) * w)
+
+    def by_gather(v):
+        return jnp.sum(v[source[:live]] * weights[:live])
+
+    want = np.asarray(jax.grad(by_gather)(values))
+    for rest in (weights[live:], np.full(rows - live, np.nan, np.float32)):
+        w = jnp.asarray(np.concatenate([weights[:live], rest]))
+        np.testing.assert_array_equal(
+            np.asarray(jax.grad(by_sort)(values, w)), want)
+
+
+def _leaving_rows_unwritten(real):
+    """`lax.ragged_dot` as the chip's kernel behaves: the rows past the
+    last group are not written (here: NaN), in the product and in the
+    rows' gradient, and what the caller put there is not read."""
+    def poison(out, sizes):
+        live = jnp.arange(out.shape[0]) < jnp.sum(sizes)
+        return jnp.where(live[:, None], out, jnp.nan)
+
+    @jax.custom_vjp
+    def ragged_dot(lhs, rhs, sizes):
+        return poison(real(lhs, rhs, sizes), sizes)
+
+    def fwd(lhs, rhs, sizes):
+        return ragged_dot(lhs, rhs, sizes), (lhs, rhs, sizes)
+
+    def bwd(res, g):
+        lhs, rhs, sizes = res
+        live = jnp.arange(g.shape[0]) < jnp.sum(sizes)
+        d_lhs, d_rhs = jax.vjp(lambda a, b: real(a, b, sizes), lhs, rhs)[1](
+            jnp.where(live[:, None], g, 0))
+        return poison(d_lhs, sizes), d_rhs, None
+
+    ragged_dot.defvjp(fwd, bwd)
+    return ragged_dot
+
+
+def test_rows_that_hold_no_assignment_may_hold_anything(monkeypatch):
+    """Output and every gradient are the same, and finite, when the
+    grouped products leave the rows past the last assignment unwritten,
+    as the TPU's kernel does and the CPU's does not (it writes zeros, so
+    no other test here sees what leaks out of those rows)."""
+    rs = np.random.RandomState(11)
+    router, w_in, w_out = _moe_weights(rs)
+    x = jnp.asarray(rs.randn(64, 32).astype(np.float32))
+    k, held = 3, (4, 2)
+    args = (x, router, w_in[4:6], w_out[4:6])
+
+    def grads():
+        def f(x_, r_, wi, wo):
+            out = moe.dropless_moe(x_, r_, wi, wo, k, held)
+            return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(
+                out.shape))), out
+        return jax.jit(jax.grad(f, (0, 1, 2, 3), has_aux=True))(*args)
+
+    g_want, out_want = grads()
+    monkeypatch.setattr(jax.lax, "ragged_dot",
+                        _leaving_rows_unwritten(jax.lax.ragged_dot))
+    g_got, out_got = grads()
+    np.testing.assert_array_equal(np.asarray(out_got), np.asarray(out_want))
+    for g, r in zip(g_got, g_want):
+        assert np.abs(np.asarray(r)).max() > 0
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+def test_gates_are_the_softmax_of_the_top_k_logits():
+    """route_top_k picks the chosen logits out by comparison; values and
+    gradients are those of lax.top_k's own values."""
+    rs = np.random.RandomState(10)
+    x = jnp.asarray(rs.randn(33, 32).astype(np.float32))
+    router = jnp.asarray(rs.randn(32, 8).astype(np.float32))
+    cot = jnp.asarray(rs.randn(33, 3).astype(np.float32))
+
+    def plain(x_, r_):
+        top, ids = jax.lax.top_k(x_ @ r_, 3)
+        return ids, jax.nn.softmax(top, axis=-1)
+
+    with jax.default_matmul_precision("highest"):
+        ids, gates = route_top_k(x, router, 3)
+        want_ids, want = plain(x, router)
+        got_g = jax.grad(lambda a, b: jnp.sum(
+            route_top_k(a, b, 3)[1] * cot), (0, 1))(x, router)
+        want_g = jax.grad(lambda a, b: jnp.sum(plain(a, b)[1] * cot),
+                          (0, 1))(x, router)
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(want_ids))
+    np.testing.assert_array_equal(np.asarray(gates), np.asarray(want))
+    for g, r in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=1e-6,
+                                   atol=1e-6)
+
+
 def test_ffn_in_token_blocks_is_the_ffn(tiny, monkeypatch):
     model, params, ids = tiny
     ids = np.tile(ids[:, :24], (1, 2))          # 2 x 48 tokens: 6 blocks of 16

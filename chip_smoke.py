@@ -10,7 +10,7 @@ at the full width of models the repo supports, with seeded random weights:
            (interpret=False) at the trainer's attention shape, against the
            dense reference in float32 "highest" precision
   server   inference.ContinuousBatchingEngine over LlamaForCausalLM at the
-           llama_535m widths (bench.py), a dozen mixed-length requests, two
+           llama_535m widths, a dozen mixed-length requests, two
            of them checked against a plain jax.numpy forward
   trainer  parallel.SpmdTrainer + GPT_SHARDING_RULES over GPTForCausalLM at
            gpt3_1p3b widths with the depth cut to what one chip holds
@@ -55,8 +55,8 @@ FOUR_CHIP_MESH = dict(mp=2, sharding=2)
 # sums are reduced in a different order across the mp shards, nothing else
 FOUR_CHIP_LOSS_TOL = 0.02
 
-# bench.py llama_535m (the model the engine reads parameter names of): all
-# 8 layers fit beside the pool, so nothing is cut.
+# llama_535m (the model the engine reads parameter names of): all 8 layers
+# fit beside the pool, so nothing is cut.
 SERVE_CONFIG = dict(vocab_size=32000, hidden_size=2048, intermediate_size=5504,
                     num_hidden_layers=8, num_attention_heads=16,
                     max_position_embeddings=2048)
@@ -264,7 +264,6 @@ def _pad96_case(b, seq, h):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from paddle_tpu.framework import flags as _flags
     from paddle_tpu.nn.functional.attention import _xla_attention
     from paddle_tpu.ops.pallas import flash_attention as fa
 
@@ -287,12 +286,7 @@ def _pad96_case(b, seq, h):
                 *(a.astype(jnp.float32) for a in (q_, k_, v_)))
             return (ref,) + pull(g_.astype(jnp.float32))
 
-    prev = _flags.flag_value("flash_attention_bwd")
-    _flags.set_flags({"flash_attention_bwd": "pallas"})
-    try:
-        got = jax.block_until_ready(flash(q, k, v, g))
-    finally:
-        _flags.set_flags({"flash_attention_bwd": prev})
+    got = jax.block_until_ready(flash(q, k, v, g))
     res = _compare("d96_zero_pad", got, dense(q, k, v, g))
     log(f"kernel d96_zero_pad: out_err={res['out_max_abs_err']:.4f} "
         + " ".join(f"{n}_err={e:.4f}"
@@ -538,27 +532,18 @@ def server_phase(config=None, engine=None, prompts=SERVE_PROMPTS,
 def _trainer_attention(batch, heads, seq, head_dim, dtype, batch_split=1,
                        head_split=1):
     """What scaled_dot_product_attention selects for the trainer's causal
-    attention — asked of the same predicates the model calls, so a cached
-    router decision is reported too. The model asks for the forward at the
-    global shape; under a mesh the kernel runs per shard (flash_attention
-    _mesh_spec), and its tiles and backward are routed at the shard's."""
-    import jax
-    from paddle_tpu.nn.functional.attention import _use_pallas
-    if jax.default_backend() != "tpu":
-        return {"forward": "xla_dense",
-                "why": f"backend is {jax.default_backend()}"}
+    attention: the rule the model asks. Under a mesh the kernel runs per
+    shard (flash_attention _mesh_spec), so the tiles and grid steps
+    reported are the shard's."""
     from paddle_tpu.ops.pallas.attention_router import route
-    if not _use_pallas((batch, seq, heads, head_dim), head_dim, False,
-                       dtype=dtype, causal=True):
+    dec = route(batch * heads, seq, seq, head_dim, dtype, True)
+    if dec.fwd != "pallas":
         return {"forward": "xla_dense", "backward": "xla_autodiff",
-                "source": route(batch * heads, seq, seq, head_dim, dtype,
-                                True).source}
+                "why": dec.why}
     bh = (batch // batch_split) * (heads // head_split)
     local = route(bh, seq, seq, head_dim, dtype, True)
     return {"forward": "pallas_flash", "backward": local.bwd,
-            "forward_source": route(batch * heads, seq, seq, head_dim,
-                                    dtype, True).source,
-            "per_shard_bh": bh, "backward_source": local.source,
+            "why": dec.why, "per_shard_bh": bh,
             "tiles": dataclasses.asdict(local.tiles),
             "grid_steps": local.grid_steps,
             "partitioned_by": ("shard_map over batch and heads"
@@ -782,8 +767,8 @@ def main(argv=None):
             f"{_gib(_peak_bytes(device))}")
     from paddle_tpu.ops.pallas.attention_router import decision_log
     for key, dec in decision_log():
-        log(f"router decision (bh, sq, sk, d, dtype, causal)={key}: "
-            f"fwd={dec.fwd} bwd={dec.bwd} source={dec.source} "
+        log(f"attention decision (bh, sq, sk, d, dtype, causal)={key}: "
+            f"fwd={dec.fwd} bwd={dec.bwd} why={dec.why!r} "
             f"grid steps {dec.grid_steps}")
     _cache_record(cache_dir, meter, "four-chip" if four_chip else "one-chip")
     log(f"total {time.perf_counter() - t_all:.1f}s")

@@ -1,6 +1,6 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point (bench.py, chip_smoke.py, tools/): the
+One rule for every entry point (benchmark/run.py, chip_smoke.py, tools/): the
 operator places the cache from outside with ``JAX_COMPILATION_CACHE_DIR``
 (JAX reads that variable itself); when it is unset the cache sits at a
 fixed path inside the checkout. The path is part of the cache key, so a

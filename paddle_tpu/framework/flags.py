@@ -86,7 +86,9 @@ define_flag("use_bfloat16_matmul", True, "prefer bf16 matmul accumulation on MXU
 define_flag("log_memory_stats", False, "log live buffer stats (ref: FLAGS_log_memory_stats)")
 define_flag("benchmark", False, "sync after each op for timing (ref: FLAGS_benchmark)")
 define_flag("jit_default_backend", "xla", "compiled-step backend")
-define_flag("flash_attention_backend", "auto", "auto|pallas|xla for scaled_dot_product_attention")
+define_flag("flash_attention_backend", "auto",
+            "attention kernel: auto (the rule on the shape in "
+            "ops/pallas/attention_router) | pallas | xla")
 define_flag("enable_auto_remat", False, "apply jax.checkpoint policy to compiled blocks")
 
 # numerics / precision (ref: FLAGS_use_mkldnn-era precision knobs collapse

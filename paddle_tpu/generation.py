@@ -89,12 +89,8 @@ def _gqa(a, rep):
 
 
 def _prefill_flash_routed(bh, s, d, dtype):
-    """Prefill attention backend: consult the baked per-shape router
-    (same ledger as the train path) — dense XLA wins most v5e prefill
-    shapes, flash wins long ones. Dense (False) only when the backend is
-    not a TPU; on a TPU a router failure propagates."""
-    if jax.default_backend() != "tpu":
-        return False
+    """Whether prefill attention of s tokens runs the flash kernels: the
+    same rule as the train path."""
     from .ops.pallas.attention_router import route
     return route(bh, s, s, d, dtype, True).fwd == "pallas"
 

@@ -185,9 +185,8 @@ def fused_bias_dropout_residual_layer_norm(x, residual, bias=None,
 
 def fused_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
                                 is_causal=False, training=True, **kw):
-    # backend (pallas flash vs dense XLA) is chosen per shape by
-    # ops/pallas/attention_router through the shared sdpa path — one
-    # baked ledger governs nn.functional, incubate, serving, and bench
+    # pallas flash or dense XLA: chosen by the rule in
+    # ops/pallas/attention_router through the shared sdpa path
     return F.scaled_dot_product_attention(q, k, v, attn_mask=attn_mask,
                                           dropout_p=dropout_p,
                                           is_causal=is_causal, training=training)
@@ -196,32 +195,18 @@ def fused_dot_product_attention(q, k, v, attn_mask=None, dropout_p=0.0,
 def fused_attention_rms_epilogue(q, k, v, residual, norm_weight,
                                  epsilon=1e-6, causal=True, name=None):
     """Causal attention with the rmsnorm(attn + residual) * weight
-    epilogue — the widened fused region (FlashFuser, PAPERS.md) the
-    backend router can select where a hardware A/B shows it winning.
+    epilogue, as an XLA composition (differentiable).
 
     q/residual: (batch, seq, heads, head_dim); k/v GQA-native (kv heads
     may divide heads); norm_weight: (head_dim,) — the norm axis is the
     head dim (per-head RMSNorm; pass heads=1 tensors for a full-hidden
-    norm). When the router's ledger marks the fusion a winner at this
-    shape (and a TPU is present), the epilogue runs INSIDE the Pallas
-    flash kernel's flush — the attention output never round-trips HBM
-    unnormalized; otherwise the same math runs as an XLA composition
-    (numerically identical, and differentiable). Inference-oriented:
-    the fused kernel path is forward-only."""
-    from ....ops.pallas.attention_router import epilogue_fusion_wins
+    norm). The same math with the epilogue inside the flash kernel's
+    flush is ops/pallas/flash_attention.flash_attention_rms_epilogue_bshd
+    (forward-only; no measurement has shown it winning, so nothing here
+    selects it)."""
 
     def f(q_, k_, v_, res_, w_):
-        b, s, h, d = q_.shape
-        use_fused = False
-        if jax.default_backend() == "tpu":
-            use_fused = epilogue_fusion_wins(b * h, s, k_.shape[1], d,
-                                             q_.dtype, causal)
-        if use_fused:
-            from ....ops.pallas.flash_attention import (
-                flash_attention_rms_epilogue_bshd)
-            return flash_attention_rms_epilogue_bshd(
-                q_, k_, v_, res_, w_, causal=causal, eps=epsilon)
-        kx, vx = _expand_gqa(k_, v_, h)
+        kx, vx = _expand_gqa(k_, v_, q_.shape[2])
         att = _sdpa_dense(q_, kx, vx, causal)
         hh = (att + res_).astype(jnp.float32)
         ms = jnp.mean(hh * hh, axis=-1, keepdims=True)

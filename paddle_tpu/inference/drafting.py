@@ -16,14 +16,15 @@ traces into the fused decode scan exactly like the built-in one, and
 the committed stream is still byte-identical to non-speculative decode
 — acceptance only changes how many drafts survive verification.
 
-Two harnesses measure this, and they sit in very different regimes:
+Two harnesses measured this, and they sit in very different regimes:
 
-* bench.py decode A/B (repetitive tiled-motif prompts, 193 new
-  tokens): the flat drafter sits at ~0.48-0.49 acceptance; the tuned
-  (3,2)-ladder at depth 2 reaches ~0.58, because the second rung
+* round 11's decode A/B (repetitive tiled-motif prompts, 193 new
+  tokens; its harness is gone): the flat drafter sat at ~0.48-0.49
+  acceptance; the tuned (3,2)-ladder at depth 2 reached ~0.58, because
+  the second rung
   converts fallback drafts (almost never accepted) into short-context
   matches and the shallower depth stops betting tokens past where the
-  match decays. PERF.md records the current numbers.
+  match decays.
 * tools/loadgen.py --speculative (Weyl-sequence prompts, 4-12 token
   replies): absolute acceptance is intrinsically tiny (a chaotic tiny
   model emitting a handful of tokens gives prompt-lookup almost

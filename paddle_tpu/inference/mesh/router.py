@@ -47,7 +47,7 @@ Simulated-parallel clock: replicas are in-process workers stepped
 round-robin, so real wall time is serial. Each pump records every
 replica's step wall; `sim_parallel_wall_s` sums the per-round MAXIMUM —
 the wall clock N separate chips stepping concurrently would see — and
-is labeled as simulated wherever it is reported (bench scaling row).
+is labeled as simulated wherever it is reported.
 """
 
 from __future__ import annotations
@@ -955,7 +955,7 @@ class MeshRouter:
         routing, handoff, failover, and simulated-parallel wall
         accounting. `sim_parallel_wall_s` is the concurrent-worker
         clock (per-round max of the in-process replica step walls) —
-        simulated, and labeled as such wherever bench reports it."""
+        simulated, and labeled as such wherever it is reported."""
         committed_tokens = sum(len(r.generated)
                                for r in self.finished.values())
         sim = self.sim_parallel_wall_s

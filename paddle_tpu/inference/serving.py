@@ -360,8 +360,9 @@ class ContinuousBatchingEngine:
       compat_step_loop: reproduce the pre-fused host-bound loop —
         decode_steps forced to 1, lane state rebuilt from numpy and
         re-uploaded EVERY step, every tile drained synchronously (no
-        dispatch-ahead). The bench A/B baseline, and a fully-synchronous
-        debug mode (nothing in flight between steps).
+        dispatch-ahead). The reference the fused-decode tests compare
+        against, and a fully-synchronous debug mode (nothing in flight
+        between steps).
 
     Round-11 knobs (PERF.md "Speculative decode + quantized KV"):
       speculative_decode: each fused scan step proposes draft_depth
@@ -471,24 +472,13 @@ class ContinuousBatchingEngine:
         # piece pads to the smallest width that fits)
         self._chunk_widths = sorted(
             {b for b in self.buckets if b <= self.chunk} | {self.chunk})
-        # prefill attention backend comes from the same baked per-shape
-        # router/ledger as the train path; keep the largest width's
-        # decision here for audit/metrics
-        try:
-            from ..ops.pallas.attention_router import route
-            self.attention_route = route(
-                self.cfg["heads"], self._chunk_widths[-1],
-                self._chunk_widths[-1], self.cfg["head_dim"],
-                self.embed_w.dtype, True)
-        except (ImportError, OSError, ValueError, KeyError) as e:
-            # audit-only probe: a missing/broken ledger must not stop the
-            # engine, but it is logged + counted, never silently nulled
-            self.attention_route = None
-            warnings.warn(
-                f"serving attention-route probe failed ({e!r}); "
-                "per-bucket routing still happens at prefill trace time",
-                RuntimeWarning, stacklevel=2)
-            _metric("serving_route_probe_failures_total").inc()
+        # the largest width's prefill attention decision, kept for audit;
+        # each width is decided by the same rule at prefill trace time
+        from ..ops.pallas.attention_router import route
+        self.attention_route = route(
+            self.cfg["heads"], self._chunk_widths[-1],
+            self._chunk_widths[-1], self.cfg["head_dim"],
+            self.embed_w.dtype, True)
         self.max_queue = None if max_queue is None else int(max_queue)
         self.max_sheds = int(max_sheds)
         self.lanes: list[Request | None] = [None] * self.max_batch
@@ -513,7 +503,7 @@ class ContinuousBatchingEngine:
         self._inflight: deque[_Inflight] = deque()
         # PIR compile pipeline reports per program (prefill.b<width> /
         # decode[.sampled]): cache hit/miss + pass stats — the engine
-        # warm-start evidence bench.py and tests read
+        # warm-start evidence tests read
         self.compile_reports: dict[str, object] = {}
         # observability handles bound ONCE (catalog names; no-op when the
         # layer is disabled — each call is a single flag check)

@@ -7,12 +7,9 @@ python/paddle/incubate/nn/functional/).
 
 TPU-first design decisions:
 - bf16 parameters by default (MXU native), fp32 RMSNorm accumulation.
-- Attention through nn.functional.scaled_dot_product_attention → the
-  per-shape backend router (ops/pallas/attention_router): Pallas flash
-  vs dense XLA vs hybrid is chosen from the baked hardware ledger, so
-  the train path runs whatever the last hardware session measured
-  fastest at THIS (batch*heads, seq, head_dim) — fwd and bwd routed
-  independently.
+- Attention through nn.functional.scaled_dot_product_attention: the
+  Pallas flash kernels, forward and backward, or dense XLA, by the rule
+  on the shape in ops/pallas/attention_router.
 - GQA (num_key_value_heads < num_attention_heads) via jnp broadcast —
   no repeat_interleave materialization.
 - Shapes arranged (batch, seq, heads, head_dim) so GSPMD shards cleanly:

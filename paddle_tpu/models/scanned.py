@@ -7,8 +7,8 @@ single lax.scan over stacked per-layer parameters — HLO size is O(1) in
 depth, XLA compiles one layer body, and per-layer rematerialization
 (jax.checkpoint on the body) gives the standard activation-memory trade.
 
-Used by bench.py for the >=780M ladder configs; numerics match the
-imperative LlamaForCausalLM (tests/test_models.py::TestScannedLlama).
+Numerics match the imperative LlamaForCausalLM
+(tests/test_models.py::TestScannedLlama).
 """
 
 from __future__ import annotations

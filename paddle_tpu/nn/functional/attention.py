@@ -4,11 +4,11 @@ reference: python/paddle/nn/functional/flash_attention.py:195 flash_attention,
 :976 scaled_dot_product_attention; kernel paddle/phi/kernels/gpu/flash_attn_kernel.cu
 (FlashAttention-2 via dynload).
 
-TPU-native design: default is an XLA attention that computes in fp32 with
-bf16 inputs (XLA already fuses QK^T→softmax→PV well at moderate sequence
-lengths); for long sequences a Pallas flash-attention kernel
-(paddle_tpu/ops/pallas/flash_attention.py) is selected via
-FLAGS_flash_attention_backend=auto when shapes qualify.
+TPU-native design: an XLA attention that computes in fp32 with bf16
+inputs, and the Pallas flash-attention kernels
+(paddle_tpu/ops/pallas/flash_attention.py); under
+FLAGS_flash_attention_backend=auto the rule in
+ops/pallas/attention_router.py picks between them from the shape.
 """
 
 from __future__ import annotations
@@ -62,40 +62,31 @@ def _expand_kv(k, v, num_heads):
     return expand(k), expand(v)
 
 
-def _use_pallas(q_shape, head_dim, has_bias, dtype=None, causal=True):
+def _use_pallas(q_shape, head_dim, has_bias, dtype=None, causal=True,
+                seq_k=None):
     if has_bias:
         # the pallas kernel takes no bias/mask — never select it silently
         return False
-    backend = _flags.flag_value("flash_attention_backend")
-    if backend == "xla":
-        return False
-    if jax.default_backend() != "tpu":
-        return False
-    if backend == "pallas":
-        return True
-    # auto: per-shape routed choice from the baked hardware ledger
-    # (ops/pallas/attention_router) — the r5 A/B showed the flash kernel
-    # losing to dense XLA at most production shapes and winning at
-    # others, so a fixed seq/head_dim threshold is wrong in both
-    # directions. On a TPU an import or router failure propagates: a
-    # broken kernel path must not read as "dense was chosen".
+    # the one rule (ops/pallas/attention_router.route): it reads
+    # FLAGS_flash_attention_backend, the live backend and the shape. On a
+    # TPU an import or kernel failure propagates: a broken kernel path
+    # must not read as "dense was chosen".
     from ...ops.pallas.attention_router import route
     b, seq = q_shape[0], q_shape[1]
     heads = q_shape[2] if len(q_shape) > 3 else 1
-    dec = route(b * heads, seq, seq, head_dim,
-                dtype if dtype is not None else "bfloat16", causal)
-    return dec.fwd == "pallas"
+    return route(b * heads, seq, seq if seq_k is None else seq_k, head_dim,
+                 dtype if dtype is not None else "bfloat16",
+                 causal).fwd == "pallas"
 
 
 def attention_bshd(q, k, v, is_causal=True, scale=None):
-    """Routed attention on raw arrays, (batch, seq, heads, head_dim) with
+    """Attention on raw arrays, (batch, seq, heads, head_dim) with
     GQA-native k/v, for code that is already a pure jax function (a
-    rematerialised sub-block): the per-shape router picks the flash
-    kernels or dense XLA attention exactly as
-    scaled_dot_product_attention does. scale: the softmax scale
-    (default 1/sqrt(head_dim))."""
+    rematerialised sub-block): the flash kernels or dense XLA attention,
+    chosen exactly as scaled_dot_product_attention chooses. scale: the
+    softmax scale (default 1/sqrt(head_dim))."""
     if _use_pallas(tuple(q.shape), q.shape[-1], False, dtype=q.dtype,
-                   causal=is_causal):
+                   causal=is_causal, seq_k=k.shape[1]):
         from ...ops.pallas.flash_attention import flash_attention_bshd
         return flash_attention_bshd(q, k, v, causal=is_causal, scale=scale)
     k, v = _expand_kv(k, v, q.shape[2])
@@ -110,7 +101,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     use_pallas = _use_pallas(tuple(query.shape), query.shape[-1],
                              attn_mask is not None,
                              dtype=getattr(query, "dtype", None),
-                             causal=is_causal) and dropout_key is None
+                             causal=is_causal, seq_k=key.shape[1]
+                             ) and dropout_key is None
 
     if use_pallas:
         from ...ops.pallas.flash_attention import flash_attention_bshd

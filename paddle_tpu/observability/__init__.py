@@ -17,10 +17,9 @@ TPU step time are unaffected when off.
 
 Instrumented hot paths: inference/serving.py (TTFT, TPOT, queue depth,
 occupancy, pool gauge, admission counters), generation.generate,
-ops/pallas/attention_router (decision-source counters), bench.py (rows
-embed registry snapshots), distributed elastic recovery (restart/resume
-counters). The canonical metric-name catalog lives in catalog.py and is
-documented in OBSERVABILITY.md (drift is test-pinned).
+distributed elastic recovery (restart/resume counters). The canonical
+metric-name catalog lives in catalog.py and is documented in
+OBSERVABILITY.md (drift is test-pinned).
 """
 
 from __future__ import annotations

@@ -99,9 +99,6 @@ CATALOG = {
     "serving_backpressure_total": (
         "counter", "add_request refusals at max_queue (BackpressureError)",
         (), None),
-    "serving_route_probe_failures_total": (
-        "counter", "audit attention-route probes that failed at engine "
-        "construction (logged, engine continues)", (), None),
     "serving_pool_exhausted_total": (
         "counter", "paged-KV-pool reservations refused "
         "(KVPoolExhaustedError raised; caller defers or sheds)", (), None),
@@ -247,15 +244,11 @@ CATALOG = {
         "counter", "generate() calls by execution path",
         ("path",), None),
 
-    # -- attention router (ops/pallas/attention_router.py) ------------------
-    "attention_router_decisions_total": (
-        "counter", "fresh (non-cached) routing decisions by source",
-        ("source",), None),
+    # -- attention kernels (ops/pallas/autotune.py) -------------------------
     "attention_backend_failures_total": (
-        "counter", "attention backends the TPU compiler or device refused "
-        "while being measured (router arms measure_<kind>_<backend>, "
-        "block autotune candidates); each is also logged with the "
-        "compiler's message", ("site",), None),
+        "counter", "flash-kernel tile candidates the TPU compiler or device "
+        "refused while the autotuner timed them (site autotune); each is "
+        "also logged with the compiler's message", ("site",), None),
 
     # -- training telemetry (observability.stepwatch.StepWatch) -------------
     "train_step_seconds": (

@@ -455,8 +455,7 @@ def load_snapshot(doc) -> MetricRegistry:
 
 
 def write_snapshot_jsonl(path, registry: MetricRegistry, meta=None):
-    """One header line + one line per metric family (append-friendly,
-    same spirit as the bench ledger .bench_tpu_wins.jsonl)."""
+    """One header line + one line per metric family."""
     doc = snapshot(registry, meta)
     with open(path, "w") as f:
         f.write(json.dumps({"format": doc["format"],

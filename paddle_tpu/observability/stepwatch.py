@@ -4,9 +4,7 @@ reference capability: python/paddle/profiler/timer.py Benchmark (ips /
 step cost) grown into the always-on telemetry the ROADMAP's production
 system needs: per-step wall time (with optional phase breakdown), online
 tokens/s + MFU, loss / grad-norm gauges, and a JSONL step log whose rows
-carry the same round/provenance fields as the bench ledger
-(.bench_tpu_wins.jsonl), so training evidence and bench evidence are one
-schema.
+carry round/provenance fields.
 
 Zero-cost when disabled: step() checks the registry's enable flag first
 and returns — the 50-step smoke-loop overhead guard in
@@ -134,9 +132,9 @@ class StepWatch:
 
     def record_run(self, steps, seconds, tokens=None, loss=None,
                    grad_norm=None):
-        """Aggregate entry for an externally timed region (bench.py times
-        its loop without per-step syncs; feeding those per-step would
-        record dispatch time, not step time)."""
+        """Aggregate entry for an externally timed region (a loop timed
+        without per-step syncs; feeding those per-step would record
+        dispatch time, not step time)."""
         if not self._registry.enabled or steps <= 0:
             return None
         dt = seconds / steps

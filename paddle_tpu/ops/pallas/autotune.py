@@ -38,6 +38,17 @@ _flags.define_flag(
 timing_log: dict = {}
 
 
+def _backend_failed(site: str, err: Exception):
+    """A candidate failed to compile or run on the TPU: never silent.
+    Logged with the compiler's message and counted by call site."""
+    import warnings
+    warnings.warn(
+        f"attention backend failure at {site}: {type(err).__name__}: "
+        f"{str(err)[:2000]}", RuntimeWarning, stacklevel=3)
+    from ...observability.catalog import metric as _obs_metric
+    _obs_metric("attention_backend_failures_total", site=site).inc()
+
+
 class AlgorithmCache:
     """Winner cache + hit/miss stats (reference: autotune/cache.h)."""
 
@@ -129,7 +140,6 @@ def autotune(key, candidates: Sequence[Any], make_runner, default=None,
         try:
             t = _time_once(runner, repeats)
         except Exception as e:  # noqa: BLE001 — any compiler/runtime class
-            from .attention_router import _backend_failed
             _backend_failed("autotune", e)
             refused += 1
             continue

@@ -50,7 +50,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...framework import flags as _flags
 
 NEG_INF = -1e30
 _LANES = 128  # scratch holds per-row scalars broadcast across one lane tile
@@ -838,60 +837,8 @@ def _fa_fwd(q, k, v, causal, scale, q_per_kv=1):
     return out, (q, k, v, out, lse)
 
 
-def _dense_remat_bwd(q, k, v, causal, scale, q_per_kv, g):
-    """Backward via XLA-dense rematerialization (GQA-grouped): the hybrid
-    (flash forward, dense backward), selectable with
-    FLAGS_flash_attention_bwd=xla and by a ledger row that measured it
-    winning. On a TPU v5e no measured row does any more: with the
-    two-level-tile kernels the Pallas backward wins every isolated row
-    (forward+backward 1.47-2.05 ms against 5.1-7.3 ms) and both end-to-end
-    A/Bs — the 535m step in round 5 (0.426 against 0.406 MFU: the
-    transient (bh, sq, sk) fp32 buffer's HBM pressure costs the scheduled
-    step more than any kernel gap), and llama_780m, head dim 96 padded to
-    128, in PR 27 (0.5775 against 0.4137 MFU), the shape where round 5's
-    f32-operand kernels had lost to it both ways."""
-    def f(q_, k_, v_):
-        if q_per_kv == 1:
-            return _xla_attention_bhsd(q_, k_, v_, causal, scale)
-        bh, sq, d = q_.shape
-        bkv = k_.shape[0]
-        qg = q_.reshape(bkv, q_per_kv, sq, d)
-        s = jnp.einsum("bgqd,bkd->bgqk", qg, k_,
-                       preferred_element_type=jnp.float32) * scale
-        if causal:
-            sk = k_.shape[1]
-            mask = jnp.tril(jnp.ones((sq, sk), jnp.bool_), k=sk - sq)
-            s = jnp.where(mask, s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1).astype(v_.dtype)
-        o = jnp.einsum("bgqk,bkd->bgqd", p, v_)
-        return o.reshape(bh, sq, d)
-
-    _, pull = jax.vjp(f, q, k, v)
-    return pull(g)
-
-
-_flags.define_flag(
-    "flash_attention_bwd", "auto",
-    "flash-attention backward: 'pallas' (FA-2 dQ/dKV kernels), 'xla' "
-    "(dense rematerialization, XLA-differentiated), or 'auto' (routed "
-    "per shape by ops/pallas/attention_router from the baked hardware "
-    "ledger, whose end-to-end rows outrank its isolated ones: on a v5e "
-    "the Pallas backward won both end-to-end A/Bs, 0.426 vs 0.406 MFU on "
-    "the 535m train step in round 5 and 0.5775 vs 0.4137 on llama_780m, "
-    "head dim 96, in PR 27, and every isolated row of round 27)")
-
-
 def _fa_bwd(causal, scale, q_per_kv, res, g):
     q, k, v, o, lse = res
-    mode = _flags.flag_value("flash_attention_bwd")
-    if mode == "auto":
-        # per-shape routed choice with provenance (ledger -> measurement
-        # -> heuristic); a router failure propagates
-        from .attention_router import route
-        mode = route(q.shape[0], q.shape[1], k.shape[1], q.shape[2],
-                     q.dtype, causal).bwd
-    if mode == "xla":
-        return _dense_remat_bwd(q, k, v, causal, scale, q_per_kv, g)
     return _flash_bwd_bhsd(q, k, v, o, lse, g, causal, scale,
                            tiles=_tiles("bwd", q, k, causal),
                            q_per_kv=q_per_kv)

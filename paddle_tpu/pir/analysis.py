@@ -322,18 +322,16 @@ def check_donation_safety(prog: Program, donate_argnums) -> list:
 # static cost model (FLOPs / bytes / roofline seconds)
 # --------------------------------------------------------------------------
 
-# PR 1 hardware ledger numbers (ops/pallas/attention_router.py _PROXY /
-# attention_ledger.json, TPU v5 lite): peak dense throughput, the
-# measured dense-matmul efficiency fraction, and HBM bandwidth. Kept as
-# a literal so the analysis stays importable without the router.
+# TPU v5 lite: peak dense throughput, the dense-matmul efficiency
+# fraction round 5 measured, and HBM bandwidth.
 DEFAULT_ROOFLINE = {
     "peak_flops": 197e12,
     "efficiency": 0.068,
     "hbm_bps": 820e9,
 }
 
-# Interconnect row of the same baked ledger (TPU v5 lite ICI): effective
-# per-direction link bandwidth and per-collective launch latency. Feeds
+# Interconnect (TPU v5 lite ICI): effective per-direction link bandwidth
+# and per-collective launch latency. Feeds
 # the CostModel's exposed-communication term — comm seconds for a
 # collective-bearing op are wire_bytes / ici_bps + latency, and compute
 # scheduled between the collective and its first consumer earns overlap

@@ -40,7 +40,7 @@ _MAGIC = b"PIRC"
 _SUFFIX = ".pirc"
 
 # process-local counters, independent of the observability layer so
-# bench.py can report hit/miss even with metrics disabled
+# hit/miss can be reported even with metrics disabled
 _STATS = {"hit": 0, "miss": 0, "write": 0, "corrupt": 0, "evict": 0,
           "read_error": 0, "write_error": 0}
 _STATS_LOCK = threading.Lock()
@@ -88,8 +88,8 @@ def cache_key(canonical_hash: str, *, sharding: str = "replicated",
     from .passes import PIPELINE_VERSION
 
     def flag(k):
-        # some flags register lazily on their module's import (e.g.
-        # attention_router); unregistered reads key as None
+        # some flags register lazily on their module's import;
+        # unregistered reads key as None
         try:
             return _flags.flag_value(k)
         except KeyError:
@@ -103,7 +103,7 @@ def cache_key(canonical_hash: str, *, sharding: str = "replicated",
         "pipeline": PIPELINE_VERSION,
         "flags": {k: flag(k) for k in (
             "matmul_precision", "use_bfloat16_matmul",
-            "flash_attention_backend", "attention_router", "pir_passes")},
+            "flash_attention_backend", "pir_passes")},
     }
     if extra:
         env["extra"] = {k: str(v) for k, v in sorted(extra.items())}

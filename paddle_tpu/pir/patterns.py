@@ -12,10 +12,10 @@ Production patterns:
 
 * ``sdpa_route`` — the scaled-dot-product-attention subgraph
   (QK dot_general -> scale -> causal mask -> softmax -> PV dot_general)
-  becomes one ``pt.sdpa`` op that dispatches through the per-shape
-  attention backend router (ops/pallas/attention_router): Pallas flash
-  on TPU where the baked ledger says it wins, otherwise an exact replay
-  of the captured region (identical numerics by construction).
+  becomes one ``pt.sdpa`` op that dispatches by the attention rule
+  (ops/pallas/attention_router.route): Pallas flash on a TPU where the
+  rule says so, otherwise an exact replay of the captured region
+  (identical numerics by construction).
 * ``rms_epilogue`` — ``rmsnorm(pt.sdpa + residual) * gamma`` becomes
   ``pt.sdpa_rms_epilogue``, dispatching to
   ``flash_attention_rms_epilogue_bshd`` (the attention output never
@@ -123,11 +123,8 @@ def _region_closed(users, region_ops, outs_allowed):
 
 
 def _route_decision(bh, sq, sk, d, dtype, causal):
-    try:
-        from ..ops.pallas.attention_router import route
-        return route(int(bh), int(sq), int(sk), int(d), dtype, bool(causal))
-    except Exception:  # noqa: BLE001 — no ledger/router: replay-only op
-        return None
+    from ..ops.pallas.attention_router import route
+    return route(int(bh), int(sq), int(sk), int(d), dtype, bool(causal))
 
 
 def _on_tpu():
@@ -288,7 +285,7 @@ class SdpaRoutePattern(RewritePattern):
         causal, scale = m["causal"], m["scale"]
         dec = _route_decision(b * h, sq, sk, d, q.dtype, causal)
         replay = region_replay(prog, m["region"], [q, k, v], out)
-        route_fwd = dec.fwd if dec is not None else "replay"
+        route_fwd = dec.fwd
 
         def fn(q_, k_, v_):
             if route_fwd == "pallas" and _on_tpu():
@@ -300,7 +297,7 @@ class SdpaRoutePattern(RewritePattern):
         new_op = Operation(
             "pt.sdpa", [q, k, v], [out],
             attrs={"causal": causal, "scale": scale, "route_fwd": route_fwd,
-                   "route_source": getattr(dec, "source", "none"),
+                   "route_why": dec.why,
                    "shape": (b, sq, sk, h, d)},
             fn=fn, scope=out.op.scope)
         prog.replace_region(m["region"], new_op)
@@ -419,7 +416,7 @@ class RmsEpiloguePattern(RewritePattern):
         q, k, v, residual, w = (m["q"], m["k"], m["v"], m["residual"],
                                 m["w"])
         dec = _route_decision(b * h, sq, sk, d, q.dtype, causal)
-        route_fwd = dec.fwd if dec is not None else "replay"
+        route_fwd = dec.fwd
         replay = region_replay(prog, m["region"],
                                [q, k, v, residual, w], m["out"])
         out_dtype = m["out"].dtype
@@ -438,7 +435,7 @@ class RmsEpiloguePattern(RewritePattern):
             "pt.sdpa_rms_epilogue", [q, k, v, residual, w], [m["out"]],
             attrs={"causal": causal, "scale": scale, "eps": eps,
                    "route_fwd": route_fwd,
-                   "route_source": getattr(dec, "source", "none"),
+                   "route_why": dec.why,
                    "shape": (b, sq, sk, h, d)},
             fn=fn, scope=m["out"].op.scope)
         prog.replace_region(m["region"], new_op)

@@ -1,15 +1,8 @@
-"""Per-shape attention backend router: ledger dispatch, provenance,
-measurement fallback, and numeric parity across all three backends.
-
-reference capability: paddle/phi/kernels/autotune/ (per-signature
-algorithm selection) generalized to backend selection; the baked ledger
-is generated by tools/bake_flash_blocks.py --ledger from real hardware
-artifacts (.flash_vs_xla.json + .bench_tpu_wins.jsonl).
+"""The rule that picks the attention kernel (ops/pallas/attention_router):
+what it answers at every shape a measurement exists for, each side of its
+constant, its gates, that every caller gets the one answer, and numeric
+parity of the flash kernels with dense attention.
 """
-
-import json
-import os
-import sys
 
 import numpy as np
 import pytest
@@ -17,199 +10,107 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.framework import flags as _flags
 from paddle_tpu.ops.pallas import attention_router as ar
 from paddle_tpu.ops.pallas import flash_attention as fa
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-V5E = "TPU v5 lite"
 
 
 @pytest.fixture(autouse=True)
 def _fresh_router():
     ar.clear_routing_cache()
     yield
-    from paddle_tpu.framework import flags as _flags
-    _flags.set_flags({"FLAGS_attention_ledger_path": "",
-                      "FLAGS_attention_router": "auto"})
+    _flags.set_flags({"FLAGS_flash_attention_backend": "auto"})
     ar.clear_routing_cache()
 
 
-# The hardware A/B shapes (rows of .flash_vs_xla.json) with the winners
-# the v5e measured (PR 27, the two-level-tile kernels: flash wins every
-# row, forward 2.1-10x and forward+backward 2.6-6x over dense; round 5's
-# f32-operand kernels had lost three of the four) — the router MUST
-# reproduce these from the shipped ledger (acceptance criterion: routed
-# choice == measured winner, asserted from the recorded table).
-R5_SHAPES = [
-    # (bh, seq, head_dim, fwd_winner, bwd_winner)
-    (128, 1024, 128, "pallas", "pallas"),
-    (32, 2048, 128, "pallas", "pallas"),
-    (8, 4096, 128, "pallas", "pallas"),
-    (32, 2048, 96, "pallas", "pallas"),
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The callers ask jax for the backend: say it is a TPU."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+# Every shape a v5e measurement exists for, with the measurement: the
+# kernels won each, forward and backward. grid: fa_fwd / fa_bwd_dq /
+# fa_bwd_dkv steps a call where the benchmark's records hold them.
+MEASURED = [
+    # id, bh, seq, head_dim, grid steps, provenance
+    ("isolated_s1024", 128, 1024, 128, None,
+     "PR 27 and PR 30 call 48: fwd 0.747 vs 1.592 ms, fwd+bwd 2.050 vs "
+     "5.271"),
+    ("isolated_s2048", 32, 2048, 128, None,
+     "fwd 0.506 vs 1.585 ms, fwd+bwd 1.474 vs 5.065"),
+    ("isolated_s4096", 8, 4096, 128, None,
+     "fwd 0.395 vs 3.968 ms, fwd+bwd 1.226 vs 7.322"),
+    ("isolated_d96", 32, 2048, 96, None,
+     "zero-padded to 128: fwd 0.553 vs 1.699 ms, fwd+bwd 1.602 vs 5.085"),
+    ("e2e_llama_535m", 64, 2048, 128, None,
+     "round 5 train step: Pallas backward 0.4261 MFU, hybrid 0.4063"),
+    ("e2e_llama_780m", 128, 2048, 96, None,
+     "PR 27 train step: Pallas backward 0.5775 MFU, hybrid 0.4137"),
+    ("cell_gpt3_xl_d12", 64, 2048, 128, (128, 256, 256),
+     "ledger, PR 27-29: 19,823 -> 27,320 tokens/s with these kernels"),
+    ("cell_granite_gqa_32_8", 32, 8192, 128, (256, 512, 512),
+     "ledger, PR 28-29; dense would hold 8 GiB of float32 scores"),
+    ("four_chip_shard", 16, 2048, 128, None,
+     "PR 27, by hand: 27,355 -> 33,463 tokens/s on 2x2"),
 ]
 
 
-class TestShippedLedger:
-    def test_ledger_loads_and_is_versioned(self):
-        led = ar.load_ledger()
-        assert led is not None, "shipped attention_ledger.json must parse"
-        assert led["ledger_format"] == ar.LEDGER_FORMAT
-        assert led["device_kind"] == V5E
-        assert led["round"] == 27
-        assert len(led["entries"]) == 4
-        # the ledger regenerates byte-identically from the artifacts
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        try:
-            import bake_flash_blocks as bake
-        finally:
-            sys.path.pop(0)
-        regen = bake.bake_ledger(
-            os.path.join(REPO, ".flash_vs_xla.json"), round_num=27,
-            wins_path=os.path.join(REPO, ".bench_tpu_wins.jsonl"))
-        shipped = json.load(open(os.path.join(
-            REPO, "paddle_tpu", "ops", "pallas", "attention_ledger.json")))
-        assert regen == shipped
-
-    @pytest.mark.parametrize("bh,seq,d,fwd,bwd", R5_SHAPES)
-    def test_routed_choice_matches_measured_winner(self, bh, seq, d, fwd,
-                                                   bwd):
-        dec = ar.route(bh, seq, seq, d, "bfloat16", True,
-                       platform="tpu", device_kind=V5E)
-        assert dec.fwd == fwd and dec.bwd == bwd
-        assert dec.source == "ledger"
-        # provenance carries the raw measurements for audit
-        assert "measured on TPU v5 lite" in dec.provenance
-        led = ar.load_ledger()
-        row = next(e for e in led["entries"]
-                   if e["seq"] == seq and e["head_dim"] == d)
-        assert str(row["fwd_ms"][fwd]) in dec.provenance
-
-    def test_end_to_end_entry_outranks_isolated(self):
-        """The 535m train shape (bh=64 s2048 d128, the benchmark's training
-        cell too): round 5's isolated timing said dense fwd + hybrid bwd
-        while the end-to-end hardware A/B measured full-pallas winning
-        (0.4261 vs 0.4063 MFU) — the e2e entry takes priority at its exact
-        shape."""
-        dec = ar.route(64, 2048, 2048, 128, "bfloat16", True,
-                       platform="tpu", device_kind=V5E)
-        assert dec.fwd == "pallas" and dec.bwd == "pallas"
-        assert dec.source == "ledger-e2e"
-        assert "llama_535m" in dec.provenance
-        # the 780m shape (d 96 padded to 128): round 5's end-to-end row
-        # said hybrid backward on kernels that no longer exist; PR 27's A/B
-        # with today's measured the Pallas backward at 0.5775 MFU against
-        # the hybrid's 0.4137, and the row carries both readings
-        dec = ar.route(128, 2048, 2048, 96, "bfloat16", True,
-                       platform="tpu", device_kind=V5E)
-        assert (dec.fwd, dec.bwd) == ("pallas", "pallas")
-        assert dec.source == "ledger-e2e"
-        assert "llama_780m" in dec.provenance
-        row = next(e for e in ar.load_ledger()["end_to_end"]
-                   if e["config"] == "llama_780m")
-        assert row["mfu"]["pallas"] > row["mfu"]["xla"] > 0
-
-    def test_ledger_ignored_on_other_device(self):
-        dec = ar.route(32, 2048, 2048, 128, "bfloat16", True,
-                       platform="cpu", device_kind="TPU v6e")
-        assert dec.source != "ledger"
-
-    def test_ledger_rows_carry_no_tiles(self):
-        """Tiles come from flash_attention.choose_tiles alone: the rows
-        rank backends, and a tile size measured on one generation of
-        kernels must not override the chooser of the next."""
-        led = ar.load_ledger()
-        for row in led["entries"] + led["end_to_end"]:
-            assert not {"blocks_fwd", "blocks_bwd"} & set(row), row
-        assert "packed_grid_validated" not in led
+class TestMeasuredShapes:
+    @pytest.mark.parametrize("case", MEASURED, ids=[c[0] for c in MEASURED])
+    def test_flash_forward_and_backward(self, case):
+        _, bh, seq, d, grid, why = case
+        dec = ar.route(bh, seq, seq, d, "bfloat16", True, platform="tpu")
+        assert (dec.fwd, dec.bwd) == ("pallas", "pallas"), why
+        assert dec.tiles == fa.tiles_for_shape(bh, seq, seq, d, "bfloat16",
+                                               True)
+        if grid is not None:
+            assert tuple(dec.grid_steps[k] for k in (
+                "fa_fwd", "fa_bwd_dq", "fa_bwd_dkv")) == grid
 
 
-class TestLedgerRoundTrip:
-    """bake -> write -> load -> dispatch, through a tmp ledger selected
-    via FLAGS_attention_ledger_path (the satellite round-trip case)."""
-
-    def test_bake_load_dispatch(self, tmp_path):
-        from paddle_tpu.framework import flags as _flags
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        try:
-            import bake_flash_blocks as bake
-        finally:
-            sys.path.pop(0)
-        src = {
-            "device_kind": "TestChip", "dtype": "float32", "causal": True,
-            "rows": [{"seq": 256, "batch": 2, "heads": 2, "head_dim": 64,
-                      "flash_fwd_ms": 1.0, "dense_fwd_ms": 2.0,
-                      "fwdbwd_ms_pallas": 3.0, "fwdbwd_ms_hybrid": 2.5,
-                      "max_abs_err": 0.001}],
-            "autotuned_blocks": {"fwd_s256_d64": [128, 128]},
-        }
-        p = tmp_path / "ab.json"
-        p.write_text(json.dumps(src))
-        led = bake.bake_ledger(str(p), round_num=99)
-        out = tmp_path / "ledger.json"
-        out.write_text(json.dumps(led))
-
-        _flags.set_flags({"FLAGS_attention_ledger_path": str(out)})
-        ar.clear_routing_cache()
-        dec = ar.route(4, 256, 256, 64, "float32", True,
-                       platform="tpu", device_kind="TestChip")
-        assert (dec.fwd, dec.bwd) == ("pallas", "xla")  # 1.0<2.0; 2.5<3.0
-        assert dec.source == "ledger"
-        assert "r99" in dec.provenance
-
-    def test_wrong_format_version_fails_open(self, tmp_path):
-        from paddle_tpu.framework import flags as _flags
-        bad = tmp_path / "bad.json"
-        bad.write_text(json.dumps({"ledger_format": 999, "entries": []}))
-        _flags.set_flags({"FLAGS_attention_ledger_path": str(bad)})
-        ar.clear_routing_cache()
-        assert ar.load_ledger() is None
-        dec = ar.route(32, 2048, 2048, 128, "bfloat16", True,
-                       platform="cpu", device_kind="cpu")
-        assert dec.source in ("proxy", "heuristic")
+# Each side of the rule's one constant, and what the sweep said of the
+# rest of the shape (PR 30, chip call 48; PERF.md section 6)
+EDGES = [
+    # id, seq_q, seq_k, head_dim, dtype, causal, backend
+    ("at_the_constant", 512, 512, 128, "bfloat16", True, "pallas"),
+    ("one_under_it", 511, 511, 128, "bfloat16", True, "xla"),
+    ("dense_3x_faster_at_256", 256, 256, 128, "bfloat16", True, "xla"),
+    ("shortest_serving_bucket", 64, 64, 128, "bfloat16", True, "xla"),
+    ("head_dim_64_wins_at_512", 512, 512, 64, "bfloat16", True, "pallas"),
+    ("head_dim_64_loses_at_256", 256, 256, 64, "bfloat16", True, "xla"),
+    ("head_dim_64_at_8k_is_not_dense", 8192, 8192, 64, "bfloat16", True,
+     "pallas"),
+    ("non_causal_wins_at_512", 512, 512, 128, "bfloat16", False, "pallas"),
+    ("non_causal_loses_at_256", 256, 256, 64, "bfloat16", False, "xla"),
+    ("float32_wins_at_512", 512, 512, 128, "float32", True, "pallas"),
+    ("few_queries_many_keys", 128, 2048, 128, "bfloat16", True, "xla"),
+    ("decode_row", 1, 4096, 128, "bfloat16", True, "xla"),
+    ("chunk_against_a_long_cache", 1024, 4096, 128, "bfloat16", True,
+     "pallas"),
+]
 
 
-class TestMeasurementFallback:
-    """Forced ledger miss exercises the deterministic CPU fallback."""
+class TestTheConstant:
+    @pytest.mark.parametrize("case", EDGES, ids=[c[0] for c in EDGES])
+    def test_each_side(self, case):
+        _, sq, sk, d, dtype, causal, backend = case
+        dec = ar.route(32, sq, sk, d, dtype, causal, platform="tpu")
+        assert (dec.fwd, dec.bwd) == (backend, backend)
+        assert str(ar._FLASH_MIN_SEQ_Q) in dec.why
 
-    def test_miss_routes_through_proxy_deterministically(self):
-        key = (4, 640, 640, 64, "float32", True)
-        d1 = ar.route(*key, platform="cpu", device_kind="cpu")
-        ar.clear_routing_cache()
-        d2 = ar.route(*key, platform="cpu", device_kind="cpu")
-        assert d1 == d2                      # no clocks, no randomness
-        assert d1.source == "proxy"
-        assert "NOT a measurement" in d1.provenance
-        # the proxy prefers the flash fwd for causal self-attn (the sweep
-        # visits half the pairs, no O(S^2) traffic) — the bf16-kernel
-        # hypothesis
-        assert d1.fwd == "pallas"
-
-    def test_proxy_math_matches_decision(self):
-        bh, s, d = 4, 640, 64
-        est = {b: ar._proxy_ms("fwd", bh, s, s, d, "float32", True, b)
-               for b in ("pallas", "xla")}
-        dec = ar.route(bh, s, s, d, "float32", True, platform="cpu",
-                       device_kind="cpu")
-        assert dec.fwd == min(est, key=est.get)
-
-    def test_ledger_mode_never_measures(self):
-        from paddle_tpu.framework import flags as _flags
-        _flags.set_flags({"FLAGS_attention_router": "ledger"})
-        ar.clear_routing_cache()
-        dec = ar.route(4, 640, 640, 64, "float32", True, platform="cpu",
-                       device_kind="cpu")
-        assert dec.source == "heuristic"
-
-    def test_decision_log_records(self):
-        ar.route(4, 640, 640, 64, "float32", True, platform="cpu",
-                 device_kind="cpu")
-        log = ar.decision_log()
-        assert log and log[-1][0][:2] == (4, 640)
+    def test_decision_log_lists_each_shape_once(self):
+        for _ in range(2):
+            ar.route(4, 640, 640, 64, "float32", True, platform="tpu")
+        ar.route(4, 640, 320, 64, "float32", True, platform="tpu")
+        keys = [key for key, _ in ar.decision_log()]
+        assert keys == [(4, 640, 640, 64, "float32", True),
+                        (4, 640, 320, 64, "float32", True)]
 
 
 class TestBackendParity:
-    """Numeric parity across the three dispatchable backends on the r5
-    production shape set (scaled bh for interpreter speed; the (seq, d)
+    """Gradients through the flash kernels against dense attention on the
+    measured shape set (scaled bh for interpreter speed; the (seq, d)
     geometry is the production one for the not-slow subset's s1024)."""
 
     def _parity(self, bh, sq, d, dtype, tol):
@@ -227,27 +128,15 @@ class TestBackendParity:
             return jnp.sum(fa._xla_attention_bhsd(q_, k_, v_, True,
                                                   scale) ** 2)
 
-        from paddle_tpu.framework import flags as _flags
-        outs = {}
-        for mode in ("pallas", "xla"):      # bwd backends behind flash fwd
-            old = _flags.flag_value("flash_attention_bwd")
-            _flags.set_flags({"FLAGS_flash_attention_bwd": mode})
-            try:
-                outs[f"hybrid_{mode}"] = jax.grad(
-                    loss_flash, argnums=(0, 1, 2))(q, k, v)
-            finally:
-                _flags.set_flags({"FLAGS_flash_attention_bwd": old})
-        outs["dense"] = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
-        base = outs.pop("dense")
-        for name, g in outs.items():
-            for a, b, nm in zip(g, base, "qkv"):
-                np.testing.assert_allclose(
-                    np.asarray(a, np.float32), np.asarray(b, np.float32),
-                    rtol=tol, atol=tol, err_msg=f"{name} d{nm}")
+        got = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+        base = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+        for a, b, nm in zip(got, base, "qkv"):
+            np.testing.assert_allclose(
+                np.asarray(a, np.float32), np.asarray(b, np.float32),
+                rtol=tol, atol=tol, err_msg=f"d{nm}")
 
     def test_parity_production_geometry_s1024(self):
-        # full production (seq, d) at reduced bh — the three backends
-        # (full-pallas, hybrid, dense) agree fwd+bwd
+        # full production (seq, d) at reduced bh
         self._parity(1, 1024, 128, jnp.float32, 2e-3)
 
     @pytest.mark.slow
@@ -262,24 +151,17 @@ class TestDecisionCarriesTiles:
     and the grid steps one call of each makes (decision_log() surfaces
     them): the count that says the two-level tiles engaged."""
 
-    @pytest.mark.parametrize("mode,kw", [
-        ("auto", dict(platform="tpu", device_kind=V5E)),     # ledger-e2e
-        ("auto", dict(platform="cpu", device_kind="cpu")),   # proxy
-        ("heuristic", dict(platform="cpu", device_kind="cpu")),
-    ])
-    def test_every_source_carries_them(self, mode, kw):
-        from paddle_tpu.framework import flags as _flags
-        _flags.set_flags({"FLAGS_attention_router": mode})
-        ar.clear_routing_cache()
-        dec = ar.route(64, 2048, 2048, 128, "bfloat16", True, **kw)
+    def test_a_dense_decision_carries_them_too(self):
+        dec = ar.route(64, 2048, 2048, 128, "bfloat16", True,
+                       platform="cpu")
+        assert dec.fwd == "xla"
         assert dec.tiles == fa.choose_tiles(2048, 2048, 128, 2)
         assert dec.grid_steps == dec.tiles.grid_steps(64, 2048, 2048)
         assert max(dec.grid_steps.values()) <= 1024
 
     def test_padded_head_dim_and_cross_length(self):
         # head_dim 96 rides zero-padded to the lane width in the kernels
-        dec = ar.route(8, 300, 1000, 96, "float32", True, platform="cpu",
-                       device_kind="cpu")
+        dec = ar.route(8, 300, 1000, 96, "float32", True, platform="cpu")
         assert dec.tiles == fa.choose_tiles(300, 1000, 128, 4)
         res_q, streamed_k, _ = dec.tiles.fwd
         assert dec.grid_steps["fa_fwd"] == (
@@ -326,34 +208,134 @@ class TestFusedEpilogue:
         np.testing.assert_allclose(np.asarray(out._data), np.asarray(fused),
                                    rtol=2e-4, atol=2e-4)
 
-    def test_epilogue_fusion_ledger_gate(self):
-        # shipped ledger has no fused_epilogue_wins rows -> never selected
-        assert ar.epilogue_fusion_wins(32, 2048, 2048, 128, "bfloat16",
-                                       True, device_kind=V5E) is False
 
 
-class TestSdpaRouting:
-    """nn.functional sdpa consults the router for its auto decision."""
 
-    def test_use_pallas_follows_router(self, monkeypatch):
+def _spy_on_flash(monkeypatch):
+    """Count calls of the kernels' entry point, and answer with zeros:
+    the interpreter would take minutes at these sizes."""
+    calls = []
+
+    def spy(q, k, v, causal=False, scale=None):
+        calls.append(q.shape)
+        return jnp.zeros(q.shape, q.dtype)
+    monkeypatch.setattr(fa, "flash_attention_bshd", spy)
+    return calls
+
+
+class TestGates:
+    """What stands before the rule in nn.functional attention."""
+
+    SHAPE = (1, 1024, 2, 128)     # the rule says flash on a TPU
+
+    def _sdpa(self, **kw):
+        import paddle_tpu as paddle
+        from paddle_tpu.nn import functional as F
+        q = paddle.Tensor(jnp.zeros(self.SHAPE, jnp.bfloat16))
+        return F.scaled_dot_product_attention(q, q, q, is_causal=True, **kw)
+
+    def test_dropout_runs_dense(self, on_tpu, monkeypatch):
+        calls = _spy_on_flash(monkeypatch)
+        self._sdpa(dropout_p=0.1, training=True)
+        assert calls == []
+        self._sdpa(dropout_p=0.1, training=False)   # no dropout at eval
+        assert calls == [self.SHAPE]
+
+    def test_no_tpu_runs_dense(self):
         from paddle_tpu.nn.functional import attention as attn
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        calls = {}
+        assert attn._use_pallas(self.SHAPE, 128, False, "bfloat16") is False
+        _flags.set_flags({"FLAGS_flash_attention_backend": "pallas"})
+        assert attn._use_pallas(self.SHAPE, 128, False, "bfloat16") is False
 
-        def fake_route(bh, sq, sk, d, dtype, causal, **kw):
-            calls["key"] = (bh, sq, sk, d, str(dtype), causal)
-            return ar.Decision(fwd="pallas", bwd="pallas")
-        monkeypatch.setattr(ar, "route", fake_route)
-        assert attn._use_pallas((2, 512, 4, 64), 64, False,
-                                dtype="bfloat16", causal=True) is True
-        assert calls["key"] == (8, 512, 512, 64, "bfloat16", True)
+    def test_flag_xla_runs_dense(self, on_tpu):
+        from paddle_tpu.nn.functional import attention as attn
+        assert attn._use_pallas(self.SHAPE, 128, False, "bfloat16") is True
+        _flags.set_flags({"FLAGS_flash_attention_backend": "xla"})
+        assert attn._use_pallas(self.SHAPE, 128, False, "bfloat16") is False
 
-        monkeypatch.setattr(
-            ar, "route",
-            lambda *a, **kw: ar.Decision(fwd="xla", bwd="xla"))
-        assert attn._use_pallas((2, 512, 4, 64), 64, False,
-                                dtype="bfloat16", causal=True) is False
+    def test_flag_pallas_runs_the_kernels_under_the_constant(self, on_tpu):
+        from paddle_tpu.nn.functional import attention as attn
+        short = (1, 64, 2, 128)
+        assert attn._use_pallas(short, 128, False, "bfloat16") is False
+        _flags.set_flags({"FLAGS_flash_attention_backend": "pallas"})
+        assert attn._use_pallas(short, 128, False, "bfloat16") is True
 
-    def test_bias_still_forces_dense(self):
+    def test_sdp_kernel_sets_and_restores_the_flag(self, on_tpu):
+        from paddle_tpu.nn.functional import attention as attn
+        with attn.sdp_kernel(enable_flash=False):
+            assert _flags.flag_value("flash_attention_backend") == "xla"
+            assert not attn._use_pallas(self.SHAPE, 128, False, "bfloat16")
+        assert _flags.flag_value("flash_attention_backend") == "auto"
+        with attn.sdp_kernel(enable_flash=True):
+            assert _flags.flag_value("flash_attention_backend") == "pallas"
+        assert _flags.flag_value("flash_attention_backend") == "auto"
+
+    def test_the_rule_is_asked_with_the_callers_shape(self, on_tpu):
+        from paddle_tpu.nn.functional import attention as attn
+        attn._use_pallas((2, 512, 4, 64), 64, False, dtype="bfloat16",
+                         causal=True, seq_k=768)
+        assert ar.decision_log()[-1][0] == (8, 512, 768, 64, "bfloat16",
+                                            True)
+
+    def test_bias_still_forces_dense(self, on_tpu):
         from paddle_tpu.nn.functional import attention as attn
         assert attn._use_pallas((2, 2048, 4, 128), 128, True) is False
+
+
+class TestOneAnswer:
+    """sdpa, attention_bshd, generation's prefill, the serving engine's
+    probe and chip_smoke's report ask the one rule: they agree."""
+
+    @pytest.mark.parametrize("heads,seq,d,dtype,backend", [
+        (2, 2048, 128, "bfloat16", "pallas"),
+        (4, 512, 96, "bfloat16", "pallas"),
+        (2, 256, 64, "float32", "xla"),
+    ])
+    def test_every_caller_agrees(self, on_tpu, monkeypatch, heads, seq, d,
+                                 dtype, backend):
+        import chip_smoke
+        import paddle_tpu as paddle
+        from paddle_tpu import generation
+        from paddle_tpu.inference import ContinuousBatchingEngine
+        from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        from paddle_tpu.nn import functional as F
+        from paddle_tpu.nn.functional.attention import attention_bshd
+
+        assert ar.route(heads, seq, seq, d, dtype, True).fwd == backend
+        flash = backend == "pallas"
+        calls = _spy_on_flash(monkeypatch)
+        q = jnp.zeros((1, seq, heads, d), dtype)
+        F.scaled_dot_product_attention(*(paddle.Tensor(q),) * 3,
+                                       is_causal=True)
+        assert bool(calls) == flash
+        del calls[:]
+        attention_bshd(q, q, q)
+        assert bool(calls) == flash
+        assert generation._prefill_flash_routed(heads, seq, d, dtype) \
+            == flash
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=32, hidden_size=heads * d, intermediate_size=32,
+            num_hidden_layers=1, num_attention_heads=heads,
+            max_position_embeddings=seq, dtype=dtype))
+        eng = ContinuousBatchingEngine(model, num_blocks=4, block_size=8,
+                                       max_batch=1, prefill_buckets=(seq,))
+        assert eng.attention_route.fwd == backend
+        report = chip_smoke._trainer_attention(1, heads, seq, d, dtype)
+        assert report["forward"] == ("pallas_flash" if flash
+                                     else "xla_dense")
+
+
+class TestBackwardOfAFlashForward:
+    def test_gradient_runs_the_two_backward_kernels(self):
+        """Nothing chooses the backward: at a shape the rule sends to the
+        kernels, the gradient of flash_attention_bshd is fa_bwd_dq and
+        fa_bwd_dkv."""
+        assert ar.route(2, 512, 512, 128, "bfloat16", True,
+                        platform="tpu").bwd == "pallas"
+        q = jax.ShapeDtypeStruct((1, 512, 2, 128), jnp.bfloat16)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda q_, k_, v_: jnp.sum(fa.flash_attention_bshd(
+                q_, k_, v_, causal=True).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, q, q))
+        for name in ("fa_fwd", "fa_bwd_dq", "fa_bwd_dkv"):
+            assert name in text, name

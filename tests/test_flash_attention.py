@@ -161,17 +161,7 @@ class TestHeadDimPadding:
 
 class TestFlashBackward(_BothTileRegimes):
     """The handwritten Pallas backward (dQ kernel + dK/dV kernel) must match
-    autodiff of the dense reference at fp32 tolerance. The bwd-mode flag is
-    pinned to 'pallas': 'auto' is routed per shape by the attention-backend
-    router (ledger/measurement), which could silently skip these kernels."""
-
-    @pytest.fixture(autouse=True)
-    def _pin_pallas_bwd(self):
-        from paddle_tpu.framework import flags as _flags
-        old = _flags.flag_value("flash_attention_bwd")
-        _flags.set_flags({"FLAGS_flash_attention_bwd": "pallas"})
-        yield
-        _flags.set_flags({"FLAGS_flash_attention_bwd": old})
+    autodiff of the dense reference at fp32 tolerance."""
 
     @pytest.mark.parametrize("sq,sk,causal", CASES)
     def test_grads_match_dense(self, sq, sk, causal):
@@ -378,59 +368,6 @@ class TestGQAModelPath:
         assert list(kproj.weight.shape)[-1] == 2 * (32 // 4)
 
 
-class TestBackwardModeSelection:
-    """The flash backward is selectable — 'pallas' (FA-2 kernels), 'xla'
-    (dense remat, XLA-differentiated), 'auto' (routed per shape by
-    ops/pallas/attention_router: baked hardware ledger first, then the
-    measurement fallback — on CPU the deterministic roofline proxy,
-    which always prefers the packed flash backward since it models no
-    O(S^2) remat traffic for it)."""
-
-    def _grads(self, mode, kvh=2):
-        from paddle_tpu.framework import flags as _flags
-        rs = np.random.RandomState(11)
-        q = _rand(rs, 1, 128, 4, 64)
-        k = _rand(rs, 1, 128, kvh, 64)
-        v = _rand(rs, 1, 128, kvh, 64)
-        old = _flags.flag_value("flash_attention_bwd")
-        _flags.set_flags({"FLAGS_flash_attention_bwd": mode})
-        try:
-            return jax.grad(
-                lambda *a: jnp.sum(flash_attention_bshd(*a, causal=True) ** 2),
-                argnums=(0, 1, 2))(q, k, v)
-        finally:
-            _flags.set_flags({"FLAGS_flash_attention_bwd": old})
-
-    @pytest.mark.parametrize("kvh", [4, 2])  # MHA and GQA-grouped
-    def test_xla_bwd_matches_pallas_bwd(self, kvh):
-        gp = self._grads("pallas", kvh)
-        gx = self._grads("xla", kvh)
-        for a, b in zip(gp, gx):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=2e-3, atol=2e-3)
-
-    def test_auto_threshold(self):
-        from paddle_tpu.ops.pallas import flash_attention as fa_mod
-        seen = []
-        orig = fa_mod._dense_remat_bwd
-
-        def spy(*a, **kw):
-            seen.append("xla")
-            return orig(*a, **kw)
-
-        fa_mod._dense_remat_bwd = spy
-        try:
-            # auto routes through the router; on CPU (no ledger match for
-            # this shape/device) the roofline proxy picks the pallas
-            # backward — no dense remat call
-            self._grads("auto")
-            assert seen == []
-            self._grads("xla")       # explicit xla still routes to dense
-            assert seen == ["xla"]
-        finally:
-            fa_mod._dense_remat_bwd = orig
-
-
 class TestProductionKernelSmoke:
     """Tier-1 pin of the PRODUCTION kernel flavor on CPU (ISSUE r6 CI
     satellite): bf16 operands + f32 accumulation at the tiles the chooser
@@ -498,8 +435,7 @@ class TestTileChooser:
         from paddle_tpu.ops.pallas import attention_router as ar
         bh, sq, sk, d = self.TRAIN
         ar.clear_routing_cache()
-        dec = ar.route(bh, sq, sk, d, "bfloat16", True, platform="tpu",
-                       device_kind="TPU v5 lite")
+        dec = ar.route(bh, sq, sk, d, "bfloat16", True, platform="tpu")
         assert dec.tiles == choose_tiles(sq, sk, d, 2)
         assert set(dec.grid_steps) == {"fa_fwd", "fa_bwd_dq", "fa_bwd_dkv"}
         # 8,192 a kernel before the two-level tiles

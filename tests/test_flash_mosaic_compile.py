@@ -97,8 +97,6 @@ def test_forward_and_backward_compile(one_chip, case):
 def test_wrappers_compile(one_chip):
     """head_dim 96 through the public wrapper (zero-padded to 128) with its
     gradient, and the RMSNorm epilogue riding the forward's flush."""
-    from paddle_tpu.framework import flags as _flags
-
     def sds(*shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
 
@@ -106,13 +104,8 @@ def test_wrappers_compile(one_chip):
         return jax.grad(lambda a, b, c: jnp.sum(fa.flash_attention_bshd(
             a, b, c, causal=True).astype(jnp.float32)), (0, 1, 2))(q, k, v)
 
-    old = _flags.flag_value("flash_attention_bwd")
-    _flags.set_flags({"FLAGS_flash_attention_bwd": "pallas"})
-    try:
-        x = sds(2, 512, 4, 96)
-        text = jax.jit(grads).lower(x, x, x).compile().as_text()
-    finally:
-        _flags.set_flags({"FLAGS_flash_attention_bwd": old})
+    x = sds(2, 512, 4, 96)
+    text = jax.jit(grads).lower(x, x, x).compile().as_text()
     assert "fa_fwd" in text and "fa_bwd_dkv" in text
 
     def epilogue(q, k, v, res, w):
@@ -126,19 +119,16 @@ def test_wrappers_compile(one_chip):
 def test_nope_gqa_at_8k_compiles_and_routes_to_the_kernels(one_chip):
     """The attention layer of granite-4.0-h-small at the benchmark cell's
     shape: causal GQA 32/8, d 128, seq 8192, softmax scale 1/128 (the
-    config's attention_multiplier, not 1/sqrt(d)), no rotary. The ledger
-    has no row above 4096; dense attention would hold 8 GiB of float32
-    scores, so the router names the kernels without measuring (source
-    `dense-too-large`), and they compile for a described v5e, forward and
-    backward, at the tiles the router's Decision records."""
+    config's attention_multiplier, not 1/sqrt(d)), no rotary. The rule
+    names the kernels (dense attention would hold 8 GiB of float32
+    scores), and they compile for a described v5e, forward and backward,
+    at the tiles the Decision records."""
     from paddle_tpu.ops.pallas.attention_router import (
         clear_routing_cache, route)
     bh, seq, d, rep, scale = 32, 8192, 128, 4, 0.0078125
     clear_routing_cache()
-    dec = route(bh, seq, seq, d, jnp.bfloat16, True, platform="tpu",
-                device_kind="TPU v5 lite")
-    assert (dec.fwd, dec.bwd, dec.source) == ("pallas", "pallas",
-                                              "dense-too-large")
+    dec = route(bh, seq, seq, d, jnp.bfloat16, True, platform="tpu")
+    assert (dec.fwd, dec.bwd) == ("pallas", "pallas")
     tiles = fa.tiles_for_shape(bh, seq, seq, d, jnp.bfloat16, True)
     assert dec.tiles == tiles
     # rows resident per grid step: 1024 (forward), 512 (backward kernels);
@@ -147,9 +137,6 @@ def test_nope_gqa_at_8k_compiles_and_routes_to_the_kernels(one_chip):
         == (512, 8192, 512)
     assert dec.grid_steps == {"fa_fwd": 256, "fa_bwd_dq": 512,
                               "fa_bwd_dkv": 512}
-    # a shape dense attention can hold is still the ledger's to rank
-    assert route(64, 2048, 2048, d, jnp.bfloat16, True, platform="tpu",
-                 device_kind="TPU v5 lite").source != "dense-too-large"
 
     def sds(*shape, dt=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)

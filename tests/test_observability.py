@@ -524,20 +524,6 @@ class TestServingTelemetry:
         assert regd.get("serving_ttft_seconds").count == 0
 
 
-class TestRouterCounters:
-    def test_fresh_decisions_counted_by_source(self, enabled_obs):
-        from paddle_tpu.ops.pallas import attention_router as ar
-        ar.clear_routing_cache()
-        fam = obs.get_registry().get("attention_router_decisions_total")
-        dec = ar.route(64, 512, 512, 64, "float32", True, platform="cpu")
-        child = fam.labels(source=dec.source)
-        after_first = child.value
-        assert after_first >= 1
-        ar.route(64, 512, 512, 64, "float32", True, platform="cpu")  # hit
-        assert child.value == after_first   # cache hits are not re-counted
-        ar.clear_routing_cache()
-
-
 class TestElasticCounters:
     def test_watch_restart_counts(self, enabled_obs):
         from paddle_tpu.distributed.fleet.elastic import (ElasticManager,

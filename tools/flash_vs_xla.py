@@ -1,14 +1,16 @@
 """Measure Pallas flash attention vs XLA dense attention on real hardware.
 
-This tool times fwd and fwd+bwd for the dense path, the full-Pallas path
+The one tool that re-checks the rule in ops/pallas/attention_router.py on
+a chip. It times forward and forward + backward of the flash kernels
 (ops/pallas/flash_attention.py: two-level tiles chosen from the shape by
-`choose_tiles`, one causal sweep), and the hybrid (Pallas fwd + XLA-remat
-bwd — the `flash_attention_bwd` modes) across seq 1024-4096 (causal,
-bf16); times each of the three kernels alone (fa_fwd, fa_bwd_dq,
-fa_bwd_dkv) at the tiles the chooser hands the shape, with their grid
-steps; with --tune also times the autotuner's (resident, sub) row
-candidates; and writes the table that tools/bake_flash_blocks.py bakes
-into the attention ledger.
+`choose_tiles`, one causal sweep) against dense XLA attention over ROWS:
+the rows PR 27 measured at the training cells' lengths (seq 1024-4096,
+head dim 96 / 128, causal, bf16) and the sweep around them (seq 64-1024
+x head dim 64 / 128 x causal and not; head dim 64 and non-causal at long
+seq; float32; seq_q != seq_k). It also times each of the three kernels
+alone (fa_fwd, fa_bwd_dq, fa_bwd_dkv) at the tiles the chooser hands the
+shape, with their grid steps, and with --tune the autotuner's (resident,
+sub) row candidates.
 
 Timing method: each measurement runs N iterations INSIDE one compiled
 lax.scan so per-dispatch launch overhead is amortized out of the kernel
@@ -16,10 +18,10 @@ time. The scan carry feeds each iteration so XLA cannot hoist the body.
 
 Run it on the chip through the chip tool (one process holds the chip):
   chiprun -- python tools/flash_vs_xla.py
-The table is printed as the last line and written to
-chiprun_out/flash_vs_xla.json, which the tool copies back; move it to
-.flash_vs_xla.json to re-bake. Without a TPU the tool exits 1 unless
---cpu asks for the tiny smoke shapes (which write nothing).
+The table is printed at the end and written whole to
+chiprun_out/flash_vs_xla.json, which the tool copies back. Without a TPU
+the tool exits 1 unless --cpu asks for the tiny smoke shapes (which write
+nothing).
 """
 
 import json
@@ -127,93 +129,107 @@ def main():
         sys.exit("flash_vs_xla: jax found no TPU; pass --cpu for the "
                  "smoke shapes")
 
-    from paddle_tpu.framework import flags as _flags
     from paddle_tpu.nn.functional.attention import _xla_attention
     from paddle_tpu.ops.pallas.flash_attention import flash_attention_bshd
     from paddle_tpu.ops.pallas import autotune as at
 
-    # (seq, batch, heads, head_dim): keep the DENSE path's fp32 logits
-    # <= ~512 MB. head_dim 96 rows measure the zero-pad path (llama_780m)
-    shapes = [(1024, 8, 16, 128), (2048, 4, 8, 128), (4096, 1, 8, 128),
-              (2048, 4, 8, 96)]
-    # timed a kernel at a time (no dense A/B, so no logits-buffer cap):
-    # (2048, 4, 16, 128) is the benchmark's training shape (gpt3-xl-d12
-    # and llama_535m: batch 4, 16 heads, d 128) — `_TILE_ROWS` in
-    # flash_attention.py was measured there
-    tune_shapes = shapes + [(2048, 4, 16, 128)]
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    # (seq_q, seq_k, batch, heads, head_dim, dtype, causal): batch x heads
+    # keeps the DENSE path's fp32 scores <= 512 MB. The first four are
+    # PR 27's rows (head_dim 96 rides zero-padded to 128)
+    shapes = [(1024, 1024, 8, 16, 128, bf16, True),
+              (2048, 2048, 4, 8, 128, bf16, True),
+              (4096, 4096, 1, 8, 128, bf16, True),
+              (2048, 2048, 4, 8, 96, bf16, True)]
+    shapes += [(s, s, 8, 16, d, bf16, c)
+               for s in (64, 128, 256, 512, 1024) for d in (64, 128)
+               for c in (True, False) if (s, d, c) != (1024, 128, True)]
+    shapes += [(2048, 2048, 4, 8, 64, bf16, True),
+               (4096, 4096, 1, 8, 64, bf16, True),
+               (2048, 2048, 4, 8, 128, bf16, False),
+               (4096, 4096, 1, 8, 128, bf16, False),
+               (512, 512, 8, 16, 128, f32, True),
+               (2048, 2048, 4, 8, 128, f32, True),
+               (128, 2048, 4, 8, 128, bf16, True),
+               (512, 4096, 1, 8, 128, bf16, True),
+               (1024, 4096, 1, 8, 128, bf16, True)]
+    # timed a kernel at a time (no dense A/B, so no scores-buffer cap):
+    # (2048, 4, 16, 128) is the benchmark's training shape (gpt3-xl-d12:
+    # batch 4, 16 heads, d 128) — `_TILE_ROWS` in flash_attention.py was
+    # measured there
+    tune_shapes = [(1024, 8, 16, 128), (2048, 4, 8, 128), (4096, 1, 8, 128),
+                   (2048, 4, 16, 128)]
     if not on_tpu:
-        shapes = [(256, 1, 2, 128), (256, 1, 2, 96)]
-        tune_shapes = shapes
-    causal = True
+        shapes = [(256, 256, 1, 2, 128, bf16, True),
+                  (128, 256, 1, 2, 96, f32, False)]
+        tune_shapes = [(256, 1, 2, 128)]
     rows = []
 
-    def flash_sum(q, k, v):
-        return jnp.sum(flash_attention_bshd(q, k, v, causal=True)
-                       .astype(jnp.float32))
+    for sq, sk, b, h, d, dtype, causal in shapes:
+        def flash_out(q, k, v):
+            return flash_attention_bshd(q, k, v, causal=causal)
 
-    def dense_sum(q, k, v):
-        return jnp.sum(_xla_attention(q, k, v, causal=True)
-                       .astype(jnp.float32))
+        def dense_out(q, k, v):
+            return _xla_attention(q, k, v, causal=causal)
 
-    flash_grad = jax.grad(flash_sum, argnums=(0, 1, 2))
-    dense_grad = jax.grad(dense_sum, argnums=(0, 1, 2))
+        def fsum(fn):
+            return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32))
 
-    for seq, b, h, d in shapes:
+        def gsum(fn):
+            g = jax.grad(fsum(fn), argnums=(0, 1, 2))
+            return lambda q, k, v: sum(
+                jnp.sum(x.astype(jnp.float32)) for x in g(q, k, v))
+
         rng = np.random.RandomState(0)
-        q = jnp.asarray(rng.randn(b, seq, h, d), jnp.bfloat16)
-        k = jnp.asarray(rng.randn(b, seq, h, d), jnp.bfloat16)
-        v = jnp.asarray(rng.randn(b, seq, h, d), jnp.bfloat16)
+        q = jnp.asarray(rng.randn(b, sq, h, d), dtype)
+        k = jnp.asarray(rng.randn(b, sk, h, d), dtype)
+        v = jnp.asarray(rng.randn(b, sk, h, d), dtype)
 
         # numeric gate first: flash must agree with dense before timing
-        of = np.asarray(jax.jit(lambda a, b_, c: flash_attention_bshd(
-            a, b_, c, causal=True))(q, k, v).astype(jnp.float32))
-        od = np.asarray(jax.jit(lambda a, b_, c: _xla_attention(
-            a, b_, c, causal=True))(q, k, v).astype(jnp.float32))
+        of, od = (np.asarray(jax.jit(fn)(q, k, v).astype(jnp.float32))
+                  for fn in (flash_out, dense_out))
         err = float(np.max(np.abs(of - od)))
-        log(f"seq={seq} b={b} h={h}: max|flash-dense| = {err:.4f}")
-        row = {"seq": seq, "batch": b, "heads": h, "head_dim": d,
-               "max_abs_err": err, "iters_per_timing": N_ITERS}
+        row = {"seq_q": sq, "seq_k": sk, "batch": b, "heads": h,
+               "head_dim": d, "dtype": jnp.dtype(dtype).name,
+               "causal": causal, "max_abs_err": err,
+               "iters_per_timing": N_ITERS}
+        rows.append(row)
         if err > 0.1:  # bf16 inputs: ~1e-2 expected; 0.1 = clearly wrong
             row["error"] = "NUMERIC MISMATCH — timing skipped"
-            rows.append(row)
+            log(f"{row}")
             continue
 
-        tf = timeit(amortized(flash_sum), q, k, v)
-        td = timeit(amortized(dense_sum), q, k, v)
-        tg = {}
-        for name, mode, gfn in (("pallas", "pallas", flash_grad),
-                                ("hybrid", "xla", flash_grad),
-                                ("dense", "pallas", dense_grad)):
-            _flags.set_flags({"FLAGS_flash_attention_bwd": mode})
-            tg[name] = timeit(amortized(
-                lambda q_, k_, v_, g=gfn: sum(
-                    jnp.sum(x.astype(jnp.float32)) for x in g(q_, k_, v_))),
-                q, k, v)
-        _flags.set_flags({"FLAGS_flash_attention_bwd": "auto"})
-        fl_f = attention_flops(b, h, seq, seq, d, causal)
-        fl_b = fl_f + attention_flops(b, h, seq, seq, d, causal, bwd=True)
+        try:
+            ms = {name: timeit(amortized(step), q, k, v) * 1e3
+                  for name, step in (("flash_fwd", fsum(flash_out)),
+                                     ("dense_fwd", fsum(dense_out)),
+                                     ("flash_fwdbwd", gsum(flash_out)),
+                                     ("dense_fwdbwd", gsum(dense_out)))}
+        except Exception as e:  # noqa: BLE001 — an arm the compiler or
+            # the device's memory refuses is the row's finding
+            row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+            log(f"{row}")
+            continue
+        fl_f = attention_flops(b, h, sq, sk, d, causal)
+        fl_b = fl_f + attention_flops(b, h, sq, sk, d, causal, bwd=True)
+        row.update({f"{name}_ms": round(t, 4) for name, t in ms.items()})
         row.update({
-            "flash_fwd_ms": round(tf * 1e3, 3),
-            "dense_fwd_ms": round(td * 1e3, 3),
-            "fwd_speedup": round(td / tf, 3),
-            "fwdbwd_ms_pallas": round(tg["pallas"] * 1e3, 3),
-            "fwdbwd_ms_hybrid": round(tg["hybrid"] * 1e3, 3),
-            "fwdbwd_ms_dense": round(tg["dense"] * 1e3, 3),
-            "flash_fwd_tflops": round(fl_f / tf / 1e12, 2),
-            "tflops_pallas_bwd": round(fl_b / tg["pallas"] / 1e12, 2),
-            "tflops_hybrid_bwd": round(fl_b / tg["hybrid"] / 1e12, 2),
-            "tflops_dense": round(fl_b / tg["dense"] / 1e12, 2),
+            "fwd_speedup": round(ms["dense_fwd"] / ms["flash_fwd"], 3),
+            "fwdbwd_speedup": round(
+                ms["dense_fwdbwd"] / ms["flash_fwdbwd"], 3),
+            "flash_fwd_tflops": round(fl_f / ms["flash_fwd"] / 1e9, 2),
+            "flash_fwdbwd_tflops": round(fl_b / ms["flash_fwdbwd"] / 1e9, 2),
         })
-        rows.append(row)
-        log(f"  fwd: flash {tf*1e3:.2f}ms vs dense {td*1e3:.2f}ms "
-            f"({td/tf:.2f}x) | fwd+bwd ms: pallas {tg['pallas']*1e3:.2f} "
-            f"hybrid {tg['hybrid']*1e3:.2f} dense {tg['dense']*1e3:.2f}")
+        log(f"sq={sq} sk={sk} bh={b * h} d={d} {row['dtype']} "
+            f"causal={causal} err={err:.4f} | fwd: flash "
+            f"{ms['flash_fwd']:.3f} dense {ms['dense_fwd']:.3f} ms "
+            f"({row['fwd_speedup']}x) | fwd+bwd: flash "
+            f"{ms['flash_fwdbwd']:.3f} dense {ms['dense_fwdbwd']:.3f} ms "
+            f"({row['fwdbwd_speedup']}x)")
 
     # each kernel alone, at the chooser's tiles
     kernels = {}
     for seq, b, h, d in tune_shapes:
-        if d % 128:
-            continue    # the wrapper pads such head dims; the A/B rows do
         res = kernel_ms(b, h, seq, d)
         kernels[f"s{seq}_d{d}_bh{b * h}"] = res
         log(f"kernels seq={seq} bh={b * h}: {res['ms']} ms, grid steps "
@@ -237,7 +253,6 @@ def main():
 
     out = {"device": str(dev),
            "device_kind": getattr(dev, "device_kind", "?"),
-           "causal": causal, "dtype": "bfloat16",
            "rows": rows, "kernels": kernels, "autotuned_tiles": tuned}
     if on_tpu:
         os.makedirs(os.path.join(REPO, "chiprun_out"), exist_ok=True)
@@ -245,7 +260,8 @@ def main():
         with open(path, "w") as f:
             json.dump(out, f, indent=1)
         log(f"wrote {path}")
-    print(json.dumps(out))
+    for r in rows:
+        print(json.dumps(r))
 
 
 if __name__ == "__main__":

@@ -26,7 +26,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .framework.core import Tensor
+from .framework.core import Tensor, no_grad
 from .framework import random as _random
 from .observability import span as _span
 from .observability.catalog import metric as _metric
@@ -328,11 +328,13 @@ def _build_llama_generate(config, tied: bool, gc: GenerationConfig):
 
 def _generic_generate(model, input_ids, gc: GenerationConfig, key):
     """Fallback for models without a cache path: recompute the full prefix
-    each step (O(n) forwards). Correct for any causal LM returning logits."""
+    each step (O(n) forwards). Correct for any causal LM returning logits.
+    No tape: a forward that nobody differentiates records and keeps
+    nothing for a backward."""
     ids = input_ids
     done = jnp.zeros((ids.shape[0],), bool)
     for _ in range(gc.max_new_tokens):
-        with _span("generation.decode_step"):
+        with _span("generation.decode_step"), no_grad():
             out = model(Tensor(ids))
         logits = (out[0] if isinstance(out, tuple) else out)._data
         key, sub = jax.random.split(key)

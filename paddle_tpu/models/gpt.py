@@ -10,7 +10,9 @@ from __future__ import annotations
 import jax
 
 from .. import nn
+from ..framework.core import execute
 from ..nn import functional as F
+from ..ops.gelu_once import gelu_once
 from ..tensor.manipulation import reshape
 from ._init import transformer_init_attr
 
@@ -82,7 +84,10 @@ class GPTBlock(nn.Layer):
         with jax.named_scope("pt.attn"):
             x = x + self.attn(self.ln_1(x))
         with jax.named_scope("pt.mlp"):
-            h = self.fc2(F.gelu(self.fc1(self.ln_2(x))))
+            # the activation sits between two matmuls and a GPT step has
+            # memory to spare: evaluate it once a layer (ops/gelu_once.py)
+            h = execute(gelu_once, self.fc1(self.ln_2(x)), _name="gelu_once")
+            h = self.fc2(h)
             return x + self.dropout(h)
 
 

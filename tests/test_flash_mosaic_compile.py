@@ -10,6 +10,8 @@ timed. All such compiles live in this one file: only the worker that is
 handed it loads the TPU's library, inside the fixture.
 """
 
+import collections
+import functools
 import os
 import re
 
@@ -198,3 +200,101 @@ def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(one_chip):
             assert rx.search(op_name), (name, op_name)
             assert "pt.moe" not in op_name      # or the first alternative
             # of moe_time_share's pattern would count the kernel twice
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$")
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([\w\-]+)\(")
+_CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")
+
+
+def _matmul_fusions(text):
+    """[(result shape, opcodes, op_names of its convolutions)] of every
+    fusion of an optimised HLO text that holds a convolution, its
+    computation's nested calls included; a fusion inside another is the
+    outer one's."""
+    bodies, current = {}, None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            current = bodies.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            current = None
+        elif current is not None:
+            m = _INSTRUCTION.match(line)
+            if m:
+                current.append((m.group(1), m.group(2), line))
+
+    def walk(name, ops, convs):
+        for _, opcode, line in bodies.get(name, ()):
+            ops[opcode] += 1
+            if opcode == "convolution":
+                convs.append(re.search(r'op_name="([^"]*)"', line).group(1))
+            for callee in _CALLED.findall(line):
+                walk(callee, ops, convs)
+
+    fused = {callee for body in bodies.values() for _, opcode, line in body
+             if opcode == "fusion" for callee in _CALLED.findall(line)}
+    out = []
+    for name, body in bodies.items():
+        if name in fused:
+            continue
+        for shape, opcode, line in body:
+            if opcode == "fusion":
+                ops, convs = collections.Counter(), []
+                for callee in _CALLED.findall(line):
+                    walk(callee, ops, convs)
+                if convs:
+                    out.append((shape, ops, convs))
+    return out
+
+
+def test_gelu_once_leaves_the_activation_in_one_fusion_a_layer(one_chip):
+    """Two blocks of LayerNorm -> fc1 -> GELU -> fc2 at the GPT cell's
+    widths, forward, backward and a donated update, compiled for a
+    described v5e. With jax.nn.gelu (the control) only the pre-activation
+    is kept and the activation is evaluated again inside the matmul
+    fusions that read it: fc2's forward, fc2's weight gradient and, as its
+    derivative, the epilogue of the gradient that flows back to fc1. With
+    ops/gelu_once.py the `exponential` (of erfc there, of the density
+    here) is in exactly one fusion a layer, the one that holds fc1's
+    forward matmul and returns value and derivative, both
+    `bf16[4,2048,8192]`."""
+    from paddle_tpu.ops.gelu_once import gelu_once
+    layers, hidden, inner = 2, 2048, 8192
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    layer = {"scale": sds(hidden), "shift": sds(hidden),
+             "w1": sds(hidden, inner), "b1": sds(inner),
+             "w2": sds(inner, hidden), "b2": sds(hidden)}
+
+    def step(act, params, x):
+        def loss(params):
+            h = x
+            for i, p in enumerate(params):
+                with jax.named_scope(f"layer{i}"):
+                    mean = jnp.mean(h.astype(jnp.float32), -1, keepdims=True)
+                    var = jnp.var(h.astype(jnp.float32), -1, keepdims=True)
+                    n = ((h - mean) * jax.lax.rsqrt(var + 1e-5)).astype(
+                        h.dtype) * p["scale"] + p["shift"]
+                    h = h + act(n @ p["w1"] + p["b1"]) @ p["w2"] + p["b2"]
+            return jnp.mean(h.astype(jnp.float32) ** 2)
+        value, grads = jax.value_and_grad(loss)(params)
+        return value, jax.tree_util.tree_map(
+            lambda p, g: p - 1e-4 * g.astype(p.dtype), params, grads)
+
+    def compiled(act):
+        return _matmul_fusions(
+            jax.jit(functools.partial(step, act), donate_argnums=0).lower(
+                [layer] * layers, sds(4, 2048, hidden)).compile().as_text())
+
+    once = [f for f in compiled(gelu_once) if f[1]["exponential"]]
+    assert len(once) == layers, [(f[0], f[2]) for f in once]
+    for shape, _, convs in once:
+        assert shape.count(f"bf16[4,2048,{inner}]") == 2, shape
+        # fc1's forward product, not a product of the backward
+        assert len(convs) == 1 and "transpose(" not in convs[0], convs
+    control = [f for f in compiled(
+        lambda a: jax.nn.gelu(a, approximate=False)) if f[1]["exponential"]]
+    assert len(control) >= 3 * layers, [(f[0], f[2]) for f in control]

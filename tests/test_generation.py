@@ -105,6 +105,38 @@ class TestGenerate:
         out = generation.generate(model, ids, max_new_tokens=3)
         assert np.asarray(out._data).shape == (1, 7)
 
+    def test_generic_fallback_records_no_tape_for_any_model(self):
+        """The recompute path runs every model without a cache path under
+        `no_grad`: a causal LM that is neither llama nor GPT sees grad mode
+        off in its forward, its logits carry no backward, the caller's
+        grad mode comes back, and the tokens are the greedy recompute's."""
+        from paddle_tpu import nn
+
+        class BagOfPrefix(nn.Layer):
+            def __init__(self):
+                super().__init__()
+                self.embed = nn.Embedding(32, 16)
+                self.head = nn.Linear(16, 32)
+                self.seen = []
+
+            def forward(self, ids):
+                h = paddle.cumsum(self.embed(ids), axis=1)
+                logits = self.head(paddle.nn.functional.gelu(h))
+                self.seen.append((paddle.is_grad_enabled(),
+                                  logits.stop_gradient))
+                return logits
+
+        paddle.seed(3)
+        model = BagOfPrefix()
+        ids = np.random.RandomState(3).randint(0, 32, (2, 5))
+        out = generation.generate(model, jnp.asarray(ids, jnp.int32),
+                                  max_new_tokens=3)
+        assert model.seen == [(False, True)] * 3
+        assert paddle.is_grad_enabled()
+        np.testing.assert_array_equal(np.asarray(out._data),
+                                      _greedy_recompute(model, ids, 3))
+        assert model.seen[-1] == (True, False)   # outside, the tape is on
+
 
     def test_generation_tracks_weight_updates(self):
         """The compiled program must take weights as arguments — after an

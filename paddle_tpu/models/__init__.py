@@ -9,3 +9,5 @@ from .granite_moe_hybrid import (GraniteMoeHybridConfig,  # noqa: F401
                                  GraniteMoeHybridModel,
                                  GraniteMoeHybridForCausalLM,
                                  granite_hybrid_tiny)
+from .brumby import (BrumbyConfig, BrumbyModel, BrumbyForCausalLM,  # noqa: F401
+                     brumby_tiny)

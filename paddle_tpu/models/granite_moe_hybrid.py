@@ -44,7 +44,6 @@ import jax
 import jax.numpy as jnp
 
 from .. import nn
-from ..framework.core import execute
 from ..framework.param_attr import ParamAttr
 from ..framework.random import next_key
 from ..generation import _rms
@@ -54,6 +53,8 @@ from ..nn.functional.attention import attention_bshd
 from ..ops.mamba2 import (causal_conv1d_silu, gated_rms_norm,
                           ssd_chunked_scan)
 from ..parallel.moe import dropless_moe
+from .sub_block import (Params as _Params, SubBlock as _SubBlock,
+                        over_token_blocks)
 
 __all__ = ["GraniteMoeHybridConfig", "GraniteMoeHybridModel",
            "GraniteMoeHybridForCausalLM", "granite_hybrid_tiny",
@@ -160,38 +161,6 @@ def _gated_mlp(x, w_in, w_out):
     h = x @ w_in
     inter = w_out.shape[0]
     return (jax.nn.silu(h[..., :inter]) * h[..., inter:]) @ w_out
-
-
-class _Params(nn.Layer):
-    """Named parameters of one sub-module, made in the model's dtype."""
-
-    def __init__(self, dtype, **specs):
-        super().__init__()
-        for name, (shape, init) in specs.items():
-            setattr(self, name, self.create_parameter(
-                shape, attr=ParamAttr(initializer=init), dtype=dtype))
-
-
-class _SubBlock(nn.Layer):
-    """A residual sub-block computed by one pure function of (hidden,
-    parameters), rematerialised in the backward."""
-
-    def _pure(self, h, **params):
-        raise NotImplementedError
-
-    def _over(self, block, h):
-        """`block` (the rematerialised `_pure`) over the hidden states."""
-        return block(h)
-
-    def forward(self, hidden):
-        names, tensors = zip(*self.named_parameters())
-
-        def pure(h, *arrays):
-            params = {n.replace(".", "_"): a for n, a in zip(names, arrays)}
-            return self._over(
-                jax.checkpoint(lambda hb: self._pure(hb, **params)), h)
-
-        return execute(pure, hidden, *tensors, _name=type(self).__name__)
 
 
 class GraniteMambaMixer(_SubBlock):
@@ -319,15 +288,8 @@ class GraniteMoeFFN(_SubBlock):
 
     def _over(self, block, h):
         """Blocks of FFN_TOKEN_BLOCK tokens one after the other, each
-        rematerialised on its own. A loop, not an unrolled list:
-        independent blocks would be scheduled side by side and hold all
-        their rows at once."""
-        b, s, d = h.shape
-        n = b * s // FFN_TOKEN_BLOCK
-        if n < 2 or b * s % FFN_TOKEN_BLOCK:
-            return block(h)
-        blocks = h.reshape(n, 1, FFN_TOKEN_BLOCK, d)
-        return jax.lax.map(block, blocks).reshape(b, s, d)
+        rematerialised on its own."""
+        return over_token_blocks(block, h, FFN_TOKEN_BLOCK)
 
 
 class GraniteDecoderLayer(nn.Layer):

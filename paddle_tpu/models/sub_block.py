@@ -1,0 +1,64 @@
+"""Residual sub-blocks that are rematerialised in the backward: what
+models/granite_moe_hybrid.py and models/brumby.py build their layers from.
+
+A step then holds the sub-blocks' inputs and one sub-block's internals.
+The layers are not stacked and scanned: a scan's backward returns the
+stacked weight gradients whole, and the trainer's fused update (6 bytes a
+parameter) rests on each gradient dying where it is made.
+"""
+
+from __future__ import annotations
+
+import jax
+
+from .. import nn
+from ..framework.core import execute
+from ..framework.param_attr import ParamAttr
+
+__all__ = ["Params", "SubBlock", "over_token_blocks"]
+
+
+class Params(nn.Layer):
+    """Named parameters of one sub-module, made in the model's dtype."""
+
+    def __init__(self, dtype, **specs):
+        super().__init__()
+        for name, (shape, init) in specs.items():
+            setattr(self, name, self.create_parameter(
+                shape, attr=ParamAttr(initializer=init), dtype=dtype))
+
+
+class SubBlock(nn.Layer):
+    """A residual sub-block computed by one pure function of (hidden,
+    parameters), rematerialised in the backward."""
+
+    def _pure(self, h, **params):
+        raise NotImplementedError
+
+    def _over(self, block, h):
+        """`block` (the rematerialised `_pure`) over the hidden states."""
+        return block(h)
+
+    def forward(self, hidden):
+        names, tensors = zip(*self.named_parameters())
+
+        def pure(h, *arrays):
+            params = {n.replace(".", "_"): a for n, a in zip(names, arrays)}
+            return self._over(
+                jax.checkpoint(lambda hb: self._pure(hb, **params)), h)
+
+        return execute(pure, hidden, *tensors, _name=type(self).__name__)
+
+
+def over_token_blocks(block, h, size):
+    """`block` over (batch, seq, hidden) in blocks of `size` tokens one
+    after the other, each rematerialised on its own, where the tokens are
+    a multiple of `size` and more than one block. A loop, not an unrolled
+    list: independent blocks would be scheduled side by side and hold all
+    their rows at once."""
+    b, s, d = h.shape
+    n = b * s // size
+    if n < 2 or b * s % size:
+        return block(h)
+    blocks = h.reshape(n, 1, size, d)
+    return jax.lax.map(block, blocks).reshape(b, s, d)

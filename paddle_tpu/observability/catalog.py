@@ -271,6 +271,13 @@ CATALOG = {
         "gauge", "largest / mean number of assignments over the held "
         "experts, same sequence, the layers' mean (1.0 = even load)", (),
         None),
+    "retention_mean_horizon_tokens": (
+        "gauge", "mean over layers and state heads of 1 / (1 - mean_t g_t), "
+        "the tokens a power-retention state remembers, from the model's "
+        "own gate (models/brumby.py retention_log_gate) on every layer's "
+        "own input on one sequence; a statistic of the weights it was "
+        "taken at, set by whoever builds the model, outside the step", (),
+        None),
     "train_nonfinite_skips_total": (
         "counter", "batches skipped by the TrainSupervisor for a "
         "non-finite loss", (), None),
@@ -523,6 +530,12 @@ TRACE_SCOPES = {
     "pt.ssm": "state-space (Mamba-2) mixer: norm, in/out projections, "
               "causal conv, the scan, gated norm, residual",
     "pt.ssm.scan": "the chunked state-space scan alone (ops/mamba2.py)",
+    "pt.retn": "power-retention mixer (models/brumby.py): norm, q/k/v/gate "
+               "projections, q/k norm, RoPE, the retention, output "
+               "projection, residual",
+    "pt.retn.scan": "the chunked power retention alone (ops/"
+                    "power_retention.py): expansion, in-chunk products, "
+                    "state products, the carried state, the normaliser",
     "pt.moe": "routed experts: router, dispatch, grouped expert matmuls "
               "with their activation, combine",
     "pt.moe.route": "what in pt.moe is no expert work: router logits, "

@@ -23,12 +23,12 @@ def test_benchmark_tests_pass():
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "benchmark/tests", "-q",
          "-p", "no:cacheprovider"],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=300)
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=450)
     tail = proc.stdout[-3000:] + proc.stderr[-1000:]
     assert proc.returncode == 0, tail
     counts = dict((word, int(n)) for n, word in re.findall(
         r"(\d+) (passed|failed|error|errors|skipped)", proc.stdout))
-    # 38 when this test was written (PR 30); a benchmark PR adds, never
-    # loses
-    assert counts.get("passed", 0) >= 38, tail
+    # 38 when this test was written (PR 30), 46 with the Brumby cell's
+    # (PR 32, 105 s alone); a benchmark PR adds, never loses
+    assert counts.get("passed", 0) >= 46, tail
     assert set(counts) <= {"passed"}, tail

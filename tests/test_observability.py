@@ -255,6 +255,31 @@ class TestCatalog:
         assert r.get(names[0]).value == 4 / 8.0
         assert r.get(names[1]).value == 1.0
 
+    def test_retention_horizon_gauge_is_declared_and_read_off_the_gate(self):
+        """The power-retention gauge: a gauge without labels in the catalog
+        and in OBSERVABILITY.md's table, and what the model's own gate
+        function (models/brumby.py) gives is what it holds: a zero gate
+        matrix and biases logit(1 - 1/h) read the horizons h back."""
+        import jax.numpy as jnp
+        from paddle_tpu.models.brumby import retention_log_gate
+        name = "retention_mean_horizon_tokens"
+        text = open(os.path.join(REPO, "OBSERVABILITY.md")).read()
+        assert obs_catalog.CATALOG[name][0] == "gauge"
+        assert obs_catalog.CATALOG[name][2] == ()
+        assert re.search(rf"^\| `{name}` \| gauge \| - \|", text,
+                         re.MULTILINE)
+        want = jnp.asarray([64.0, 1024.0])
+        log_g = retention_log_gate(
+            jnp.ones((12, 16)), jnp.ones((16,)), jnp.zeros((16, 2)),
+            jnp.log(want - 1.0), 1e-6)
+        assert log_g.shape == (12, 2) and log_g.dtype == jnp.float32
+        horizons = 1.0 / (1.0 - jnp.mean(jnp.exp(log_g), axis=0))
+        r = obs_metrics.MetricRegistry(enabled=True)
+        obs_catalog.register_all(r)
+        r.get(name).set(float(jnp.mean(horizons)))
+        # float32's 1 - g at g = 1 - 1/1024: 1e-4 of the horizon
+        assert abs(r.get(name).value - 544.0) < 0.1
+
     def test_metric_refuses_unknown_names(self):
         with pytest.raises(KeyError, match="catalog"):
             obs.metric("not_a_registered_name_total")

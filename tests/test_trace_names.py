@@ -38,6 +38,8 @@ SERVE_SCOPES = ("pt.embed", "pt.attn", "pt.mlp", "pt.head",
                 "pt.serve.gather", "pt.serve.attend", "pt.serve.sample")
 # what models/granite_moe_hybrid.py adds to a train step's names
 HYBRID_SCOPES = ("pt.ssm", "pt.ssm.scan", "pt.moe", "pt.moe.route")
+# what models/brumby.py adds
+RETENTION_SCOPES = ("pt.retn", "pt.retn.scan")
 
 
 def _trainer():
@@ -265,11 +267,51 @@ def test_hybrid_train_step_lowers_with_the_mixer_and_expert_scopes():
                for h in _scope_hits(text, "pt.moe.route"))
 
 
+def test_retention_train_step_lowers_with_the_mixer_scopes():
+    """The power-retention mixer carries its scope through the sub-block's
+    rematerialisation, the map over state heads and the rematerialised
+    scan over chunks, forward and backward, nested as the readers'
+    patterns expect (pt.retn.scan inside pt.retn); the FFN keeps pt.mlp,
+    and the head and the loss, taken a block of tokens at a time, pt.head
+    and pt.loss."""
+    from paddle_tpu.framework.random import get_rng_state
+    from paddle_tpu.models.brumby import brumby_tiny
+    from paddle_tpu.parallel import DP_ONLY_RULES
+    paddle.seed(0)
+    model = brumby_tiny()
+    opt = optimizer.AdamW(1e-3, parameters=model.parameters())
+    trainer = SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
+                          DP_ONLY_RULES)
+    ids = np.zeros((1, 16), np.int32)
+    batch = trainer._batch_arrays((ids, ids))
+    with jax.set_mesh(trainer.mesh):
+        text = trainer._compiled.lower(
+            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
+            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
+        ).as_text(debug_info=True)
+    for scope in RETENTION_SCOPES + TRAIN_SCOPES:
+        if scope == "pt.attn":
+            assert not _scope_hits(text, scope)     # no attention here
+            continue
+        hits = _scope_hits(text, scope)
+        assert hits, scope
+        if scope != "pt.opt":
+            assert any("transpose(jvp(" in h for h in hits), scope
+    scan = _scope_hits(text, "pt.retn.scan")
+    assert any("pt.retn/pt.retn.scan" in h for h in scan)
+    # the two readers' patterns: the mixer's takes the scan's operations
+    # too, the scan's takes nothing of the mixer outside it
+    mixer = re.compile(r"\bpt\.retn\b")
+    inner = re.compile(r"\bpt\.retn\.scan\b")
+    assert all(mixer.search(h) for h in scan)
+    assert any(not inner.search(h) for h in _scope_hits(text, "pt.retn"))
+
+
 def test_every_declared_scope_is_held_by_a_test_and_none_else():
     """catalog.py TRACE_SCOPES against the scopes these tests look for in
     lowered programs, both directions."""
     assert set(TRAIN_SCOPES) | set(SERVE_SCOPES) | set(HYBRID_SCOPES) \
-        == set(TRACE_SCOPES)
+        | set(RETENTION_SCOPES) == set(TRACE_SCOPES)
 
 
 @pytest.fixture(scope="module")

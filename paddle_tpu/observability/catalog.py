@@ -550,12 +550,20 @@ TRACE_SCOPES = {
                        "decode scan",
 }
 
-# `name=` of the Pallas kernels (ops/pallas/flash_attention.py): the
-# Mosaic kernel name and the innermost scope of the call on the trace.
+# `name=` of the Pallas kernels (ops/pallas/flash_attention.py,
+# ops/pallas/power_retention.py): the Mosaic kernel name and the innermost
+# scope of the call on the trace.
 KERNEL_NAMES = {
     "fa_fwd": "flash attention forward (+ fused RMS epilogue)",
     "fa_bwd_dq": "flash attention backward, dQ",
     "fa_bwd_dkv": "flash attention backward, dK and dV",
+    "retn_read": "power retention: phi(u) @ M, the expansion made a "
+                 "rotation at a time in VMEM (the state read; in the "
+                 "backward, the cotangent of what was written)",
+    "retn_write": "power retention: phi(u)^T @ W over the row grid (the "
+                  "state's update; in the backward, the state's cotangent)",
+    "retn_back": "power retention: the chain rule through phi back to q "
+                 "or k, phi's own cotangent never in memory",
 }
 
 

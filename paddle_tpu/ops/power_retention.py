@@ -20,8 +20,8 @@ same products (a column of ones beside v, `_beside`), so they see the
 same rounded weights and no expansion is read a second time for its
 normaliser. The chunks are a `lax.scan` whose body is rematerialised, and
 the state heads a `lax.map` around it, so a layer's backward holds one
-carried state a chunk and one state head's expansion of one chunk, never
-all of them (phi(Q) of 40 heads x 1,024 rows is 0.68 GB in bf16). Decays
+carried state a chunk and one state head's chunk, never all of them
+(phi(Q) of 40 heads x 1,024 rows would be 0.68 GB in bf16). Decays
 are summed and exponentiated in float32 whatever the operands' type; the
 matmuls take the operands' type (bf16 on the chip) and accumulate in
 float32.
@@ -35,15 +35,24 @@ Rotation r and rotation d - r hold the same unordered pairs, so the full
 square sum_{a,b} q_a q_b k_a k_b is rotation 0, twice each of 1 .. d/2 - 1,
 and rotation d / 2 once (it holds every one of its pairs twice already).
 That is 65 x 128 = 8,320 features at d = 128 for the 8,256 minimal ones
-(64 duplicates), and the product is the same. Both factors of every
-feature are made by a product with a 0/1 matrix (`_selectors`), so an
-expansion is (rows, features) from the start. The weights and the 1 / d
+(64 duplicates), and the product is the same. The weights and the 1 / d
 scale sit on the QUERY side (w = 1/d, 2/d .. 2/d, 1/d) and the key side is
 bare u_i u_{i+r}: at a d that is a power of two neither costs a rounding,
 and the carried state is sqrt(d) x the symmetric convention's, which no
 output sees.
 
-No Pallas kernel here: every product is an einsum XLA lowers to the MXU.
+**Who makes the expansion.** The input's shape decides, nothing else
+(`_in_vmem`). At a head_dim that is a multiple of 128 a rotation is a
+rotation of whole lane registers, and the two products that have an
+expansion as an operand, the state read by the queries and the state's
+update from the keys, are the Pallas kernels of ops/pallas/
+power_retention.py (compiled on a TPU, interpreted elsewhere): they make
+each rotation in VMEM beside the MXU, round it where `_expand` rounds, and
+no (rows, features) array reaches memory, forward or backward. At any
+other head_dim (the tiny models') `expand_queries` / `expand_keys` build
+the expansion in jax.numpy, both factors of every feature by a product
+with a 0/1 matrix (`_selectors`), and the products are einsums. The two
+paths round at the same points: they differ by the order of float32 sums.
 """
 
 from __future__ import annotations
@@ -53,6 +62,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from .pallas import power_retention as _kernels
 
 __all__ = ["power_retention", "expand_queries", "expand_keys",
            "retention_features"]
@@ -125,6 +136,29 @@ def _beside(a, column):
         [a, jnp.zeros_like(a).at[:, 0].set(column.astype(a.dtype))], axis=-1)
 
 
+def _in_vmem(d):
+    """Whether the expansions are made in the kernels of
+    ops/pallas/power_retention.py: a rotation is a rotation of whole lane
+    registers there, which it is at a multiple of 128 and at no other d."""
+    return d % 128 == 0
+
+
+def _read_state(rows, carried):
+    """phi_q(rows) @ carried: (n, d), (features, e) -> (n, e) float32."""
+    if _in_vmem(rows.shape[-1]):
+        return _kernels.phi_dot(rows, carried, True)
+    return jnp.einsum("nf,fe->ne", expand_queries(rows), carried,
+                      preferred_element_type=_F32)
+
+
+def _write_state(k, vw):
+    """phi_k(k)^T @ vw: (c, d), (c, e) -> (features, e) float32."""
+    if _in_vmem(k.shape[-1]):
+        return _kernels.phi_t_dot(k, vw, False)
+    return jnp.einsum("sf,se->fe", expand_keys(k), vw,
+                      preferred_element_type=_F32)
+
+
 def _chunk_step(carry, inputs, eps):
     """One chunk of one state head. carry: S (features, d), z (features,),
     float32. inputs: q (r, c, d) for the group's r query heads, k, v
@@ -149,15 +183,12 @@ def _chunk_step(carry, inputs, eps):
     # what the state carried into the chunk still gives position t
     carried = _beside(state, norm)
     into = jnp.tile(jnp.exp(cum), rep)[:, None]
-    both = both + into * jnp.einsum(
-        "nf,fe->ne", expand_queries(rows), carried.astype(dtype),
-        preferred_element_type=_F32)
+    both = both + into * _read_state(rows, carried.astype(dtype))
     y = (both[:, :d] / (both[:, d:d + 1] + eps)).astype(dtype)
     # the state after the chunk
     to_end = jnp.exp(cum[-1] - cum)                      # (c,)
     vw = (va.astype(_F32) * to_end[:, None]).astype(dtype)
-    added = jnp.einsum("sf,se->fe", expand_keys(k), vw,
-                       preferred_element_type=_F32)
+    added = _write_state(k, vw)
     last = jnp.exp(cum[-1])
     return ((state * last + added[:, :d], norm * last + added[:, d]),
             y.reshape(rep, length, d))

@@ -202,6 +202,78 @@ def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(one_chip):
             # of moe_time_share's pattern would count the kernel twice
 
 
+@pytest.mark.parametrize("rows, weighted", [(5120, True), (1024, False)],
+                         ids=["query_side_5120_rows", "key_side_1024_rows"])
+def test_retention_kernels_compile_at_the_cell_s_shapes(one_chip, rows,
+                                                        weighted):
+    """ops/pallas/power_retention.py at the Brumby cell's shapes: a chunk's
+    1,024 key rows or its 5 x 1,024 query rows of 128 lanes against a
+    state of 65 rotations x 128 x 256 (the normaliser's column beside the
+    128 of v), bf16. What the interpreter cannot refuse and Mosaic can: a
+    lane rotation by the loop's index, `m_ref[r]`, a product that
+    contracts the rows of both operands, a whole float32 state
+    accumulated over the row grid with the VMEM limit raised."""
+    from paddle_tpu.ops.pallas import power_retention as kernels
+    d, e = 128, 256
+    features = (d // 2 + 1) * d
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    u, m, w = sds(rows, d), sds(features, e), sds(rows, e)
+    for name, fn, args in (
+            ("retn_read", kernels.read, (u, m)),
+            ("retn_write", kernels.write, (u, w)),
+            ("retn_back", kernels.back, (u, w, m))):
+        text = jax.jit(functools.partial(
+            fn, weighted=weighted, interpret=False)).lower(
+                *args).compile().as_text()
+        assert "tpu_custom_call" in text and name in text, name
+
+
+def test_retention_layer_compiles_and_no_expansion_reaches_memory(
+        one_chip, monkeypatch):
+    """One layer's `power_retention` of the Brumby cell (16,384 positions,
+    40 query heads over 8 state heads, d 128, bf16, chunks of 1,024),
+    forward and backward with the chunk's recomputation, for a described
+    v5e. The compiled text names the three kernels as the catalog does,
+    each under the scan's scope in the forward, the recomputation and the
+    backward, and holds no array of an expansion's shape: neither the
+    query side's bf16[5120,8320] nor the key side's bf16[1024,8320]."""
+    from paddle_tpu.observability.catalog import KERNEL_NAMES
+    from paddle_tpu.ops.pallas import power_retention as kernels
+    from paddle_tpu.ops.power_retention import power_retention
+    # the described chip is not jax.default_backend(): the kernels are
+    # asked for compiled here, where the program would interpret them
+    monkeypatch.setattr(kernels, "_interpret_default", lambda: False)
+    seq, heads, groups, d = 16384, 40, 8, 128
+
+    def sds(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def loss(q, k, v, log_g):
+        return jnp.sum(power_retention(q, k, v, log_g, 1024).astype(
+            jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
+        sds(1, seq, heads, d), sds(1, seq, groups, d), sds(1, seq, groups, d),
+        sds(1, seq, groups, dt=jnp.float32)).compile().as_text()
+    assert not re.search(r"\[(?:5120|1024),8320\]", text)
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    names = collections.Counter()
+    for line in calls:
+        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+        assert "pt.retn.scan" in op_name, op_name
+        names[op_name.split("/")[-2]] += 1
+    assert set(names) == {n for n in KERNEL_NAMES if n.startswith("retn_")}
+    # forward: read, write. Recomputed: read (the recomputed write feeds
+    # nothing). Backward: back twice, write for the state's cotangent,
+    # read for vw's
+    assert names == {"retn_read": 3, "retn_write": 2, "retn_back": 2}
+    assert any("transpose(jvp(pt.retn.scan))" in line for line in calls)
+
+
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$")
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?[\w.\-]+ = (.+?) ([\w\-]+)\(")
 _CALLED = re.compile(r"(?:calls|to_apply|body|condition)=%?([\w.\-]+)")

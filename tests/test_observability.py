@@ -280,6 +280,16 @@ class TestCatalog:
         # float32's 1 - g at g = 1 - 1/1024: 1e-4 of the horizon
         assert abs(r.get(name).value - 544.0) < 0.1
 
+    @pytest.mark.parametrize("name", sorted(obs_catalog.KERNEL_NAMES))
+    def test_kernel_names_have_their_row_in_the_docs(self, name):
+        """Every `name=` of a Pallas kernel, the flash kernels' and the
+        power retention's `retn_*`, has a `kernel/NAME` row in
+        OBSERVABILITY.md beside the scopes: a trace's reader finds the
+        kernel by that name."""
+        text = open(os.path.join(REPO, "OBSERVABILITY.md")).read()
+        assert re.search(rf"^\| `kernel/{name}` \| \S", text, re.MULTILINE)
+        assert name.startswith(("fa_", "retn_"))
+
     def test_metric_refuses_unknown_names(self):
         with pytest.raises(KeyError, match="catalog"):
             obs.metric("not_a_registered_name_total")

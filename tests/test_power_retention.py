@@ -3,7 +3,12 @@ its carried state against the quadratic form (the `a_ts` matrix of the
 published description) and against the token-by-token recurrence with the
 MINIMAL symmetric expansion, both written here in float64 numpy and
 sharing nothing with the op; values and gradients; chunks that do and do
-not divide the length; five query heads a state head.
+not divide the length; five query heads a state head. At head_dim 128 the
+products with an expansion are the kernels of ops/pallas/power_retention.py
+(under the Pallas interpreter here): each against the jax.numpy expansion,
+their two differentiable operations against `jax.vjp` of the einsum form,
+and the whole op against the same two float64 forms; at any other
+head_dim the jax.numpy expansion stays.
 
 Each tolerance has its reason beside it.
 """
@@ -13,6 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paddle_tpu.ops import power_retention as op
+from paddle_tpu.ops.pallas import power_retention as kernels
 from paddle_tpu.ops.power_retention import (expand_keys, expand_queries,
                                             power_retention,
                                             retention_features)
@@ -112,11 +119,19 @@ def test_the_expansion_gives_the_squared_scaled_product():
         expand_keys(np.ones((3, 7), np.float32))
 
 
-@pytest.mark.parametrize("seq, chunk", [(32, 8), (29, 8), (5, 8), (29, 29)],
-                         ids=["whole_chunks", "ragged_tail", "under_a_chunk",
-                              "one_chunk"])
-def test_chunked_is_the_quadratic_form_is_the_recurrence(seq, chunk):
-    q, k, v, log_g = _draw(seq, s=seq)
+# head_dim 128: the expansions are made in the kernels; one batch row, one
+# state head with its five query heads (the recurrence holds 8,256 x 128)
+_D128 = dict(b=1, heads=5, groups=1, d=128)
+
+
+@pytest.mark.parametrize(
+    "seq, chunk, shape",
+    [(32, 8, {}), (29, 8, {}), (5, 8, {}), (29, 29, {}), (40, 16, _D128),
+     (21, 16, _D128)],
+    ids=["whole_chunks", "ragged_tail", "under_a_chunk", "one_chunk",
+         "head_dim_128_kernels", "head_dim_128_kernels_ragged_tail"])
+def test_chunked_is_the_quadratic_form_is_the_recurrence(seq, chunk, shape):
+    q, k, v, log_g = _draw(seq, s=seq, **shape)
     quad = _quadratic(q, k, v, log_g)
     rec = _recurrence(q, k, v, log_g)
     # float64 both: the two published forms are one function
@@ -144,10 +159,11 @@ def _ref_jnp(q, k, v, log_g):
         / (a.sum(2)[..., None] + EPS)
 
 
-@pytest.mark.parametrize("seq, chunk", [(24, 8), (21, 8)],
-                         ids=["whole_chunks", "ragged_tail"])
-def test_gradients_match_the_quadratic_forms(seq, chunk):
-    q, k, v, log_g = _draw(100 + seq, b=1, s=seq)
+@pytest.mark.parametrize(
+    "seq, chunk, shape", [(24, 8, {}), (21, 8, {}), (40, 16, _D128)],
+    ids=["whole_chunks", "ragged_tail", "head_dim_128_kernels"])
+def test_gradients_match_the_quadratic_forms(seq, chunk, shape):
+    q, k, v, log_g = _draw(100 + seq, s=seq, **{"b": 1, **shape})
     w = np.random.RandomState(5).randn(*q.shape)
 
     def loss(fn):
@@ -213,3 +229,184 @@ def test_bf16_operands_keep_the_decays_in_float32():
     # expansions, weights and the carried state each round once to 8 bits
     # (2^-9 = 0.2% an entry); measured 0.24%
     assert err < 0.01
+
+
+# -- the kernels of ops/pallas/power_retention.py, under the interpreter ------
+
+def _operands(seed, n, e, dtype, d=128):
+    """u (n, d), m (features, e), w and dy (n, e) in `dtype`."""
+    rs = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rs.randn(*shape), dtype) for shape in
+                 ((n, d), (retention_features(d), e), (n, e), (n, e)))
+
+
+def _rel(got, want):
+    got, want = (np.asarray(t, np.float64) for t in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """32 rows a grid step, so that 96 rows are three whole steps and 100
+    rows three and a padded fourth."""
+    monkeypatch.setattr(kernels, "_ROWS", 32)
+
+
+_SIDES = {"query_side": (True, expand_queries), "key_side": (False, expand_keys)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("n", [96, 100], ids=["whole_tiles", "padded_tile"])
+@pytest.mark.parametrize("side", list(_SIDES))
+@pytest.mark.parametrize("kernel", ["read", "write", "back"])
+def test_each_kernel_is_the_product_with_the_expansion(small_tiles, kernel,
+                                                       side, n, dtype):
+    weighted, expand = _SIDES[side]
+    u, m, w, dy = _operands(n, n, 256, dtype)
+    with jax.default_matmul_precision("highest"):
+        if kernel == "read":
+            got = kernels.read(u, m, weighted)
+            want = jnp.einsum("nf,fe->ne", expand(u), m,
+                              preferred_element_type=jnp.float32)
+        elif kernel == "write":
+            got = kernels.write(u, w, weighted)
+            want = jnp.einsum("nf,ne->fe", expand(u), w,
+                              preferred_element_type=jnp.float32)
+        else:
+            got = kernels.back(u, dy, m, weighted)
+            want = jax.vjp(lambda t: jnp.einsum(
+                "nf,fe->ne", expand(t), m,
+                preferred_element_type=jnp.float32), u)[1](
+                    dy.astype(jnp.float32))[0]
+    assert got.shape == want.shape and got.dtype == want.dtype
+    if kernel != "back":
+        # the same expansion rounded at the same point, the same products
+        # summed in float32 in another order
+        assert _rel(got, want) <= 1e-6
+    elif dtype == jnp.float32:
+        assert _rel(got, want) <= 1e-6
+    else:
+        # jax.vjp rounds the expansion's cotangent and each factor's to
+        # bf16 on the way back (2^-9 an entry each); the kernel keeps them
+        # in float32 and rounds du once
+        assert _rel(got, want) <= 8e-3
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("side", list(_SIDES))
+@pytest.mark.parametrize("operation", ["phi_dot", "phi_t_dot"])
+def test_the_differentiable_operations_are_the_einsum_forms(small_tiles,
+                                                            operation, side,
+                                                            dtype):
+    """Values and the cotangents of BOTH operands against jax.vjp of the
+    einsum over the jax.numpy expansion, 100 rows (a padded tile)."""
+    weighted, expand = _SIDES[side]
+    u, m, w, dy = _operands(7, 100, 128, dtype)
+    if operation == "phi_dot":
+        second, spec = m, "nf,fe->ne"
+        fn = lambda a, b: kernels.phi_dot(a, b, weighted)
+        ct = dy.astype(jnp.float32)
+    else:
+        second, spec = w, "nf,ne->fe"
+        fn = lambda a, b: kernels.phi_t_dot(a, b, weighted)
+        ct = jnp.asarray(np.random.RandomState(8).randn(
+            retention_features(128), 128), jnp.float32)
+
+    def ref(a, b):
+        return jnp.einsum(spec, expand(a), b,
+                          preferred_element_type=jnp.float32)
+
+    with jax.default_matmul_precision("highest"):
+        got, got_vjp = jax.vjp(fn, u, second)
+        want, want_vjp = jax.vjp(ref, u, second)
+        got_ct, want_ct = got_vjp(ct), want_vjp(ct)
+    assert _rel(got, want) <= 1e-6
+    for name, g, r in zip(("u", "second"), got_ct, want_ct):
+        assert g.shape == r.shape and g.dtype == r.dtype, name
+        # float32: the same sums in another order. bf16: the einsum form
+        # rounds the cotangent it multiplies to bf16 as the kernels do,
+        # and phi's own cotangent and each factor's besides (2^-9 an entry
+        # each; measured 0.25-0.41%)
+        assert _rel(g, r) <= (1e-6 if dtype == jnp.float32 else 8e-3), name
+
+
+@pytest.mark.parametrize("group", [1, 4, 65], ids=["one_rotation_a_step",
+                                                    "a_rotation_left_over",
+                                                    "all_at_once"])
+@pytest.mark.parametrize("kernel", ["read", "write", "back"])
+def test_any_group_of_rotations_is_the_same_product(small_tiles, monkeypatch,
+                                                    kernel, group):
+    """`_GROUP` rotations go through the MXU at once; 65 = 16 x 4 + 1, so
+    a group of 4 leaves one rotation for the step after the loop."""
+    u, m, w, dy = _operands(3, 40, 128, jnp.float32)
+    args = {"read": (u, m), "write": (u, w), "back": (u, dy, m)}[kernel]
+    fn = getattr(kernels, kernel)
+    with jax.default_matmul_precision("highest"):
+        want = fn(*args, True)
+        monkeypatch.setattr(kernels, "_GROUP", group)
+        got = fn(*args, True)
+    assert _rel(got, want) <= 1e-6
+
+
+@pytest.mark.parametrize("n, tile", [(5120, 2560), (1024, 1024), (6144, 2048),
+                                     (100, 112), (2561, 1296)])
+def test_row_tiles_spread_the_rows_evenly(n, tile):
+    """The fewest grid steps of at most 2,560 rows, the rows spread evenly
+    over them in multiples of 16: the Brumby cell's 5,120 query rows are
+    two steps and its 1,024 key rows one, and an odd count pads a few rows,
+    not most of a tile."""
+    assert kernels._row_tile(n) == tile
+    assert tile % 16 == 0 and tile <= kernels._ROWS
+    assert -(-n // tile) == -(-n // kernels._ROWS)
+
+
+def test_kernels_refuse_what_is_no_lane_rotation():
+    u, m, _, _ = _operands(0, 16, 128, jnp.float32, d=64)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        kernels.read(u, m, True)
+
+
+@pytest.mark.parametrize("d, in_kernels", [(16, False), (128, True)],
+                         ids=["head_dim_16_jax_numpy", "head_dim_128_kernels"])
+def test_the_head_dim_alone_chooses_the_path(d, in_kernels):
+    """head_dim % 128 decides, and nothing else: the traced op holds the
+    three kernels (forward and backward) or none, and either way it is the
+    quadratic form."""
+    q, k, v, log_g = _draw(11, b=1, s=24, heads=5, groups=1, d=d)
+
+    def loss(*a):
+        return jnp.sum(power_retention(*a, 8, EPS))
+
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(q, k, v, log_g))
+    for name in ("retn_read", "retn_write", "retn_back"):
+        assert (name in text) == in_kernels, name
+    assert op._in_vmem(d) == in_kernels
+    got = np.asarray(_op(8)(q, k, v, log_g))
+    quad = _quadratic(q, k, v, log_g)
+    assert np.abs(got - quad).max() <= 2e-5 * np.abs(quad).max()
+
+
+def test_bf16_kernels_round_where_the_expansion_rounds():
+    """bf16 at head_dim 128, four chunks, five query heads a state head:
+    the op through the kernels against the op through the jax.numpy
+    expansion (the path every other head_dim takes), which rounds at the
+    same points: they differ by the order of float32 sums alone."""
+    q, k, v, log_g = _draw(4, b=1, s=64, heads=5, groups=1, d=128,
+                           horizon=(16.0, 4096.0))
+    qb, kb, vb = (jnp.asarray(t, jnp.bfloat16) for t in (q, k, v))
+    run = jax.jit(lambda *a: power_retention(*a, 16, EPS))
+    got = run(qb, kb, vb, log_g)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(op, "_in_vmem", lambda d: False)
+        plain = jax.jit(lambda *a: power_retention(*a, 16, EPS))(
+            qb, kb, vb, log_g)
+    assert got.dtype == jnp.bfloat16
+    # one bf16 rounding of y where a float32 sum fell on the other side
+    # of a rounding boundary: 2^-8 of an entry at most, and few of them
+    assert _rel(got, plain) <= 1e-3
+    want = _quadratic(*(np.asarray(t, np.float32) for t in (qb, kb, vb)),
+                      log_g)
+    # as test_bf16_operands_keep_the_decays_in_float32: measured 0.24%
+    assert _rel(got, want) < 0.01

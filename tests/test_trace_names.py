@@ -401,7 +401,52 @@ def test_flash_kernels_carry_their_names():
                         walk(sub)
 
     walk(jaxpr.jaxpr)
-    assert sorted(set(names)) == sorted(KERNEL_NAMES)
+    assert sorted(set(names)) == sorted(
+        n for n in KERNEL_NAMES if n.startswith("fa_"))
+
+
+def test_retention_kernels_sit_under_the_scan_s_scope_both_ways():
+    """At head_dim 128 the retention's products with an expansion are the
+    `retn_*` kernels (ops/pallas/power_retention.py). In the lowered train
+    step every one of their operations, the forward's, the recomputation's
+    and the backward's (whose calls are traced when the scan is
+    transposed, outside the forward's scope, and enter it themselves),
+    is under pt.retn/pt.retn.scan, so the kernels' time on a trace stays
+    in `retention_scan_time_share` and out of `unnamed_op_time_share`."""
+    from paddle_tpu.framework.random import get_rng_state
+    from paddle_tpu.models.brumby import brumby_tiny
+    from paddle_tpu.parallel import DP_ONLY_RULES
+    paddle.seed(0)
+    model = brumby_tiny(num_hidden_layers=1, num_attention_heads=2,
+                        num_key_value_heads=1, head_dim=128)
+    opt = optimizer.AdamW(1e-3, parameters=model.parameters())
+    trainer = SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
+                          DP_ONLY_RULES)
+    ids = np.zeros((1, 16), np.int32)
+    batch = trainer._batch_arrays((ids, ids))
+    with jax.set_mesh(trainer.mesh):
+        # compiled, not lowered: a lowered operation inside the scan's body
+        # carries the body's own name stack, an optimised one's op_name
+        # the whole path, as a device trace's HloProto does
+        text = trainer._compiled.lower(
+            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
+            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
+        ).compile().as_text()
+    op_names = set(re.findall(r'op_name="([^"]*)"', text))
+    retention = sorted(n for n in KERNEL_NAMES if n.startswith("retn_"))
+    assert retention == ["retn_back", "retn_read", "retn_write"]
+    mixer = re.compile(r"\bpt\.retn\b")
+    scan = re.compile(r"\bpt\.retn\.scan\b")
+    for name in retention:
+        hits = [n for n in op_names if f"/{name}/" in n]
+        assert hits, name
+        for h in hits:
+            assert scan.search(h) and mixer.search(
+                h[:scan.search(h).start()]), h
+        # retn_back runs in the backward alone; the other two in both
+        assert any("transpose(jvp(" in h for h in hits), name
+        if name != "retn_back":
+            assert any("transpose(jvp(" not in h for h in hits), name
 
 
 def test_scope_names_stay_clear_of_effect_scopes():

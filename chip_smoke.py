@@ -765,11 +765,16 @@ def main(argv=None):
         gc.collect()
         log(f"allocator peak of the process after phase {name}: "
             f"{_gib(_peak_bytes(device))}")
-    from paddle_tpu.ops.pallas.attention_router import decision_log
+    from paddle_tpu.ops.pallas.attention_router import decision_log, route
+    # the two attention shapes of the cell with window and full layers
+    # (benchmark/configs/mellum2-12b-a2.5b-d8.json): 2 x 32 heads x 16,384
+    for window in (1024, None):
+        route(64, 16384, 16384, 128, "bfloat16", True, window=window)
     for key, dec in decision_log():
-        log(f"attention decision (bh, sq, sk, d, dtype, causal)={key}: "
-            f"fwd={dec.fwd} bwd={dec.bwd} why={dec.why!r} "
-            f"grid steps {dec.grid_steps}")
+        log(f"attention decision (bh, sq, sk, d, dtype, causal, window)="
+            f"{key}: fwd={dec.fwd} bwd={dec.bwd} why={dec.why!r} "
+            f"grid steps {dec.grid_steps} visited/needed pairs "
+            f"{dec.visited_pair_share}")
     _cache_record(cache_dir, meter, "four-chip" if four_chip else "one-chip")
     log(f"total {time.perf_counter() - t_all:.1f}s")
     out_dir = os.path.join(REPO, "chiprun_out")

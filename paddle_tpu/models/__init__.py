@@ -11,3 +11,5 @@ from .granite_moe_hybrid import (GraniteMoeHybridConfig,  # noqa: F401
                                  granite_hybrid_tiny)
 from .brumby import (BrumbyConfig, BrumbyModel, BrumbyForCausalLM,  # noqa: F401
                      brumby_tiny)
+from .mellum import (MellumConfig, MellumModel, MellumForCausalLM,  # noqa: F401
+                     mellum_tiny)

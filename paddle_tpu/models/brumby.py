@@ -21,11 +21,9 @@ head from a linear map of the layer's normed input, with a bias), the
 1 / sqrt(d) scale inside the power, eps in the normaliser. The chunk size
 is the program's choice, not the model's.
 
-RoPE's sin and cos are float32 and the rotation is made in float32, then
-rounded once: incubate's fused_rotary_position_embedding rounds the tables
-to the operand's type first, which at 16k positions in bf16 is a second
-rounding of every rotated entry (tests/test_brumby.py holds both to a
-float64 rotation).
+RoPE is the default kind of models/rope.py: sin and cos are float32 and
+the rotation is made in float32, then rounded once (tests/test_brumby.py
+holds it to a float64 rotation).
 
 Memory: each mixer, the FFN of each block of FFN_TOKEN_BLOCK tokens and
 the head with its loss over each block of LOSS_TOKEN_BLOCK tokens are
@@ -49,7 +47,8 @@ from ..generation import _rms
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..ops.power_retention import power_retention
-from .sub_block import Params, SubBlock, over_token_blocks
+from .rope import apply_rope, rope_frequencies
+from .sub_block import Params, SubBlock, blocked_lm_loss, over_token_blocks
 
 __all__ = ["BrumbyConfig", "BrumbyModel", "BrumbyForCausalLM", "brumby_tiny",
            "retention_log_gate"]
@@ -97,16 +96,9 @@ class BrumbyConfig:
 
 
 def _rope(x, theta):
-    """Rotate-half RoPE at positions 0..T-1 of x (batch, T, heads, d): the
-    tables and the rotation in float32, one rounding to x's type."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    freqs = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv
-    emb = jnp.concatenate([freqs, freqs], axis=-1)[None, :, None, :]
-    x32 = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x32, 2, axis=-1)
-    rot = jnp.concatenate([-x2, x1], axis=-1)
-    return (x32 * jnp.cos(emb) + rot * jnp.sin(emb)).astype(x.dtype)
+    """Default-kind rotate-half RoPE at positions 0..T-1 (models/rope.py)."""
+    return apply_rope(x, *rope_frequencies(
+        {"rope_type": "default", "rope_theta": theta}, x.shape[-1]))
 
 
 def retention_log_gate(h, norm_weight, gate_weight, gate_bias, eps):
@@ -221,38 +213,6 @@ class BrumbyModel(nn.Layer):
         return hidden
 
 
-def _blocked_lm_loss(h, norm_w, head_w, labels, eps, block):
-    """Mean next-token cross entropy of (batch, T, hidden) against labels
-    (batch, T): final norm, head and log-softmax over `block` tokens at a
-    time, each block rematerialised in the backward. Every position is a
-    row, so that the blocks are even; a sequence's last position, which
-    predicts nothing, carries weight 0."""
-    b, s, d = h.shape
-    rows = h.reshape(b * s, d)
-    targets = jnp.roll(labels, -1, axis=1).reshape(b * s)
-    counted = (jnp.arange(b * s) % s != s - 1)
-
-    @jax.checkpoint
-    def block_sum(x, tgt, on):
-        with jax.named_scope("pt.head"):
-            logits = jnp.dot(_rms(x, norm_w, eps), head_w,
-                             preferred_element_type=jnp.float32)
-        with jax.named_scope("pt.loss"):
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            own = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
-            return jnp.sum(jnp.where(on, lse - own, 0.0))
-
-    n = b * s // block
-    if n < 2 or b * s % block:
-        total = block_sum(rows, targets, counted)
-    else:
-        total = jnp.sum(jax.lax.map(
-            lambda t: block_sum(*t),
-            (rows.reshape(n, block, d), targets.reshape(n, block),
-             counted.reshape(n, block))))
-    return total / (b * (s - 1))
-
-
 class BrumbyForCausalLM(nn.Layer):
     def __init__(self, config: BrumbyConfig):
         super().__init__()
@@ -267,7 +227,7 @@ class BrumbyForCausalLM(nn.Layer):
         hidden = self.model(input_ids)
         if labels is not None:
             return execute(
-                lambda h, nw, hw, lab: _blocked_lm_loss(
+                lambda h, nw, hw, lab: blocked_lm_loss(
                     h, nw, hw, lab, c.rms_norm_eps, LOSS_TOKEN_BLOCK),
                 hidden, self.model.norm.weight, self.lm_head.weight, labels,
                 _name="BrumbyHeadLoss")

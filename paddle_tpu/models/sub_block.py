@@ -10,12 +10,14 @@ parameter) rests on each gradient dying where it is made.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 
 from .. import nn
 from ..framework.core import execute
 from ..framework.param_attr import ParamAttr
+from ..generation import _rms
 
-__all__ = ["Params", "SubBlock", "over_token_blocks"]
+__all__ = ["Params", "SubBlock", "over_token_blocks", "blocked_lm_loss"]
 
 
 class Params(nn.Layer):
@@ -62,3 +64,35 @@ def over_token_blocks(block, h, size):
         return block(h)
     blocks = h.reshape(n, 1, size, d)
     return jax.lax.map(block, blocks).reshape(b, s, d)
+
+
+def blocked_lm_loss(h, norm_w, head_w, labels, eps, block):
+    """Mean next-token cross entropy of (batch, T, hidden) against labels
+    (batch, T): final norm, head and log-softmax over `block` tokens at a
+    time, each block rematerialised in the backward. Every position is a
+    row, so that the blocks are even; a sequence's last position, which
+    predicts nothing, carries weight 0."""
+    b, s, d = h.shape
+    rows = h.reshape(b * s, d)
+    targets = jnp.roll(labels, -1, axis=1).reshape(b * s)
+    counted = (jnp.arange(b * s) % s != s - 1)
+
+    @jax.checkpoint
+    def block_sum(x, tgt, on):
+        with jax.named_scope("pt.head"):
+            logits = jnp.dot(_rms(x, norm_w, eps), head_w,
+                             preferred_element_type=jnp.float32)
+        with jax.named_scope("pt.loss"):
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            own = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]
+            return jnp.sum(jnp.where(on, lse - own, 0.0))
+
+    n = b * s // block
+    if n < 2 or b * s % block:
+        total = block_sum(rows, targets, counted)
+    else:
+        total = jnp.sum(jax.lax.map(
+            lambda t: block_sum(*t),
+            (rows.reshape(n, block, d), targets.reshape(n, block),
+             counted.reshape(n, block))))
+    return total / (b * (s - 1))

@@ -25,7 +25,7 @@ __all__ = ["scaled_dot_product_attention", "flash_attention",
 
 
 def _xla_attention(q, k, v, bias=None, causal=False, scale=None, dropout_p=0.0,
-                   dropout_key=None):
+                   dropout_key=None, window=None):
     # q,k,v: (batch, seq, heads, head_dim) — paddle flash_attention layout
     hd = q.shape[-1]
     s = scale if scale is not None else 1.0 / (hd ** 0.5)
@@ -35,7 +35,11 @@ def _xla_attention(q, k, v, bias=None, causal=False, scale=None, dropout_p=0.0,
         logits = logits + bias.astype(logits.dtype)
     if causal:
         ql, kl = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((ql, kl), dtype=jnp.bool_), k=kl - ql)
+        if window is None:
+            mask = jnp.tril(jnp.ones((ql, kl), dtype=jnp.bool_), k=kl - ql)
+        else:
+            from ...ops.pallas.flash_attention import band_mask
+            mask = band_mask(ql, kl, window)
         logits = jnp.where(mask, logits, jnp.float32(-1e30))
     probs = jax.nn.softmax(logits, axis=-1)
     if dropout_p > 0.0 and dropout_key is not None:
@@ -63,7 +67,7 @@ def _expand_kv(k, v, num_heads):
 
 
 def _use_pallas(q_shape, head_dim, has_bias, dtype=None, causal=True,
-                seq_k=None):
+                seq_k=None, window=None):
     if has_bias:
         # the pallas kernel takes no bias/mask — never select it silently
         return False
@@ -76,21 +80,28 @@ def _use_pallas(q_shape, head_dim, has_bias, dtype=None, causal=True,
     heads = q_shape[2] if len(q_shape) > 3 else 1
     return route(b * heads, seq, seq if seq_k is None else seq_k, head_dim,
                  dtype if dtype is not None else "bfloat16",
-                 causal).fwd == "pallas"
+                 causal, window=window).fwd == "pallas"
 
 
-def attention_bshd(q, k, v, is_causal=True, scale=None):
+def attention_bshd(q, k, v, is_causal=True, scale=None, window=None):
     """Attention on raw arrays, (batch, seq, heads, head_dim) with
     GQA-native k/v, for code that is already a pure jax function (a
     rematerialised sub-block): the flash kernels or dense XLA attention,
     chosen exactly as scaled_dot_product_attention chooses. scale: the
-    softmax scale (default 1/sqrt(head_dim))."""
+    softmax scale (default 1/sqrt(head_dim)). window (with is_causal): a
+    query sees `window` keys, its own included; the kernels skip what the
+    band hides and the dense path masks it."""
+    if window is not None and not is_causal:
+        raise ValueError("a window is a band under the causal diagonal: "
+                         "is_causal=True")
     if _use_pallas(tuple(q.shape), q.shape[-1], False, dtype=q.dtype,
-                   causal=is_causal, seq_k=k.shape[1]):
+                   causal=is_causal, seq_k=k.shape[1], window=window):
         from ...ops.pallas.flash_attention import flash_attention_bshd
-        return flash_attention_bshd(q, k, v, causal=is_causal, scale=scale)
+        return flash_attention_bshd(q, k, v, causal=is_causal, scale=scale,
+                                    window=window)
     k, v = _expand_kv(k, v, q.shape[2])
-    return _xla_attention(q, k, v, causal=is_causal, scale=scale)
+    return _xla_attention(q, k, v, causal=is_causal, scale=scale,
+                          window=window)
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,

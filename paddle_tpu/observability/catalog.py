@@ -271,6 +271,13 @@ CATALOG = {
         "gauge", "largest / mean number of assignments over the held "
         "experts, same sequence, the layers' mean (1.0 = even load)", (),
         None),
+    "attn_window_visited_pair_share": (
+        "gauge", "query-key pairs the three windowed flash kernels' sweeps "
+        "visit at sub-block granularity / three times the pairs the band "
+        "leaves, at the window layers' shape (ops/pallas/attention_router "
+        "Decision.visited_pair_share; 1.0 at best); a count from the "
+        "tiles, set by whoever builds the model, outside the step", (),
+        None),
     "retention_mean_horizon_tokens": (
         "gauge", "mean over layers and state heads of 1 / (1 - mean_t g_t), "
         "the tokens a power-retention state remembers, from the model's "
@@ -525,6 +532,12 @@ TRACE_SCOPES = {
     "pt.embed": "token (+ position) embedding lookup",
     "pt.attn": "attention sub-block: norm, QKV projections, rope, the "
                "attention kernel or paged attention, output projection",
+    "pt.attn.sliding": "inside pt.attn, a window layer's whole mixer "
+                       "(models/mellum.py sliding_attention: default RoPE, "
+                       "the faw_* kernels)",
+    "pt.attn.full": "inside pt.attn, a full-attention layer's whole mixer "
+                    "in a model that mixes kinds (models/mellum.py "
+                    "full_attention: YaRN RoPE, the fa_* kernels)",
     "pt.mlp": "MLP sub-block with its norm (in an expert layer: the norm, "
               "the shared expert and the residual)",
     "pt.ssm": "state-space (Mamba-2) mixer: norm, in/out projections, "
@@ -557,6 +570,10 @@ KERNEL_NAMES = {
     "fa_fwd": "flash attention forward (+ fused RMS epilogue)",
     "fa_bwd_dq": "flash attention backward, dQ",
     "fa_bwd_dkv": "flash attention backward, dK and dV",
+    "faw_fwd": "flash attention forward under a window that hides "
+               "something: the band's sub-blocks alone",
+    "faw_bwd_dq": "windowed flash attention backward, dQ",
+    "faw_bwd_dkv": "windowed flash attention backward, dK and dV",
     "retn_read": "power retention: phi(u) @ M, the expansion made a "
                  "rotation at a time in VMEM (the state read; in the "
                  "backward, the cotangent of what was written)",

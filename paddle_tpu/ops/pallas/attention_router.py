@@ -23,13 +23,18 @@ class Decision:
     kernels take at this shape and grid_steps: each kernel's grid steps
     in one call, by kernel name, from flash_attention.tiles_for_shape,
     the resolver the kernels' own entry points use; there whichever way
-    the choice went. why: the line of the rule that decided."""
+    the choice went. why: the line of the rule that decided.
+    visited_pair_share: query-key pairs the three kernels' sweeps visit at
+    sub-block granularity (Tiles.visited_pairs, summed) over three times
+    the pairs the mask leaves (flash_attention.band_pairs); 1.0 at best,
+    None for a call that is not causal (nothing is skipped there)."""
 
     fwd: str
     bwd: str
     tiles: Any
     grid_steps: dict
     why: str
+    visited_pair_share: Optional[float] = None
 
 
 # The one constant of the rule, from tools/flash_vs_xla.py on a TPU v5e
@@ -53,24 +58,32 @@ def clear_routing_cache():
 
 
 def decision_log():
-    """[((batch_heads, seq_q, seq_k, head_dim, dtype, causal), Decision)]
-    for every distinct shape this process asked about."""
-    return [(key[:6], dec) for key, dec in _decisions.items()]
+    """[((batch_heads, seq_q, seq_k, head_dim, dtype, causal, window),
+    Decision)] for every distinct shape this process asked about; window
+    is None where there is none or it hides nothing at the shape."""
+    return [(key[:7], dec) for key, dec in _decisions.items()]
 
 
 def route(batch_heads: int, seq_q: int, seq_k: int, head_dim: int, dtype,
-          causal: bool, platform: Optional[str] = None) -> Decision:
+          causal: bool, platform: Optional[str] = None,
+          window: Optional[int] = None) -> Decision:
     """The attention backend for one shape. batch_heads = batch * query
     heads (the kernels' parallel grid axis). platform defaults to the
-    live jax backend; tests and chip_smoke.py name one they are not on."""
+    live jax backend; tests and chip_smoke.py name one they are not on.
+    window: keys a query sees under the causal mask, its own included
+    (flash_attention.effective_window). It is in the key, the tiles and
+    the counts, and not in the choice: a band is a shorter sweep of the
+    same kernels, not an arbitrary mask."""
     import jax
     import jax.numpy as jnp
     from .autotune import autotune_enabled   # it changes Decision.tiles
-    from .flash_attention import tiles_for_shape
+    from .flash_attention import (band_pairs, effective_window,
+                                  tiles_for_shape)
     dtype = jnp.dtype(dtype).name
     platform = platform or jax.default_backend()
     forced = _flags.flag_value("flash_attention_backend")
-    key = (batch_heads, seq_q, seq_k, head_dim, dtype, bool(causal),
+    window = effective_window(window, causal, seq_k)
+    key = (batch_heads, seq_q, seq_k, head_dim, dtype, bool(causal), window,
            platform, forced, autotune_enabled())
     if key in _decisions:
         return _decisions[key]
@@ -85,9 +98,15 @@ def route(batch_heads: int, seq_q: int, seq_k: int, head_dim: int, dtype,
     else:
         backend, why = "xla", f"seq_q < {_FLASH_MIN_SEQ_Q}"
     tiles = tiles_for_shape(batch_heads, seq_q, seq_k, head_dim, dtype,
-                            causal)
+                            causal, window)
+    share = None
+    if causal:
+        visited = tiles.visited_pairs(seq_q, seq_k, window)
+        share = sum(visited.values()) / (
+            3.0 * max(band_pairs(seq_q, seq_k, window), 1))
     dec = Decision(fwd=backend, bwd=backend, tiles=tiles,
-                   grid_steps=tiles.grid_steps(batch_heads, seq_q, seq_k),
-                   why=why)
+                   grid_steps=tiles.grid_steps(batch_heads, seq_q, seq_k,
+                                               window),
+                   why=why, visited_pair_share=share)
     _decisions[key] = dec
     return dec

@@ -36,6 +36,14 @@ are then read from HBM once per head.
   the padded key tail) crosses. Cross-length causal uses the
   bottom-right-aligned convention (offset = seq_k - seq_q), matching the
   dense reference.
+- Window (with causal): query i sees key j iff 0 <= i + offset - j <
+  window. The band enters where the diagonal does: the sub-block loops get
+  a lower bound as well, the sub-blocks the band's far edge crosses the
+  iota mask, and the streamed grid axis runs over the tiles of each
+  resident tile's band, first to last, so no grid step is made for a tile
+  the band never reaches. The same three kernel functions under the names
+  `faw_fwd`, `faw_bwd_dq`, `faw_bwd_dkv`; a window that hides nothing
+  (`effective_window`) is the causal call, program and names.
 
 On non-TPU backends the kernels run under the Pallas interpreter (tests).
 """
@@ -74,18 +82,78 @@ class Tiles:
     dq: tuple
     dkv: tuple
 
-    def grid_steps(self, batch_heads: int, seq_q: int, seq_k: int) -> dict:
-        """Grid steps of one call of each kernel, by the kernel's name."""
-        def n(seq, tile):
-            return -(-seq // tile)
-        return {
-            "fa_fwd": batch_heads * n(seq_q, self.fwd[0])
-            * n(seq_k, self.fwd[1]),
-            "fa_bwd_dq": batch_heads * n(seq_q, self.dq[0])
-            * n(seq_k, self.dq[1]),
-            "fa_bwd_dkv": batch_heads * n(seq_k, self.dkv[0])
-            * n(seq_q, self.dkv[1]),
-        }
+    def _sweeps(self, seq_q, seq_k):
+        """(kernel's name less its prefix, tile, resident side, resident
+        and streamed sequence lengths) of the three kernels."""
+        return (("fwd", self.fwd, "q", seq_q, seq_k),
+                ("bwd_dq", self.dq, "q", seq_q, seq_k),
+                ("bwd_dkv", self.dkv, "k", seq_k, seq_q))
+
+    def grid_steps(self, batch_heads: int, seq_q: int, seq_k: int,
+                   window=None) -> dict:
+        """Grid steps of one call of each kernel, by the kernel's name
+        (`faw_*` under a window that hides something: their streamed axis
+        runs over the tiles one resident tile's band can touch)."""
+        window = effective_window(window, True, seq_k)
+        out = {}
+        for name, tile, side, seq_res, seq_str in self._sweeps(seq_q, seq_k):
+            if window is None:
+                streamed = -(-seq_str // tile[1])
+            else:
+                streamed = _band_tiles(side, tile, seq_res, seq_str,
+                                       seq_k - seq_q, window)
+            out[("fa_" if window is None else "faw_") + name] = (
+                batch_heads * -(-seq_res // tile[0]) * streamed)
+        return out
+
+    def visited_pairs(self, seq_q: int, seq_k: int, window=None) -> dict:
+        """Query-key pairs one (batch, head) of each kernel's sweep visits
+        under the causal mask, by the kernel's name: (visited sub-blocks)
+        x (resident rows x sub rows), from the bounds the kernels' own
+        loops take (`_key_bounds`, `_query_bounds`), counted on the host.
+        `band_pairs` is what the mask needs."""
+        window = effective_window(window, True, seq_k)
+        offset = seq_k - seq_q
+        out = {}
+        for name, tile, side, seq_res, seq_str in self._sweeps(seq_q, seq_k):
+            res, streamed, sub = tile
+            bounds = _key_bounds if side == "q" else _query_bounds
+            blocks = 0
+            for i in range(-(-seq_res // res)):
+                for j in range(-(-seq_str // streamed)):
+                    lo, _, _, hi = bounds(i * res, res, j * streamed, sub,
+                                          streamed // sub, True, offset,
+                                          seq_str, window)
+                    blocks += hi - lo
+            out[("fa_" if window is None else "faw_") + name] = (
+                blocks * res * sub)
+        return out
+
+
+def effective_window(window, causal, seq_k):
+    """`window` as the kernels take it: None where it hides nothing (no
+    window, or one that reaches past the first key), so that such a call
+    is the causal call, program and names. A window needs `causal`: query
+    i (bottom-right aligned) sees key j iff 0 <= i + offset - j < window."""
+    if window is None:
+        return None
+    window = int(window)
+    if window < 1:
+        raise ValueError(f"window {window}: at least the query's own key")
+    if not causal:
+        raise ValueError("a window is a band under the causal diagonal: "
+                         "causal=True")
+    return None if window >= seq_k else window
+
+
+def band_pairs(seq_q, seq_k, window=None):
+    """Query-key pairs the causal mask (bottom-right aligned), cut to
+    `window` keys a query where one is given, leaves of one head."""
+    import numpy as np
+    reach = np.arange(seq_q, dtype=np.int64) + (seq_k - seq_q)
+    last = np.minimum(reach, seq_k - 1)
+    first = 0 if window is None else np.maximum(reach - window + 1, 0)
+    return int(np.maximum(last - first + 1, 0).sum())
 
 
 # (resident, sub) rows a kernel takes when the sequence is long enough:
@@ -98,6 +166,17 @@ class Tiles:
 _TILE_ROWS = {"fwd": (1024, 1024), "dq": (512, 512), "dkv": (512, 512)}
 _ROW_CANDIDATES = ((256, 256), (512, 256), (512, 512), (1024, 512),
                    (1024, 1024))
+
+# Under a window that hides something the same rows won the sweep (TPU v5e,
+# batch x heads 64 over 8 key-value heads, seq 16384, window 1024, d 128,
+# bf16: tools/window_attention.py; PERF.md section 6, PR 34): the forward
+# 10.1 ms a call at 1024 x 1024, 15.1 at 512 x 512, 25 at 256. The one
+# difference is dKV's streamed tile: it streams Q and dO, which change with
+# every query head of the group, so whole they would be 8 MiB a grid step
+# for a band of 1024 + 512 rows (11.3 ms at 1024 rows, 24.4 whole). For the
+# forward and dQ the streamed tile's size moves nothing (K and V of a head
+# are fetched once either way), so they keep the whole sequence.
+_WINDOW_STREAMED_ROWS = {"dkv": 1024}
 
 # what a kernel's buffers may take when the streamed tile is sized, and the
 # most Mosaic is ever asked for (a v5e core has 128 MiB of VMEM; the
@@ -145,19 +224,27 @@ def vmem_bytes(kind, tile, head_dim, itemsize):
 
 
 def choose_tiles(seq_q, seq_k, head_dim, itemsize,
-                 vmem_budget=_VMEM_BUDGET, rows=None) -> Tiles:
+                 vmem_budget=_VMEM_BUDGET, rows=None, window=None) -> Tiles:
     """The one place tiles are chosen, from the shape alone. Resident and
     sub tiles are the kernel's `_TILE_ROWS`, halved while padding a short
     or ragged sequence to them would waste more than an eighth of it; the
     streamed tile is the whole padded sequence, halved while the kernel's
     buffers exceed `vmem_budget`. `rows` overrides `_TILE_ROWS` for some
-    kernels (the autotuner's candidates)."""
+    kernels (the autotuner's candidates).
+
+    Under a `window` that hides something (`effective_window`) a kernel
+    named in `_WINDOW_STREAMED_ROWS` streams tiles as near to that many
+    rows as its sub tile allows."""
+    banded = effective_window(window, True, seq_k) is not None
     rows = {**_TILE_ROWS, **(rows or {})}
+    cap = _WINDOW_STREAMED_ROWS if banded else {}
 
     def one(kind, seq_res, seq_str):
         res = _fit(seq_res, rows[kind][0])
         sub = _fit(seq_str, rows[kind][1])
         streamed = _round_up(seq_str, sub)
+        if kind in cap:
+            streamed = min(streamed, _round_up(cap[kind], sub))
         while streamed > sub and vmem_bytes(
                 kind, (res, streamed, sub), head_dim, itemsize) > vmem_budget:
             streamed = _round_up(streamed // 2, sub)
@@ -172,45 +259,85 @@ def choose_tiles(seq_q, seq_k, head_dim, itemsize,
 # --------------------------------------------------------------------------
 
 def _fdiv(x, n):
-    """floor(max(x, 0) / n) on traced int32 scalars."""
+    """floor(max(x, 0) / n): on traced int32 scalars in a kernel or an
+    index map, on Python ints where the host counts the same sweep."""
+    if isinstance(x, int):
+        return max(x, 0) // n
     return jax.lax.div(jnp.maximum(x, 0), jnp.int32(n))
 
 
-def _key_bounds(row0, block_q, col0, block_k, n_sub, causal, offset, seq_k):
+def _cdiv(x, n):
+    """ceil(max(x, 0) / n), as `_fdiv`."""
+    return _fdiv(x + n - 1, n)
+
+
+def _min(a, b):
+    if isinstance(a, int) and isinstance(b, int):
+        return min(a, b)
+    return jnp.minimum(a, b)
+
+
+def _max(a, b):
+    if isinstance(a, int) and isinstance(b, int):
+        return max(a, b)
+    return jnp.maximum(a, b)
+
+
+def _key_bounds(row0, block_q, col0, block_k, n_sub, causal, offset, seq_k,
+                window=None):
     """Query rows [row0, row0 + block_q) against key sub-blocks s = 0..n_sub
-    of block_k columns from col0: -> (n_full, hi). Sub-blocks [0, n_full)
-    are attended whole, [n_full, hi) need the iota mask (the diagonal or
-    the padded key tail crosses them), [hi, n_sub) hold nothing."""
+    of block_k columns from col0: -> (lo, lo_full, n_full, hi). Sub-blocks
+    [lo_full, n_full) are attended whole; [lo, lo_full) need the iota mask
+    (the band's lower edge crosses them), as do [n_full, hi) (the diagonal
+    or the padded key tail); below lo every key is behind the window of
+    every row, from hi on they hold nothing. Without a window lo = lo_full
+    = 0."""
     n_full = hi = n_sub
+    lo = lo_full = 0
     if causal:
-        hi = jnp.minimum(hi, _fdiv(row0 + block_q - 1 + offset - col0
-                                   + block_k, block_k))
-        n_full = jnp.minimum(n_full, _fdiv(row0 + offset - col0 + 1,
-                                           block_k))
+        hi = _min(hi, _fdiv(row0 + block_q - 1 + offset - col0
+                            + block_k, block_k))
+        n_full = _min(n_full, _fdiv(row0 + offset - col0 + 1, block_k))
     if seq_k is not None:
-        hi = jnp.minimum(hi, _fdiv(seq_k - col0 + block_k - 1, block_k))
-        n_full = jnp.minimum(n_full, _fdiv(seq_k - col0, block_k))
-    return n_full, hi
+        hi = _min(hi, _fdiv(seq_k - col0 + block_k - 1, block_k))
+        n_full = _min(n_full, _fdiv(seq_k - col0, block_k))
+    if window is not None:
+        # row r's first key is r + offset - window + 1
+        first = row0 + offset - window + 1 - col0
+        lo = _min(hi, _fdiv(first, block_k))
+        lo_full = _min(hi, _cdiv(first + block_q - 1, block_k))
+        n_full = _max(n_full, lo_full)
+    return lo, lo_full, n_full, hi
 
 
 def _query_bounds(col0, block_k, row0, block_q, n_sub, causal, offset,
-                  seq_q):
+                  seq_q, window=None):
     """Key rows [col0, col0 + block_k) against query sub-blocks s = 0..n_sub
-    of block_q rows from row0: -> (lo, first_full, hi). [lo, first_full)
-    cross the diagonal, [first_full, hi) are attended whole; below lo the
-    keys are in the future of every query, from hi on the rows are pad."""
+    of block_q rows from row0: -> (lo, first_full, last_full, hi). [lo,
+    first_full) cross the diagonal, [first_full, last_full) are attended
+    whole, [last_full, hi) cross the band's far edge; below lo the keys are
+    in the future of every query, from hi on the rows are pad or past the
+    window of every key. Without a window last_full = hi."""
     lo = first_full = 0
     hi = n_sub
     if seq_q is not None:
-        hi = jnp.minimum(hi, _fdiv(seq_q - row0 + block_q - 1, block_q))
+        hi = _min(hi, _fdiv(seq_q - row0 + block_q - 1, block_q))
+    if window is not None:
+        # key c's last query is c - offset + window - 1
+        hi = _min(hi, _cdiv(col0 + block_k - offset + window - 1 - row0,
+                            block_q))
     if causal:
-        lo = jnp.minimum(hi, _fdiv(col0 - offset - row0, block_q))
-        first_full = jnp.minimum(hi, _fdiv(
+        lo = _min(hi, _fdiv(col0 - offset - row0, block_q))
+        first_full = _min(hi, _fdiv(
             col0 + block_k - 1 - offset - row0 + block_q - 1, block_q))
-    return lo, first_full, hi
+    last_full = hi
+    if window is not None:
+        last_full = _min(hi, _max(first_full, _fdiv(
+            col0 - offset + window - row0, block_q)))
+    return lo, first_full, last_full, hi
 
 
-def _keep(shape, q_axis, row0, col0, causal, offset, seq_k):
+def _keep(shape, q_axis, row0, col0, causal, offset, seq_k, window=None):
     """Attended pairs of one score tile whose queries run along `q_axis`
     from row0 and whose keys run along the other axis from col0."""
     rows = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
@@ -218,10 +345,60 @@ def _keep(shape, q_axis, row0, col0, causal, offset, seq_k):
     keep = None
     if causal:
         keep = rows + offset >= cols
+    if window is not None:
+        keep = keep & (rows + offset - cols < window)
     if seq_k is not None:
         tail = cols < seq_k
         keep = tail if keep is None else keep & tail
     return keep
+
+
+# The streamed tiles a resident tile needs, first and last. The index maps
+# clamp to them, so that a grid step outside repeats a block index and
+# issues no DMA. Under a window the grid's streamed axis is RELATIVE to the
+# first (step j loads tile first + j, at most the last) and only as long as
+# the widest resident tile's band (`_band_tiles`): the steps of a
+# rectangular grid that the band never reaches are not made at all.
+
+def _last_key_tile(i, block_q, block_km, n_km, offset):
+    """The last streamed key tile query tile i attends under the causal
+    mask: the index map clamps to it, so the steps beyond repeat its block
+    index and fetch nothing."""
+    return _min(_fdiv(i * block_q + block_q - 1 + offset, block_km),
+                n_km - 1)
+
+
+def _first_key_tile(i, block_q, block_km, n_km, offset, window):
+    """The first streamed key tile query tile i's window reaches."""
+    return _min(_fdiv(i * block_q + offset - window + 1, block_km), n_km - 1)
+
+
+def _first_q_tile(j, block_k, block_qm, n_qm, offset):
+    """The first streamed query tile key tile j is visible to."""
+    return _min(_fdiv(j * block_k - offset, block_qm), n_qm - 1)
+
+
+def _last_q_tile(j, block_k, block_qm, n_qm, offset, window):
+    """The last streamed query tile whose window reaches key tile j."""
+    return _min(_fdiv(j * block_k + block_k - 1 - offset + window - 1,
+                      block_qm), n_qm - 1)
+
+
+def _band_tiles(side, tile, seq_res, seq_str, offset, window):
+    """Steps of a windowed call's streamed grid axis: the most streamed
+    tiles any resident tile's band touches. side 'q': resident query
+    tiles (fwd, dQ); 'k': resident key tiles (dKV)."""
+    res, streamed, _ = tile
+    n_res, n_str = -(-seq_res // res), -(-seq_str // streamed)
+    if side == "q":
+        spans = (_last_key_tile(i, res, streamed, n_str, offset)
+                 - _first_key_tile(i, res, streamed, n_str, offset, window)
+                 for i in range(n_res))
+    else:
+        spans = (_last_q_tile(j, res, streamed, n_str, offset, window)
+                 - _first_q_tile(j, res, streamed, n_str, offset)
+                 for j in range(n_res))
+    return max(1, max(spans) + 1)
 
 
 def _loop(start, stop, body):
@@ -249,13 +426,41 @@ def _column(row_ref, rows):
     return jnp.transpose(jnp.broadcast_to(row_ref[0], (_LANES, rows)))
 
 
+def _streamed_origin(qi, kj, tile, offset, window, n_streamed):
+    """First key column of the streamed tile grid step (qi, kj) of the
+    forward and dQ kernels holds: tile kj, or under a window tile kj of
+    query tile qi's band (a step past the band's last tile repeats that
+    tile's block index; its columns, reckoned here unclamped, lie above
+    the diagonal or past the keys and the sweep is empty)."""
+    block_q, block_km, _ = tile
+    if window is None:
+        return kj * block_km
+    return (_first_key_tile(qi, block_q, block_km, n_streamed, offset,
+                            window) + kj) * block_km
+
+
+def _sweep_keys(visit, row0, col0, tile, causal, offset, seq_k, window):
+    """visit(s, masked) over the key sub-blocks of one streamed tile that
+    the resident query tile attends (`_key_bounds`)."""
+    block_q, block_km, block_k = tile
+    lo, lo_full, n_full, hi = _key_bounds(
+        row0, block_q, col0, block_k, block_km // block_k, causal, offset,
+        seq_k, window)
+    if window is not None:
+        _loop(lo, lo_full, functools.partial(visit, masked=True))
+    _loop(lo_full, n_full, functools.partial(visit, masked=False))
+    if causal or seq_k is not None:
+        _loop(n_full, hi, functools.partial(visit, masked=True))
+
+
 # --------------------------------------------------------------------------
 # kernels
 # --------------------------------------------------------------------------
 
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
                    seq_k, tile: tuple, offset: int, epilogue: bool = False,
-                   rms_eps: float = 1e-6, rms_d: int = 0):
+                   rms_eps: float = 1e-6, rms_d: int = 0, window=None,
+                   n_streamed: int = 0):
     # optional fused epilogue (FlashFuser-style widened fusion): two extra
     # inputs — residual block + lane-broadcast RMSNorm gamma — and the
     # flush writes rmsnorm(attn + residual) * gamma instead of attn,
@@ -270,7 +475,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     row0 = qi * block_q
-    col0 = kj * block_km
+    col0 = _streamed_origin(qi, kj, tile, offset, window, n_streamed)
 
     @pl.when(kj == 0)
     def _init():
@@ -290,7 +495,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
                                  preferred_element_type=jnp.float32)
         if masked:
             sc = jnp.where(_keep(sc.shape, 0, row0, col0 + c, causal, offset,
-                                 seq_k), sc, NEG_INF)
+                                 seq_k, window), sc, NEG_INF)
         m_prev = m_s[...][:, :1]
         l_prev = l_s[...][:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
@@ -302,11 +507,7 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
         m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
         l_s[...] = jnp.broadcast_to(l_new, l_s.shape)
 
-    n_full, hi = _key_bounds(row0, block_q, col0, block_k,
-                             block_km // block_k, causal, offset, seq_k)
-    _loop(0, n_full, functools.partial(visit, masked=False))
-    if causal or seq_k is not None:
-        _loop(n_full, hi, functools.partial(visit, masked=True))
+    _sweep_keys(visit, row0, col0, tile, causal, offset, seq_k, window)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _flush():
@@ -328,12 +529,13 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, *refs, causal: bool, scale: float,
 
 def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                   qs_s, lse_s, delta_s, dq_s, *, causal: bool, scale: float,
-                  seq_k, tile: tuple, offset: int):
+                  seq_k, tile: tuple, offset: int, window=None,
+                  n_streamed: int = 0):
     block_q, block_km, block_k = tile
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     row0 = qi * block_q
-    col0 = kj * block_km
+    col0 = _streamed_origin(qi, kj, tile, offset, window, n_streamed)
 
     @pl.when(kj == 0)
     def _init():
@@ -354,7 +556,7 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                                  preferred_element_type=jnp.float32)
         if masked:
             sc = jnp.where(_keep(sc.shape, 0, row0, col0 + c, causal, offset,
-                                 seq_k), sc, NEG_INF)
+                                 seq_k, window), sc, NEG_INF)
         p = jnp.exp(sc - lse_s[...][:, :1])       # (block_q, block_k)
         dp = jax.lax.dot_general(do, v, _NT,
                                  preferred_element_type=jnp.float32)
@@ -362,11 +564,7 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         dq_s[...] += jax.lax.dot_general(ds, k, _NN,
                                          preferred_element_type=jnp.float32)
 
-    n_full, hi = _key_bounds(row0, block_q, col0, block_k,
-                             block_km // block_k, causal, offset, seq_k)
-    _loop(0, n_full, functools.partial(visit, masked=False))
-    if causal or seq_k is not None:
-        _loop(n_full, hi, functools.partial(visit, masked=True))
+    _sweep_keys(visit, row0, col0, tile, causal, offset, seq_k, window)
 
     @pl.when(kj == pl.num_programs(2) - 1)
     def _flush():
@@ -376,7 +574,7 @@ def _fa_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dk_ref, dv_ref, ks_s, dk_s, dv_s, *, causal: bool,
                    scale: float, seq_q, tile: tuple, offset: int,
-                   n_rep: int = 1):
+                   n_rep: int = 1, window=None, n_streamed: int = 0):
     # grid (bh_kv, k tiles, q-head group reps, q tiles): the scratch
     # accumulates over BOTH the group axis and the q tiles, flushing once
     # per kv tile — this is how GQA's dK/dV reduction happens in-kernel.
@@ -390,7 +588,11 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     rr = pl.program_id(2)
     qi = pl.program_id(3)
     col0 = kj * block_k
-    row0 = qi * block_qm
+    if window is None:
+        row0 = qi * block_qm
+    else:
+        row0 = (_first_q_tile(kj, block_k, block_qm, n_streamed, offset)
+                + qi) * block_qm
 
     @pl.when((qi == 0) & (rr == 0))
     def _init():
@@ -407,7 +609,7 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                                  preferred_element_type=jnp.float32)
         if masked:
             st = jnp.where(_keep(st.shape, 1, row0 + r, col0, causal, offset,
-                                 None), st, NEG_INF)
+                                 None, window), st, NEG_INF)
         pt = jnp.exp(st - lse_ref[0, s])          # (block_k, block_q)
         dv_s[...] += jax.lax.dot_general(
             pt.astype(do.dtype), do, _NN, preferred_element_type=jnp.float32)
@@ -417,12 +619,14 @@ def _fa_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_s[...] += jax.lax.dot_general(dst, q, _NN,
                                          preferred_element_type=jnp.float32)
 
-    lo, first_full, hi = _query_bounds(col0, block_k, row0, block_q,
-                                       block_qm // block_q, causal, offset,
-                                       seq_q)
+    lo, first_full, last_full, hi = _query_bounds(
+        col0, block_k, row0, block_q, block_qm // block_q, causal, offset,
+        seq_q, window)
     if causal:
         _loop(lo, first_full, functools.partial(visit, masked=True))
-    _loop(first_full, hi, functools.partial(visit, masked=False))
+    _loop(first_full, last_full, functools.partial(visit, masked=False))
+    if window is not None:
+        _loop(last_full, hi, functools.partial(visit, masked=True))
 
     @pl.when((qi == pl.num_programs(3) - 1) & (rr == n_rep - 1))
     def _flush():
@@ -465,17 +669,28 @@ def _params(kind, tile, head_dim, itemsize, semantics):
         vmem_limit_bytes=min(max(2 * est, 32 << 20), _VMEM_LIMIT_MAX))
 
 
-def _last_key_tile(i, block_q, block_km, n_km, offset):
-    """The last streamed key tile query tile i attends under the causal
-    mask: the index map clamps to it, so the steps beyond repeat its block
-    index and fetch nothing."""
-    return jnp.minimum(_fdiv(i * block_q + block_q - 1 + offset, block_km),
-                       n_km - 1)
+def _key_tile_map(tile, n_km, offset, causal, window, q_per_kv):
+    """Index map of the forward's and dQ's K and V blocks over grid (b, i,
+    j): kv head b // q_per_kv (GQA), streamed tile j clamped to the last
+    tile query tile i attends; under a window, tile j of i's band."""
+    block_q, block_km, _ = tile
+
+    def kmap(b, i, j):
+        if window is not None:
+            j = jnp.minimum(
+                _first_key_tile(i, block_q, block_km, n_km, offset, window)
+                + j, _last_key_tile(i, block_q, block_km, n_km, offset))
+        elif causal:
+            j = jnp.minimum(j, _last_key_tile(i, block_q, block_km, n_km,
+                                              offset))
+        return (b // q_per_kv, j, 0)
+
+    return kmap
 
 
 def _flash_fwd_bhsd(q, k, v, causal, scale, tiles=None, interpret=None,
                     q_per_kv=1, residual=None, rms_weight=None,
-                    rms_eps=1e-6, rms_d=None):
+                    rms_eps=1e-6, rms_d=None, window=None):
     """q: (BH, Sq, D), k/v: (BH // q_per_kv, Sk, D) -> (out, lse), lse
     (BH, Sq) float32. tiles: a `Tiles` (None = `choose_tiles` of the shape).
 
@@ -493,14 +708,16 @@ def _flash_fwd_bhsd(q, k, v, causal, scale, tiles=None, interpret=None,
     folds the head grouping (q index b -> kv index b // q_per_kv), so no
     (B, S, H, D) broadcast of KV ever materializes in HBM. With batch-major
     bh layout (bi*h + hq), b // q_per_kv == bi*kvh + hq // rep exactly."""
+    window = effective_window(window, causal, k.shape[1])
     if tiles is None:
         tiles = choose_tiles(q.shape[1], k.shape[1], q.shape[2],
-                             q.dtype.itemsize)
+                             q.dtype.itemsize, window=window)
     if interpret is None:
         interpret = _interpret_default()
     return _fwd_call(q, k, v, residual, rms_weight, causal=causal,
                      scale=scale, tiles=tiles, interpret=interpret,
-                     q_per_kv=q_per_kv, rms_eps=rms_eps, rms_d=rms_d)
+                     q_per_kv=q_per_kv, rms_eps=rms_eps, rms_d=rms_d,
+                     window=window)
 
 
 # the calls are jitted so that a model's layers, which call with one
@@ -509,9 +726,10 @@ def _flash_fwd_bhsd(q, k, v, causal, scale, tiles=None, interpret=None,
 # them like any call. Every default is resolved before, so what the jit
 # caches on is what the kernel is built from.
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "tiles", "interpret", "q_per_kv", "rms_eps", "rms_d"))
+    "causal", "scale", "tiles", "interpret", "q_per_kv", "rms_eps", "rms_d",
+    "window"))
 def _fwd_call(q, k, v, residual, rms_weight, *, causal, scale, tiles,
-              interpret, q_per_kv, rms_eps, rms_d):
+              interpret, q_per_kv, rms_eps, rms_d, window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
     tile = block_q, block_km, block_k = tiles.fwd
@@ -523,19 +741,16 @@ def _fwd_call(q, k, v, residual, rms_weight, *, causal, scale, tiles,
     nq, nk = sq_p // block_q, sk_p // block_km
     offset = sk - sq
     epilogue = residual is not None
+    banded = window is not None
     kernel = functools.partial(
         _fa_fwd_kernel, causal=causal, scale=scale,
-        seq_k=sk if sk_p != sk else None, tile=tile, offset=offset,
-        epilogue=epilogue, rms_eps=rms_eps, rms_d=(rms_d or d))
+        seq_k=sk if sk_p != sk or banded else None, tile=tile, offset=offset,
+        epilogue=epilogue, rms_eps=rms_eps, rms_d=(rms_d or d),
+        window=window, n_streamed=nk)
+    kmap = _key_tile_map(tile, nk, offset, causal, window, g)
 
     def qmap(b, i, j):
         return (b, i, 0)
-
-    def kmap(b, i, j):
-        if causal:
-            j = jnp.minimum(j, _last_key_tile(i, block_q, block_km, nk,
-                                              offset))
-        return (b // g, j, 0)
 
     in_specs = [
         pl.BlockSpec((1, block_q, d), qmap),
@@ -554,7 +769,8 @@ def _fwd_call(q, k, v, residual, rms_weight, *, causal, scale, tiles,
             rms_weight.astype(jnp.float32)[None, :], (8, d)))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, nq, nk),
+        grid=(bh, nq, _band_tiles("q", tile, sq, sk, offset, window)
+              if banded else nk),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, block_q, d), qmap),
@@ -575,37 +791,40 @@ def _fwd_call(q, k, v, residual, rms_weight, *, causal, scale, tiles,
         compiler_params=_params("fwd", tile, d, q.dtype.itemsize,
                                 ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="fa_fwd",
+        name="faw_fwd" if banded else "fa_fwd",
     )(*operands)
     return out[:, :sq], lse[:, 0, :sq]
 
 
 def _flash_bwd_bhsd(q, k, v, o, lse, g, causal, scale, tiles=None,
-                    interpret=None, q_per_kv=1):
+                    interpret=None, q_per_kv=1, window=None):
     """FlashAttention-2 backward: returns (dq, dk, dv), all in input dtype.
     lse: (BH, Sq) from the forward. GQA: k/v carry BH // q_per_kv heads;
     dk/dv come back already reduced over the query-head group (the rep
     axis rides the grid, accumulating into the same VMEM scratch — no
     XLA-side segment-sum needed)."""
+    window = effective_window(window, causal, k.shape[1])
     if tiles is None:
         tiles = choose_tiles(q.shape[1], k.shape[1], q.shape[2],
-                             q.dtype.itemsize)
+                             q.dtype.itemsize, window=window)
     if interpret is None:
         interpret = _interpret_default()
     return _bwd_call(q, k, v, o, lse, g, causal=causal, scale=scale,
-                     tiles=tiles, interpret=interpret, q_per_kv=q_per_kv)
+                     tiles=tiles, interpret=interpret, q_per_kv=q_per_kv,
+                     window=window)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "scale", "tiles", "interpret", "q_per_kv"))
+    "causal", "scale", "tiles", "interpret", "q_per_kv", "window"))
 def _bwd_call(q, k, v, o, lse, g, *, causal, scale, tiles, interpret,
-              q_per_kv):
+              q_per_kv, window=None):
     bh, sq, d = q.shape
     sk = k.shape[1]
     itemsize = q.dtype.itemsize
     offset = sk - sq
     grp = q_per_kv
     bh_kv = bh // grp
+    banded = window is not None
 
     # delta = rowsum(dO * O): cheap XLA elementwise+reduce, fp32
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
@@ -622,23 +841,21 @@ def _bwd_call(q, k, v, o, lse, g, *, causal, scale, tiles, interpret,
     # and their contributions vanish because dO's pad rows are zero
     rows = [_pad_to(x, 1, block_q)[:, None, :] for x in (lse, delta)]
 
+    dq_kmap = _key_tile_map(tile, nk, offset, causal, window, grp)
+
     def dq_qmap(b, i, j):
         return (b, i, 0)
-
-    def dq_kmap(b, i, j):
-        if causal:
-            j = jnp.minimum(j, _last_key_tile(i, block_q, block_km, nk,
-                                              offset))
-        return (b // grp, j, 0)
 
     def dq_rmap(b, i, j):
         return (b, 0, i)
 
     dq = pl.pallas_call(
         functools.partial(_fa_dq_kernel, causal=causal, scale=scale,
-                          seq_k=sk if sk_p != sk else None, tile=tile,
-                          offset=offset),
-        grid=(bh, nq, nk),
+                          seq_k=sk if sk_p != sk or banded else None,
+                          tile=tile, offset=offset, window=window,
+                          n_streamed=nk),
+        grid=(bh, nq, _band_tiles("q", tile, sq, sk, offset, window)
+              if banded else nk),
         in_specs=[
             pl.BlockSpec((1, block_q, d), dq_qmap),
             pl.BlockSpec((1, block_km, d), dq_kmap),
@@ -658,7 +875,7 @@ def _bwd_call(q, k, v, o, lse, g, *, causal, scale, tiles, interpret,
         compiler_params=_params("dq", tile, d, itemsize,
                                 ("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-        name="fa_bwd_dq",
+        name="faw_bwd_dq" if banded else "fa_bwd_dq",
     )(q_p, k_p, v_p, do_p, *rows)
 
     # ---- dK/dV: resident k tile, Q/dO streamed ---------------------------
@@ -676,18 +893,23 @@ def _bwd_call(q, k, v, o, lse, g, *, causal, scale, tiles, interpret,
     rows = [_pad_to(x, 1, block_qm).reshape(bh, sq_p // block_q, 1, block_q)
             for x in (lse, delta)]
 
-    def first_q_tile(j, i):
-        # the first streamed query tile key tile j is visible to
+    def q_tile(j, i):
+        # step i's streamed query tile: never before the first one key
+        # tile j is visible to; under a window, step i of j's band
+        if banded:
+            return jnp.minimum(
+                _first_q_tile(j, block_k, block_qm, nqm, offset) + i,
+                _last_q_tile(j, block_k, block_qm, nqm, offset, window))
         if causal:
-            i = jnp.maximum(i, jnp.minimum(
-                _fdiv(j * block_k - offset, block_qm), nqm - 1))
+            i = jnp.maximum(i, _first_q_tile(j, block_k, block_qm, nqm,
+                                             offset))
         return i
 
     def dkv_qmap(b, j, r, i):
-        return (b * grp + r, first_q_tile(j, i), 0)
+        return (b * grp + r, q_tile(j, i), 0)
 
     def dkv_rmap(b, j, r, i):
-        return (b * grp + r, first_q_tile(j, i), 0, 0)
+        return (b * grp + r, q_tile(j, i), 0, 0)
 
     def dkv_kmap(b, j, r, i):
         return (b, j, 0)
@@ -695,9 +917,11 @@ def _bwd_call(q, k, v, o, lse, g, *, causal, scale, tiles, interpret,
     n_sub = block_qm // block_q
     dk, dv = pl.pallas_call(
         functools.partial(_fa_dkv_kernel, causal=causal, scale=scale,
-                          seq_q=sq if sq_p != sq else None, tile=tile,
-                          offset=offset, n_rep=grp),
-        grid=(bh_kv, nk, grp, nqm),
+                          seq_q=sq if sq_p != sq or banded else None,
+                          tile=tile, offset=offset, n_rep=grp, window=window,
+                          n_streamed=nqm),
+        grid=(bh_kv, nk, grp, _band_tiles("k", tile, sk, sq, offset, window)
+              if banded else nqm),
         in_specs=[
             pl.BlockSpec((1, block_qm, d), dkv_qmap),
             pl.BlockSpec((1, block_k, d), dkv_kmap),
@@ -723,25 +947,32 @@ def _bwd_call(q, k, v, o, lse, g, *, causal, scale, tiles, interpret,
             "dkv", tile, d, itemsize,
             ("parallel", "parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
-        name="fa_bwd_dkv",
+        name="faw_bwd_dkv" if banded else "fa_bwd_dkv",
     )(q_p, k_p, v_p, do_p, *rows)
 
     return dq[:, :sq], dk[:, :sk], dv[:, :sk]
 
 
-def _xla_attention_bhsd(q, k, v, causal, scale):
+def band_mask(seq_q, seq_k, window=None):
+    """(seq_q, seq_k) bool: the causal mask, bottom-right aligned, cut to
+    `window` keys a query where one is given. What the dense paths apply."""
+    back = (jnp.arange(seq_q)[:, None] + (seq_k - seq_q)
+            - jnp.arange(seq_k)[None, :])
+    mask = back >= 0
+    return mask if window is None else mask & (back < window)
+
+
+def _xla_attention_bhsd(q, k, v, causal, scale, window=None):
     """Dense reference (O(S^2) memory). Used by tests and tiny shapes."""
     s = jnp.einsum("bqd,bkd->bqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
-        sq, sk = q.shape[1], k.shape[1]
-        mask = jnp.tril(jnp.ones((sq, sk), jnp.bool_), k=sk - sq)
-        s = jnp.where(mask, s, NEG_INF)
+        s = jnp.where(band_mask(q.shape[1], k.shape[1], window), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
     return jnp.einsum("bqk,bkd->bqd", p, v)
 
 
-def _tiles_for(kind, bh, sq, sk, d, dtype, causal, interpret):
+def _tiles_for(kind, bh, sq, sk, d, dtype, causal, interpret, window=None):
     """`choose_tiles` of the shape; with FLAGS_use_autotune on, the
     (resident, sub) rows of kernel(s) `kind` ('fwd' | 'bwd') are the timed
     winner of `_ROW_CANDIDATES` instead of `_TILE_ROWS`. Timing runs on
@@ -749,17 +980,18 @@ def _tiles_for(kind, bh, sq, sk, d, dtype, causal, interpret):
     from .autotune import autotune, autotune_enabled
     itemsize = jnp.dtype(dtype).itemsize
     if not autotune_enabled():
-        return choose_tiles(sq, sk, d, itemsize)
+        return choose_tiles(sq, sk, d, itemsize, window=window)
     dev = jax.devices()[0]
     # tb (the clamped tuning batch*heads) is part of the key: tile ranking
     # depends on grid parallelism, so a winner timed at 2 heads must not be
     # served to a 64-head caller
     tb = min(bh, 64)
-    key = (kind, tb, sq, sk, d, str(dtype), bool(causal), dev.device_kind)
+    key = (kind, tb, sq, sk, d, str(dtype), bool(causal), dev.device_kind,
+           window)
     kernels = ("fwd",) if kind == "fwd" else ("dq", "dkv")
 
     def tiles_of(rows):
-        return choose_tiles(sq, sk, d, itemsize,
+        return choose_tiles(sq, sk, d, itemsize, window=window,
                             rows={name: rows for name in kernels})
 
     def make_runner(rows):
@@ -775,7 +1007,7 @@ def _tiles_for(kind, bh, sq, sk, d, dtype, causal, interpret):
         if kind == "fwd":
             def step(qq):
                 o, _ = _flash_fwd_bhsd(qq, k, v, causal, 1.0, tiles=tiles,
-                                       interpret=interpret)
+                                       interpret=interpret, window=window)
                 return jnp.sum(o.astype(jnp.float32))
         else:
             # o / lse only need the forward's shapes: timing is on zeros
@@ -783,7 +1015,8 @@ def _tiles_for(kind, bh, sq, sk, d, dtype, causal, interpret):
 
             def step(qq):
                 outs = _flash_bwd_bhsd(qq, k, v, q, lse, q, causal, 1.0,
-                                       tiles=tiles, interpret=interpret)
+                                       tiles=tiles, interpret=interpret,
+                                       window=window)
                 return sum(jnp.sum(x.astype(jnp.float32)) for x in outs)
 
         @jax.jit
@@ -802,46 +1035,46 @@ def _tiles_for(kind, bh, sq, sk, d, dtype, causal, interpret):
                              default=_TILE_ROWS[kernels[0]]))
 
 
-def _tiles(kind, q, k, causal):
+def _tiles(kind, q, k, causal, window=None):
     bh, sq, d = q.shape
     return _tiles_for(kind, bh, sq, k.shape[1], d, q.dtype, causal,
-                      _interpret_default())
+                      _interpret_default(),
+                      effective_window(window, causal, k.shape[1]))
 
 
 def tiles_for_shape(batch_heads, seq_q, seq_k, head_dim, dtype,
-                    causal) -> Tiles:
+                    causal, window=None) -> Tiles:
     """The tiles the entry points of this module hand the three kernels
     for attention of this shape, resolved the way they resolve them
     (`_tiles_for`: the head dim padded to the lane width, and
     FLAGS_use_autotune's timed winners where it is on). The router's
     Decision records these."""
     d = _round_up(head_dim, _LANES)
+    window = effective_window(window, causal, seq_k)
     fwd, bwd = (_tiles_for(kind, batch_heads, seq_q, seq_k, d,
-                           jnp.dtype(dtype), causal, _interpret_default())
+                           jnp.dtype(dtype), causal, _interpret_default(),
+                           window)
                 for kind in ("fwd", "bwd"))
     return Tiles(fwd=fwd.fwd, dq=bwd.dq, dkv=bwd.dkv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_attention_bhsd(q, k, v, causal, scale, q_per_kv=1):
-    out, _ = _flash_fwd_bhsd(q, k, v, causal, scale,
-                             tiles=_tiles("fwd", q, k, causal),
-                             q_per_kv=q_per_kv)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_attention_bhsd(q, k, v, causal, scale, q_per_kv=1, window=None):
+    return _fa_fwd(q, k, v, causal, scale, q_per_kv, window)[0]
 
 
-def _fa_fwd(q, k, v, causal, scale, q_per_kv=1):
+def _fa_fwd(q, k, v, causal, scale, q_per_kv=1, window=None):
     out, lse = _flash_fwd_bhsd(q, k, v, causal, scale,
-                               tiles=_tiles("fwd", q, k, causal),
-                               q_per_kv=q_per_kv)
+                               tiles=_tiles("fwd", q, k, causal, window),
+                               q_per_kv=q_per_kv, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, scale, q_per_kv, res, g):
+def _fa_bwd(causal, scale, q_per_kv, window, res, g):
     q, k, v, o, lse = res
     return _flash_bwd_bhsd(q, k, v, o, lse, g, causal, scale,
-                           tiles=_tiles("bwd", q, k, causal),
-                           q_per_kv=q_per_kv)
+                           tiles=_tiles("bwd", q, k, causal, window),
+                           q_per_kv=q_per_kv, window=window)
 
 
 _flash_attention_bhsd.defvjp(_fa_fwd, _fa_bwd)
@@ -880,8 +1113,14 @@ def _mesh_spec(b, h, kvh):
     return jax.sharding.PartitionSpec(tuple(batch) or None, None, heads, None)
 
 
-def flash_attention_bshd(q, k, v, causal=False, scale=None):
+def flash_attention_bshd(q, k, v, causal=False, scale=None, window=None):
     """Paddle flash_attention layout: (batch, seq, heads, head_dim).
+
+    window (with causal): query i, bottom-right aligned, sees key j iff
+    0 <= i + offset - j < window: `window` keys with its own (the Hugging
+    Face sliding-window mask). The kernels (`faw_*`) visit the band's
+    sub-blocks and no others. A window that hides nothing at this shape is
+    the causal call.
 
     GQA-native: k/v may carry FEWER heads than q (num_kv_heads divides
     num_heads); the kernel groups query heads onto shared KV blocks via
@@ -896,8 +1135,9 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None):
         raise ValueError(f"num_heads {h} not divisible by kv heads {kvh}")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    local = functools.partial(_flash_attention_local, causal=causal,
-                              scale=scale)
+    local = functools.partial(
+        _flash_attention_local, causal=causal, scale=scale,
+        window=effective_window(window, causal, k.shape[1]))
     spec = _mesh_spec(b, h, kvh)
     if spec is None:
         return local(q, k, v)
@@ -905,7 +1145,7 @@ def flash_attention_bshd(q, k, v, causal=False, scale=None):
                          check_vma=False)(q, k, v)
 
 
-def _flash_attention_local(q, k, v, causal, scale):
+def _flash_attention_local(q, k, v, causal, scale, window=None):
     """flash_attention_bshd on the operands one device holds."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -924,7 +1164,7 @@ def _flash_attention_local(q, k, v, causal, scale):
     qt = jnp.swapaxes(q, 1, 2).reshape(b * h, sq, dp)
     kt = jnp.swapaxes(k, 1, 2).reshape(b * kvh, sk, dp)
     vt = jnp.swapaxes(v, 1, 2).reshape(b * kvh, sk, dp)
-    out = _flash_attention_bhsd(qt, kt, vt, causal, scale, h // kvh)
+    out = _flash_attention_bhsd(qt, kt, vt, causal, scale, h // kvh, window)
     out = jnp.swapaxes(out.reshape(b, h, sq, dp), 1, 2)
     return out[..., :d] if d_pad else out
 

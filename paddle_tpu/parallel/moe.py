@@ -196,6 +196,11 @@ class ExpertParallelMoE:
 def route_top_k(x, router_w, top_k):
     """(expert ids [T, k], gates [T, k] float32): the top-k of the router's
     logits over ALL its outputs, gates = softmax over the k chosen logits.
+    That is also the routing of the configs that key it `norm_topk_prob`
+    (softmax over all outputs, top-k of the probabilities, weights
+    renormalised over the k): exp is monotone, so the top-k is the same,
+    and p_e / sum of the k p's = exp(l_e) / sum of the k exp(l)'s, the
+    full softmax's denominator cancelling (tests/test_mellum.py).
     Logits accumulate in float32 so that near-ties break as they would in
     a float32 reference."""
     logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
@@ -311,7 +316,8 @@ def _in_slot_order_bwd(rows, res, g):
 _in_slot_order.defvjp(_in_slot_order_fwd, _in_slot_order_bwd)
 
 
-def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held):
+def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held,
+                 differentiate_routing=True):
     """The held experts' part of a routed gated-MLP layer.
 
     x [T, D]; router_w [D, E] over ALL E experts; w_in [count, D, 2 * I]
@@ -334,10 +340,19 @@ def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held):
     written by that kernel and may hold anything, NaN too: nothing may
     carry them on, forward or backward. No part of the layer is a
     scatter, and no gather fetches single numbers: a TPU pays both by the
-    index. Component scope `pt.moe.route` holds what is not expert work."""
+    index. Component scope `pt.moe.route` holds what is not expert work.
+
+    `differentiate_routing=False` makes the gates constants of the
+    backward pass: the router's weight gets no gradient and the routing
+    sends none to x. A share of the experts cannot give that gradient:
+    the terms of the experts that are not held are missing from it, and
+    with the held terms alone it sends every token to the held experts
+    (PERF.md section 6, PR 34)."""
     tokens, k = x.shape[0], top_k
     with jax.named_scope("pt.moe.route"):
         top_ids, gates = route_top_k(x, router_w, k)
+        if not differentiate_routing:
+            gates = jax.lax.stop_gradient(gates)
         source, slot, sizes = sorted_assignments(top_ids, experts_held)
         rows = source.shape[0]
         live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]

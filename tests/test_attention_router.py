@@ -104,8 +104,8 @@ class TestTheConstant:
             ar.route(4, 640, 640, 64, "float32", True, platform="tpu")
         ar.route(4, 640, 320, 64, "float32", True, platform="tpu")
         keys = [key for key, _ in ar.decision_log()]
-        assert keys == [(4, 640, 640, 64, "float32", True),
-                        (4, 640, 320, 64, "float32", True)]
+        assert keys == [(4, 640, 640, 64, "float32", True, None),
+                        (4, 640, 320, 64, "float32", True, None)]
 
 
 class TestBackendParity:
@@ -168,6 +168,82 @@ class TestDecisionCarriesTiles:
             8 * -(-300 // res_q) * -(-1000 // streamed_k))
 
 
+class TestWindow:
+    """A window is in the rule's key, its log and its counts, and not in
+    its choice."""
+
+    CELL = (64, 16384, 16384, 128, "bfloat16", True)   # the Mellum cell's
+
+    def test_the_window_is_in_the_key_and_the_log(self):
+        full = ar.route(*self.CELL, platform="tpu")
+        banded = ar.route(*self.CELL, platform="tpu", window=1024)
+        again = ar.route(*self.CELL, platform="tpu", window=1024)
+        assert again is banded and banded is not full
+        assert [key for key, _ in ar.decision_log()] == [
+            self.CELL + (None,), self.CELL + (1024,)]
+        assert set(full.grid_steps) == {"fa_fwd", "fa_bwd_dq", "fa_bwd_dkv"}
+        assert set(banded.grid_steps) == {"faw_fwd", "faw_bwd_dq",
+                                          "faw_bwd_dkv"}
+        assert banded.tiles == fa.choose_tiles(16384, 16384, 128, 2,
+                                               window=1024)
+        assert banded.grid_steps == banded.tiles.grid_steps(
+            64, 16384, 16384, 1024)
+
+    def test_a_window_that_hides_nothing_is_the_causal_decision(self):
+        """The accepted cells' shapes, whatever window reaches them: the
+        same Decision object, tiles and grid steps as without one."""
+        for bh, seq, steps in ((64, 2048, (128, 256, 256)),
+                               (32, 8192, (256, 512, 512))):
+            plain = ar.route(bh, seq, seq, 128, "bfloat16", True,
+                             platform="tpu")
+            for window in (seq, seq + 1, 1 << 20):
+                assert ar.route(bh, seq, seq, 128, "bfloat16", True,
+                                platform="tpu", window=window) is plain
+            assert tuple(plain.grid_steps[k] for k in (
+                "fa_fwd", "fa_bwd_dq", "fa_bwd_dkv")) == steps
+        assert len(ar.decision_log()) == 2
+
+    @pytest.mark.parametrize("seq_q,backend", [(256, "xla"), (512, "pallas"),
+                                               (16384, "pallas")])
+    def test_the_choice_stays_one_rule_on_seq_q(self, seq_q, backend):
+        for window in (None, 64, 1024):
+            dec = ar.route(8, seq_q, seq_q, 128, "bfloat16", True,
+                           platform="tpu", window=window)
+            assert (dec.fwd, dec.bwd) == (backend, backend)
+            assert str(ar._FLASH_MIN_SEQ_Q) in dec.why
+        assert ar.route(8, seq_q, seq_q, 128, "bfloat16", True,
+                        platform="cpu", window=64).fwd == "xla"
+
+    def test_visited_pair_share(self):
+        """visited / needed pairs, the three kernels' mean: 1.0 at best.
+        Without a window the causal schedule's own waste at the diagonal;
+        under the cell's window never twice the band; a call that is not
+        causal skips nothing and reports None."""
+        full = ar.route(*self.CELL, platform="tpu")
+        banded = ar.route(*self.CELL, platform="tpu", window=1024)
+        assert 1.0 < full.visited_pair_share < 1.1
+        assert 1.0 < banded.visited_pair_share < 2.0
+        visited = banded.tiles.visited_pairs(16384, 16384, 1024)
+        assert banded.visited_pair_share == pytest.approx(
+            sum(visited.values()) / (3 * 16_253_440))
+        # the causal kernels on the same band would visit the triangle
+        assert sum(full.tiles.visited_pairs(16384, 16384).values()) \
+            > 8 * sum(visited.values()) / 2
+        assert ar.route(8, 512, 512, 128, "bfloat16", False,
+                        platform="tpu").visited_pair_share is None
+        with pytest.raises(ValueError):
+            ar.route(8, 512, 512, 128, "bfloat16", False, platform="tpu",
+                     window=64)
+
+    def test_the_functional_entry_asks_with_the_window(self, on_tpu):
+        from paddle_tpu.nn.functional import attention as attn
+        assert attn._use_pallas((2, 1024, 4, 128), 128, False,
+                                dtype="bfloat16", causal=True, seq_k=1024,
+                                window=256) is True
+        assert ar.decision_log()[-1][0] == (8, 1024, 1024, 128, "bfloat16",
+                                            True, 256)
+
+
 class TestFusedEpilogue:
     """The rmsnorm(attn+residual)*gamma epilogue fused into the flash
     flush must match the unfused composition exactly (incl. zero-padded
@@ -216,7 +292,7 @@ def _spy_on_flash(monkeypatch):
     the interpreter would take minutes at these sizes."""
     calls = []
 
-    def spy(q, k, v, causal=False, scale=None):
+    def spy(q, k, v, causal=False, scale=None, window=None):
         calls.append(q.shape)
         return jnp.zeros(q.shape, q.dtype)
     monkeypatch.setattr(fa, "flash_attention_bshd", spy)
@@ -275,7 +351,7 @@ class TestGates:
         attn._use_pallas((2, 512, 4, 64), 64, False, dtype="bfloat16",
                          causal=True, seq_k=768)
         assert ar.decision_log()[-1][0] == (8, 512, 768, 64, "bfloat16",
-                                            True)
+                                            True, None)
 
     def test_bias_still_forces_dense(self, on_tpu):
         from paddle_tpu.nn.functional import attention as attn

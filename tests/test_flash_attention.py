@@ -545,3 +545,212 @@ class TestMeshPartitioning:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)
         assert grads[0].sharding.spec == P("sharding", None, "mp", None)
+
+
+# --------------------------------------------------------------------------
+# the window: a band under the causal diagonal
+# --------------------------------------------------------------------------
+
+def _dense_band(q, k, v, window, scale, rep=1):
+    """Dense masked attention with the band written out, float64-free but
+    independent of the kernels: the reference of every windowed test."""
+    ke, ve = jnp.repeat(k, rep, 0), jnp.repeat(v, rep, 0)
+    sq, sk = q.shape[1], k.shape[1]
+    back = (np.arange(sq)[:, None] + (sk - sq)) - np.arange(sk)[None, :]
+    keep = (back >= 0) & (back < window)
+    s = jnp.einsum("bqd,bkd->bqk", q, ke) * scale
+    p = jax.nn.softmax(jnp.where(keep, s, -1e30), axis=-1)
+    return jnp.einsum("bqk,bkd->bqd", p, ve)
+
+
+WINDOW_TILES = {
+    # band-relative streamed axis over one-sub-block tiles: the index maps
+    # do the skipping
+    "one_sub": _same(64, 64, 64),
+    # several sub-blocks a tile, sizes all different between kernels: the
+    # in-kernel bounds do
+    "many_sub": Tiles(fwd=(128, 256, 32), dq=(32, 128, 64),
+                      dkv=(64, 256, 128)),
+    # the chooser's own
+    "chosen": None,
+}
+
+# (seq_q, seq_k, window): smaller than a sub-block; between tile sizes;
+# a multiple of the tiles; ragged lengths; one key; seq_k > seq_q
+# (bottom-right aligned); a window one short of the sequence
+WINDOW_CASES = [
+    (256, 256, 20), (256, 256, 100), (256, 256, 128), (384, 384, 129),
+    (300, 300, 130), (200, 200, 64), (256, 256, 1), (128, 384, 150),
+    (100, 260, 70), (256, 256, 255),
+]
+
+
+class TestWindowedFlash:
+    @pytest.mark.parametrize("tiles", list(WINDOW_TILES))
+    @pytest.mark.parametrize("sq,sk,window", WINDOW_CASES)
+    def test_forward_and_every_gradient_match_dense(self, sq, sk, window,
+                                                    tiles):
+        rs = np.random.RandomState(sq + sk + window)
+        bh, d, scale = 2, 16, 0.25
+        q, do = _rand(rs, bh, sq, d), _rand(rs, bh, sq, d)
+        k, v = _rand(rs, bh, sk, d), _rand(rs, bh, sk, d)
+        kw = dict(tiles=WINDOW_TILES[tiles], interpret=True, window=window)
+        out, lse = _flash_fwd_bhsd(q, k, v, True, scale, **kw)
+        want = _dense_band(q, k, v, window, scale)
+        np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)
+        grads = _flash_bwd_bhsd(q, k, v, out, lse, do, True, scale, **kw)
+        wants = jax.grad(lambda *a: jnp.sum(
+            _dense_band(*a, window, scale) * do), (0, 1, 2))(q, k, v)
+        for name, g, w in zip(("dq", "dk", "dv"), grads, wants):
+            np.testing.assert_allclose(g, w, rtol=5e-5, atol=5e-5,
+                                       err_msg=name)
+
+    @pytest.mark.parametrize("window", [24, 64, 200])
+    def test_gqa_eight_to_one(self, window):
+        rs = np.random.RandomState(window)
+        scale = 0.25
+        q, do = _rand(rs, 16, 192, 16), _rand(rs, 16, 192, 16)
+        k, v = _rand(rs, 2, 192, 16), _rand(rs, 2, 192, 16)
+        kw = dict(tiles=_same(64, 128, 64), interpret=True, q_per_kv=8,
+                  window=window)
+        out, lse = _flash_fwd_bhsd(q, k, v, True, scale, **kw)
+        np.testing.assert_allclose(
+            out, _dense_band(q, k, v, window, scale, rep=8), rtol=2e-5,
+            atol=2e-5)
+        grads = _flash_bwd_bhsd(q, k, v, out, lse, do, True, scale, **kw)
+        wants = jax.grad(lambda *a: jnp.sum(
+            _dense_band(*a, window, scale, rep=8) * do), (0, 1, 2))(q, k, v)
+        for name, g, w in zip(("dq", "dk", "dv"), grads, wants):
+            np.testing.assert_allclose(g, w, rtol=5e-5, atol=1e-4,
+                                       err_msg=name)
+
+    def test_public_entry_point_and_its_vjp(self):
+        """(batch, seq, heads, d) through flash_attention_bshd with GQA and
+        a head dim that is padded to the lane width."""
+        rs = np.random.RandomState(5)
+        q = _rand(rs, 2, 160, 4, 24)
+        k, v = _rand(rs, 2, 160, 2, 24), _rand(rs, 2, 160, 2, 24)
+
+        def dense(q_, k_, v_):
+            qt, kt, vt = (jnp.swapaxes(x, 1, 2).reshape(-1, 160, 24)
+                          for x in (q_, k_, v_))
+            out = _dense_band(qt, kt, vt, 48, 24 ** -0.5, rep=2)
+            return jnp.swapaxes(out.reshape(2, 4, 160, 24), 1, 2)
+
+        def flash(q_, k_, v_):
+            return flash_attention_bshd(q_, k_, v_, causal=True, window=48)
+
+        np.testing.assert_allclose(flash(q, k, v), dense(q, k, v),
+                                   rtol=2e-5, atol=2e-5)
+        got = jax.grad(lambda *a: jnp.sum(jnp.sin(flash(*a))), (0, 1, 2))(
+            q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(jnp.sin(dense(*a))), (0, 1, 2))(
+            q, k, v)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, rtol=5e-5, atol=5e-5)
+
+    @pytest.mark.parametrize("window", [None, 256, 300, 10 ** 6])
+    def test_a_window_that_hides_nothing_is_the_causal_program(self, window):
+        """window None, equal to and past the sequence: the same kernels
+        under the same names, tiles and grids, and the same numbers."""
+        q = jax.ShapeDtypeStruct((4, 256, 128), jnp.bfloat16)
+
+        def program(w):
+            text = str(jax.make_jaxpr(jax.grad(lambda q_, k_, v_: jnp.sum(
+                _flash_attention_bhsd(q_, k_, v_, True, 0.1, 1, w).astype(
+                    jnp.float32)), argnums=(0, 1, 2)))(q, q, q))
+            return re.sub(r"0x[0-9a-f]+", "", text)
+
+        assert fa.effective_window(window, True, 256) is None
+        assert program(window) == program(None)
+        assert sorted(set(re.findall(r"name=(fa\w+)", program(window)))) == [
+            "fa_bwd_dkv", "fa_bwd_dq", "fa_fwd"]
+        assert choose_tiles(256, 256, 128, 2, window=window) == \
+            choose_tiles(256, 256, 128, 2)
+        assert choose_tiles(2048, 2048, 128, 2, window=2048) == Tiles(
+            fwd=(1024, 2048, 1024), dq=(512, 2048, 512),
+            dkv=(512, 2048, 512))
+
+    def test_windowed_calls_carry_their_own_kernel_names(self):
+        q = jax.ShapeDtypeStruct((4, 512, 128), jnp.bfloat16)
+        text = str(jax.make_jaxpr(jax.grad(lambda q_, k_, v_: jnp.sum(
+            _flash_attention_bhsd(q_, k_, v_, True, 0.1, 1, 128).astype(
+                jnp.float32)), argnums=(0, 1, 2)))(q, q, q))
+        names = re.findall(r"name=(fa\w+)", text)
+        assert sorted(set(names)) == ["faw_bwd_dkv", "faw_bwd_dq", "faw_fwd"]
+        # and the grids are what Tiles.grid_steps reports
+        tiles = choose_tiles(512, 512, 128, 2, window=128)
+        grids = re.findall(r"GridMapping\(grid=\(([\d, ]+)\)", text)
+        assert dict(zip(names, (int(np.prod([int(n) for n in g.split(",")]))
+                                for g in grids))) == tiles.grid_steps(
+            4, 512, 512, 128)
+
+    def test_a_window_needs_the_causal_mask_and_a_key(self):
+        with pytest.raises(ValueError):
+            fa.effective_window(64, False, 256)
+        with pytest.raises(ValueError):
+            fa.effective_window(0, True, 256)
+        q = jnp.zeros((1, 128, 2, 16))
+        with pytest.raises(ValueError):
+            flash_attention_bshd(q, q, q, causal=False, window=8)
+
+    @pytest.mark.parametrize("seq", [2048, 4096, 16384, 20000])
+    @pytest.mark.parametrize("window", [100, 1024, 1500])
+    def test_visited_sub_blocks_are_bounded_by_the_band(self, seq, window):
+        """Whatever the length, a resident tile visits no more than the
+        band plus one sub-block at each edge: (resident + window) / sub + 2
+        sub-blocks, from the bounds the kernels' own loops take; and the
+        windowed grids never grow with the square of the length."""
+        tiles = choose_tiles(seq, seq, 128, 2, window=window)
+        for tile, bounds in ((tiles.fwd, fa._key_bounds),
+                             (tiles.dq, fa._key_bounds),
+                             (tiles.dkv, fa._query_bounds)):
+            res, streamed, sub = tile
+            n_sub = streamed // sub
+            limit = (res + window) / sub + 2
+            for i in range(-(-seq // res)):
+                visited = 0
+                for j in range(-(-seq // streamed)):
+                    lo, a, b, hi = bounds(i * res, res, j * streamed, sub,
+                                          n_sub, True, 0, seq, window)
+                    assert 0 <= lo <= a <= b <= hi <= n_sub
+                    visited += hi - lo
+                assert 1 <= visited <= limit, (tile, i, visited)
+        visited = tiles.visited_pairs(seq, seq, window)
+        needed = fa.band_pairs(seq, seq, window)
+        assert set(visited) == {"faw_fwd", "faw_bwd_dq", "faw_bwd_dkv"}
+        assert all(needed <= v for v in visited.values())
+        steps = tiles.grid_steps(1, seq, seq, window)
+        for name, tile in (("faw_fwd", tiles.fwd), ("faw_bwd_dq", tiles.dq),
+                           ("faw_bwd_dkv", tiles.dkv)):
+            per_resident = steps[name] / -(-seq // tile[0])
+            assert per_resident <= (tile[0] + window) / tile[1] + 2
+
+    def test_band_pairs_and_the_cells_counts(self):
+        assert fa.band_pairs(16384, 16384, 1024) == 16_253_440
+        assert fa.band_pairs(16384, 16384) == 134_225_920
+        assert fa.band_pairs(4, 6, 2) == 8          # offset 2: two keys each
+        assert fa.band_pairs(4, 6) == 3 + 4 + 5 + 6
+        # visited by the causal schedule without a window: the triangle at
+        # sub-block granularity, more than eight times the band's needs
+        causal = choose_tiles(16384, 16384, 128, 2).visited_pairs(
+            16384, 16384)
+        assert min(causal.values()) > 8 * 16_253_440
+        windowed = choose_tiles(16384, 16384, 128, 2, window=1024
+                                ).visited_pairs(16384, 16384, 1024)
+        assert max(windowed.values()) < 2.1 * 16_253_440
+
+    def test_dense_fallback_applies_the_band(self):
+        from paddle_tpu.nn.functional.attention import attention_bshd
+        rs = np.random.RandomState(2)
+        q = _rand(rs, 2, 48, 4, 8)
+        k, v = _rand(rs, 2, 48, 1, 8), _rand(rs, 2, 48, 1, 8)
+        got = attention_bshd(q, k, v, is_causal=True, window=5)  # off-TPU
+        qt = jnp.swapaxes(q, 1, 2).reshape(8, 48, 8)
+        kt, vt = (jnp.swapaxes(x, 1, 2).reshape(2, 48, 8) for x in (k, v))
+        want = _dense_band(qt, kt, vt, 5, 8 ** -0.5, rep=4)
+        np.testing.assert_allclose(
+            got, jnp.swapaxes(want.reshape(2, 4, 48, 8), 1, 2), rtol=2e-5,
+            atol=2e-5)
+        with pytest.raises(ValueError):
+            attention_bshd(q, k, v, is_causal=False, window=5)

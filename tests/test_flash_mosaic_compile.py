@@ -96,6 +96,76 @@ def test_forward_and_backward_compile(one_chip, case):
     assert "fa_bwd_dq" in text and "fa_bwd_dkv" in text
 
 
+def _win_tiles(rows, streamed):
+    tile = (rows[0], streamed, rows[1])
+    return fa.Tiles(fwd=tile, dq=tile, dkv=tile)
+
+
+# (id, bh, seq_q, seq_k, q_per_kv, window, tiles): the windowed kernels
+# (faw_*), whose streamed grid axis is relative to each resident tile's
+# band. The cell's shape at the chooser's tiles, then what the chip sweep
+# of tools/window_attention.py times, then the edges.
+WINDOW_CASES = [
+    ("mellum_cell_2x32x16k", 64, 16384, 16384, 8, 1024, None),
+    ("at_8k", 64, 8192, 8192, 8, 1024, None),
+    ("at_2k", 64, 2048, 2048, 8, 1024, None),
+    ("rows_256_streamed_1024", 8, 16384, 16384, 8, 1024,
+     _win_tiles((256, 256), 1024)),
+    ("rows_1024_512_streamed_2048", 8, 16384, 16384, 8, 1024,
+     _win_tiles((1024, 512), 2048)),
+    ("rows_1024_streamed_4096", 8, 16384, 16384, 8, 1024,
+     _win_tiles((1024, 1024), 4096)),
+    ("rows_512_whole_sequence", 8, 16384, 16384, 8, 1024,
+     _win_tiles((512, 512), 16384)),
+    ("window_under_a_sub_block", 8, 4096, 4096, 1, 100, None),
+    ("window_between_tiles", 8, 4096, 4096, 1, 1500, None),
+    ("ragged", 8, 3000, 3000, 1, 1024, None),
+    ("cross_length", 8, 1024, 4096, 1, 1024, None),
+]
+
+
+@pytest.mark.parametrize("case", WINDOW_CASES,
+                         ids=[c[0] for c in WINDOW_CASES])
+def test_windowed_forward_and_backward_compile(one_chip, case):
+    _, bh, sq, sk, rep, window, tiles = case
+    d = 128
+
+    def sds(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    q, kv = sds(bh, sq, d), sds(bh // rep, sk, d)
+
+    def fwd(q_, k_, v_):
+        return fa._flash_fwd_bhsd(q_, k_, v_, True, 0.088, tiles=tiles,
+                                  interpret=False, q_per_kv=rep,
+                                  window=window)
+
+    def bwd(q_, k_, v_, o_, lse_, g_):
+        return fa._flash_bwd_bhsd(q_, k_, v_, o_, lse_, g_, True, 0.088,
+                                  tiles=tiles, interpret=False, q_per_kv=rep,
+                                  window=window)
+
+    text = jax.jit(fwd).lower(q, kv, kv).compile().as_text()
+    assert "faw_fwd" in text and "fa_fwd" not in text
+    text = jax.jit(bwd).lower(q, kv, kv, q, sds(bh, sq, dt=jnp.float32),
+                              q).compile().as_text()
+    assert "faw_bwd_dq" in text and "faw_bwd_dkv" in text
+    assert "fa_bwd" not in text
+
+
+def test_a_window_that_hides_nothing_compiles_the_causal_kernels(one_chip):
+    """window >= seq_k is the causal call: the accepted cells' kernel
+    names, whatever window a caller passes."""
+    x = jax.ShapeDtypeStruct((8, 2048, 128), jnp.bfloat16, sharding=one_chip)
+
+    def fwd(q, k, v):
+        return fa._flash_fwd_bhsd(q, k, v, True, 0.088, interpret=False,
+                                  window=2048)
+
+    text = jax.jit(fwd).lower(x, x, x).compile().as_text()
+    assert "fa_fwd" in text and "faw_" not in text
+
+
 def test_wrappers_compile(one_chip):
     """head_dim 96 through the public wrapper (zero-padded to 128) with its
     gradient, and the RMSNorm epilogue riding the forward's flush."""
@@ -272,6 +342,66 @@ def test_retention_layer_compiles_and_no_expansion_reaches_memory(
     # read for vw's
     assert names == {"retn_read": 3, "retn_write": 2, "retn_back": 2}
     assert any("transpose(jvp(pt.retn.scan))" in line for line in calls)
+
+
+def test_mellum_mixers_compile_with_their_kernels_under_their_scopes(
+        one_chip, monkeypatch):
+    """One window layer's and one full layer's mixer of the Mellum cell
+    (16,384 positions, 32 query heads over 4 key-value heads, d 128,
+    bf16, window 1,024; models/mellum.py MellumAttention), forward and
+    backward with the sub-block's recomputation, for a described v5e. The
+    window layer's kernels are the faw_* ones and the full layer's the
+    fa_* ones, as the catalog names them, and the forward's and the
+    recomputation's are under pt.attn/pt.attn.<kind>: the cell's
+    `attn_window_*` and `attn_full_*` metrics tell the kinds apart by
+    these names. (Alone, a sub-block's forward and its recomputation are
+    one call: the step has both.)"""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.mellum import MellumAttention, MellumConfig
+    from paddle_tpu.observability.catalog import KERNEL_NAMES
+    from paddle_tpu.ops.pallas import attention_router
+    # the described chip is not jax.default_backend(): the rule and the
+    # kernels' interpret switch are told it is
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    attention_router.clear_routing_cache()
+    cfg = MellumConfig(num_hidden_layers=2, layer_types=[
+        "sliding_attention", "full_attention"], vocab_size=256,
+        dtype="bfloat16")
+    seen = {}
+    for kind in cfg.layer_types:
+        with paddle.LazyGuard():
+            sub = MellumAttention(cfg, kind)
+        names, tensors = zip(*sub.named_parameters())
+        params = {n.replace(".", "_"): jax.ShapeDtypeStruct(
+            tuple(t.shape), jnp.bfloat16, sharding=one_chip)
+            for n, t in zip(names, tensors)}
+        h = jax.ShapeDtypeStruct((1, 16384, cfg.hidden_size), jnp.bfloat16,
+                                 sharding=one_chip)
+
+        def loss(h_, p_, sub=sub):
+            block = jax.checkpoint(lambda x: sub._pure(x, **p_))
+            return jnp.sum(block(h_).astype(jnp.float32))
+
+        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            h, params).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if "custom-call(" in line and "tpu_custom_call" in line]
+        seen[kind] = collections.Counter()
+        scope = "pt.attn/pt.attn." + kind.split("_")[0]
+        for line in calls:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            kernel = op_name.split("/")[-2]
+            seen[kind][kernel] += 1
+            # the backward's too: the rematerialised sub-block's transpose
+            # is traced inside its scopes
+            assert scope in op_name, op_name
+    attention_router.clear_routing_cache()
+    assert set(seen["sliding_attention"]) == {
+        "faw_fwd", "faw_bwd_dq", "faw_bwd_dkv"}
+    assert set(seen["full_attention"]) == {
+        "fa_fwd", "fa_bwd_dq", "fa_bwd_dkv"}
+    assert set(seen["sliding_attention"]) | set(seen["full_attention"]) == {
+        n for n in KERNEL_NAMES if n.startswith("fa")}
 
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$")

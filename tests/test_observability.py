@@ -255,6 +255,27 @@ class TestCatalog:
         assert r.get(names[0]).value == 4 / 8.0
         assert r.get(names[1]).value == 1.0
 
+    def test_window_gauge_is_declared_and_is_the_rules_count(self):
+        """The windowed kernels' gauge: a gauge without labels in the
+        catalog and in OBSERVABILITY.md's table, and what it holds is the
+        Decision's visited / needed pairs at the window layers' shape."""
+        from paddle_tpu.ops.pallas.attention_router import route
+        name = "attn_window_visited_pair_share"
+        text = open(os.path.join(REPO, "OBSERVABILITY.md")).read()
+        assert obs_catalog.CATALOG[name][0] == "gauge"
+        assert obs_catalog.CATALOG[name][2] == ()
+        assert re.search(rf"^\| `{name}` \| gauge \| - \|", text,
+                         re.MULTILINE)
+        dec = route(64, 16384, 16384, 128, "bfloat16", True, platform="tpu",
+                    window=1024)
+        r = obs_metrics.MetricRegistry(enabled=True)
+        obs_catalog.register_all(r)
+        r.get(name).set(dec.visited_pair_share)
+        assert 1.0 <= r.get(name).value < 2.0
+        for scope in ("pt.attn.sliding", "pt.attn.full"):
+            assert re.search(rf"^\| `scope/{re.escape(scope)}` \| \S", text,
+                             re.MULTILINE)
+
     def test_retention_horizon_gauge_is_declared_and_read_off_the_gate(self):
         """The power-retention gauge: a gauge without labels in the catalog
         and in OBSERVABILITY.md's table, and what the model's own gate
@@ -288,7 +309,7 @@ class TestCatalog:
         kernel by that name."""
         text = open(os.path.join(REPO, "OBSERVABILITY.md")).read()
         assert re.search(rf"^\| `kernel/{name}` \| \S", text, re.MULTILINE)
-        assert name.startswith(("fa_", "retn_"))
+        assert name.startswith(("fa_", "faw_", "retn_"))
 
     def test_metric_refuses_unknown_names(self):
         with pytest.raises(KeyError, match="catalog"):

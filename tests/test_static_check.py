@@ -56,6 +56,14 @@ def test_unknown_rule_is_a_usage_error():
     ('from jax.experimental import pallas as pl\n'
      'pl.pallas_call(k, out_shape=s, name="unnamed_kernel")(x)\n',
      "trace-scopes"),
+    # a name chosen between two literals: both arms are held
+    ('from jax.experimental import pallas as pl\n'
+     'pl.pallas_call(k, out_shape=s,\n'
+     '               name="faw_fwd" if banded else "fa_forward")(x)\n',
+     "trace-scopes"),
+    ('import jax\n'
+     'with jax.named_scope("pt.attn.full" if full else "pt.attn.other"):\n'
+     '    pass\n', "trace-scopes"),
 ])
 def test_injected_violation_fails(tmp_path, source, rule):
     bad = tmp_path / "bad_module.py"
@@ -232,6 +240,9 @@ def test_trace_scopes_rule_passes_declared_names_and_other_prefixes(tmp_path):
         'import jax\n'
         'from jax.experimental import pallas as pl\n'
         'with jax.named_scope("pt.mlp"), jax.named_scope("kv.write"):\n'
-        '    pl.pallas_call(k, out_shape=s, name="fa_fwd")(x)\n')
+        '    pl.pallas_call(k, out_shape=s, name="fa_fwd")(x)\n'
+        'with jax.named_scope("pt.attn.sliding" if w else "pt.attn.full"):\n'
+        '    pl.pallas_call(k, out_shape=s,\n'
+        '                   name="faw_fwd" if w else "fa_fwd")(x)\n')
     r = _run("--rule", "trace-scopes", "--paths", str(ok), "--json")
     assert r.returncode == 0, r.stdout

@@ -40,6 +40,8 @@ SERVE_SCOPES = ("pt.embed", "pt.attn", "pt.mlp", "pt.head",
 HYBRID_SCOPES = ("pt.ssm", "pt.ssm.scan", "pt.moe", "pt.moe.route")
 # what models/brumby.py adds
 RETENTION_SCOPES = ("pt.retn", "pt.retn.scan")
+# what models/mellum.py adds: the attention kind, inside pt.attn
+KIND_SCOPES = ("pt.attn.sliding", "pt.attn.full")
 
 
 def _trainer():
@@ -307,11 +309,58 @@ def test_retention_train_step_lowers_with_the_mixer_scopes():
     assert any(not inner.search(h) for h in _scope_hits(text, "pt.retn"))
 
 
+def test_mellum_train_step_lowers_with_both_attention_kinds_scopes():
+    """A model that mixes window and full layers names each kind's whole
+    mixer inside pt.attn, forward and backward through the sub-block's
+    rematerialisation; its experts keep pt.moe and pt.moe.route (no
+    pt.mlp: there is no shared expert), the blocked head and loss pt.head
+    and pt.loss. The cell's two mixer metrics read these patterns."""
+    from paddle_tpu.framework.random import get_rng_state
+    from paddle_tpu.models.mellum import mellum_tiny
+    from paddle_tpu.parallel import DP_ONLY_RULES
+    paddle.seed(0)
+    model = mellum_tiny()
+    opt = optimizer.AdamW(1e-3, parameters=model.parameters())
+    trainer = SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
+                          DP_ONLY_RULES)
+    ids = np.zeros((1, 16), np.int32)
+    batch = trainer._batch_arrays((ids, ids))
+    with jax.set_mesh(trainer.mesh):
+        text = trainer._compiled.lower(
+            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
+            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
+        ).as_text(debug_info=True)
+    for scope in KIND_SCOPES + ("pt.moe", "pt.moe.route") + TRAIN_SCOPES:
+        if scope == "pt.mlp":
+            assert not _scope_hits(text, scope)     # no dense FFN here
+            continue
+        hits = _scope_hits(text, scope)
+        assert hits, scope
+        if scope != "pt.opt":
+            assert any("transpose(jvp(" in h for h in hits), scope
+    sliding = re.compile(r"\bpt\.attn\.sliding\b")
+    full = re.compile(r"\bpt\.attn\.full\b")
+    for kind, rx, other in (("sliding", sliding, full),
+                            ("full", full, sliding)):
+        hits = _scope_hits(text, f"pt.attn.{kind}")
+        # nested: "pt.attn/pt.attn.<kind>", or with the transformation
+        # that wrapped the outer scope, "jvp(pt.attn)/pt.attn.<kind>"
+        nested = re.compile(r"pt\.attn\)*/pt\.attn\." + kind)
+        assert all(nested.search(h) for h in hits)
+        assert all(rx.search(h) and not other.search(h) for h in hits)
+    # the whole mixer is inside its kind's scope: nothing of pt.attn is
+    # outside both
+    assert all(sliding.search(h) or full.search(h)
+               for h in _scope_hits(text, "pt.attn"))
+    assert any("pt.moe/pt.moe.route" in h
+               for h in _scope_hits(text, "pt.moe.route"))
+
+
 def test_every_declared_scope_is_held_by_a_test_and_none_else():
     """catalog.py TRACE_SCOPES against the scopes these tests look for in
     lowered programs, both directions."""
     assert set(TRAIN_SCOPES) | set(SERVE_SCOPES) | set(HYBRID_SCOPES) \
-        | set(RETENTION_SCOPES) == set(TRACE_SCOPES)
+        | set(RETENTION_SCOPES) | set(KIND_SCOPES) == set(TRACE_SCOPES)
 
 
 @pytest.fixture(scope="module")

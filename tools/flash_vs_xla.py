@@ -53,12 +53,15 @@ def log(msg):
 
 
 def amortized(step_fn, n=N_ITERS):
-    """n iterations inside ONE compiled program; the carry data-flows into
-    each iteration so the body cannot be CSE'd/hoisted."""
+    """n iterations of step_fn(*arrays) inside ONE compiled program; the
+    carry data-flows into the first array of each iteration so the body
+    cannot be CSE'd/hoisted. An array the step needs is best an ARGUMENT:
+    one closed over is a constant of the executable (268 MB each at 64 x
+    16,384 x 128, a minute of compile a program)."""
     @jax.jit
-    def run(q, k, v):
+    def run(q, *rest):
         def body(carry, _):
-            s = step_fn(q + carry, k, v)
+            s = step_fn(q + carry, *rest)
             return (s * 0).astype(q.dtype), None
         c, _ = jax.lax.scan(body, jnp.zeros((), q.dtype), None, length=n)
         return c

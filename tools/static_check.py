@@ -422,6 +422,18 @@ def rule_profiler_phases(ctx):
     return out
 
 
+def _str_literals(node):
+    """The string literals an argument can be: a constant, or a conditional
+    expression both of whose arms are (``"faw_fwd" if banded else
+    "fa_fwd"``); [] for anything else."""
+    if isinstance(node, ast.Constant) and isinstance(node.value, str):
+        return [node.value]
+    if isinstance(node, ast.IfExp):
+        arms = _str_literals(node.body) + _str_literals(node.orelse)
+        return arms if len(arms) == 2 else []
+    return []
+
+
 def rule_trace_scopes(ctx):
     """The names the program gives its work on a device trace are closed
     like the metric catalog (observability/catalog.py TRACE_SCOPES and
@@ -439,27 +451,26 @@ def rule_trace_scopes(ctx):
             if not isinstance(node, ast.Call):
                 continue
             callee = _callee(node)
-            if callee == "named_scope" and node.args \
-                    and isinstance(node.args[0], ast.Constant) \
-                    and isinstance(node.args[0].value, str) \
-                    and node.args[0].value.startswith("pt."):
-                name = node.args[0].value
-                used_scopes.add(name)
-                if name not in ctx.trace_scopes:
-                    out.append(Violation(
-                        "trace-scopes", path, node.lineno,
-                        f"named_scope({name!r}) is not in {CATALOG_PY} "
-                        "TRACE_SCOPES"))
+            if callee == "named_scope" and node.args:
+                for name in _str_literals(node.args[0]):
+                    if not name.startswith("pt."):
+                        continue
+                    used_scopes.add(name)
+                    if name not in ctx.trace_scopes:
+                        out.append(Violation(
+                            "trace-scopes", path, node.lineno,
+                            f"named_scope({name!r}) is not in {CATALOG_PY} "
+                            "TRACE_SCOPES"))
             elif callee == "pallas_call":
                 for kw in node.keywords:
-                    if kw.arg == "name" \
-                            and isinstance(kw.value, ast.Constant) \
-                            and isinstance(kw.value.value, str):
-                        used_kernels.add(kw.value.value)
-                        if kw.value.value not in ctx.kernel_names:
+                    if kw.arg != "name":
+                        continue
+                    for name in _str_literals(kw.value):
+                        used_kernels.add(name)
+                        if name not in ctx.kernel_names:
                             out.append(Violation(
                                 "trace-scopes", path, node.lineno,
-                                f"pallas_call(name={kw.value.value!r}) is "
+                                f"pallas_call(name={name!r}) is "
                                 f"not in {CATALOG_PY} KERNEL_NAMES"))
     # reverse direction only on a scan that includes the registry's own
     # file (a --paths run on one module must not fire "never entered")

@@ -326,13 +326,62 @@ def _epilogue_case(b, seq, h, d):
     return {"case": "rms_epilogue", "out_max_abs_err": e}
 
 
+def _grouped_matmul_case(rows, k, n, groups, tiles=None):
+    """`parallel/moe.py grouped_matmul` (bf16; on the chip, at widths with
+    an entry in its `_GMM_TILES`, jax's Pallas grouped matmul: a fall-back
+    to `lax.ragged_dot` there fails the case) against `lax.ragged_dot`,
+    the product and both gradients, on uneven groups, one of them empty,
+    that leave the buffer's last rows to no group. tiles: the CPU test's,
+    interpreted."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.parallel import moe
+
+    rs = np.random.RandomState(9)
+    share = rs.dirichlet(np.full(groups - 1, 8.0)) * rows * 0.6
+    sizes = jnp.asarray([0] + [int(s) for s in share], jnp.int32)
+    live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+    lhs = jnp.asarray(rs.randn(rows, k), jnp.bfloat16)
+    rhs = jnp.asarray(rs.randn(groups, k, n) * 0.02, jnp.bfloat16)
+    cot = jnp.where(live, jnp.asarray(rs.randn(rows, n), jnp.bfloat16), 0)
+    if tiles is None and moe._gmm_tiles(lhs, rhs) is None:
+        raise AssertionError(f"grouped_matmul fell back to lax.ragged_dot "
+                             f"at {lhs.shape} x {rhs.shape}")
+
+    def three(product):
+        out, back = jax.vjp(lambda a, b: product(a, b, sizes), lhs, rhs)
+        d_lhs, d_rhs = back(cot)
+        return [jnp.where(live, out, 0), jnp.where(live, d_lhs, 0), d_rhs]
+
+    ours = moe.grouped_matmul if tiles is None else (
+        lambda a, b, s: moe._gmm(a, b, s, tiles, True))
+    errs = {}
+    for name, got, want in zip(("out", "d_rows", "d_weights"),
+                               jax.jit(lambda: three(ours))(),
+                               jax.jit(lambda: three(jax.lax.ragged_dot))()):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        errs[name] = float(jnp.max(jnp.abs(got - want))
+                           / jnp.max(jnp.abs(want)))
+    log(f"kernel grouped_matmul {rows}x{k}x{n}, {groups} groups "
+        f"{[int(s) for s in sizes]}: {errs}")
+    # two bf16 roundings of float32 sums in different orders
+    bad = {name: e for name, e in errs.items() if not e <= KERNEL_GRAD_TOL}
+    if bad:
+        raise AssertionError(f"grouped_matmul differs from lax.ragged_dot: "
+                             f"{bad} > {KERNEL_GRAD_TOL}")
+    return {"case": "grouped_matmul", "max_rel_err": errs}
+
+
 def kernel_phase(bh=TRAIN_BATCH * 16, seq=TRAIN_SEQ, d=128, small_seq=512,
-                 gqa=(16, 4), interpret=False):
+                 gqa=(16, 4), interpret=False,
+                 grouped=(8192, 2304, 1792, 16)):
     """Compile (interpret=False on the chip) and run the bf16 flash kernels
     at the trainer's attention shape with the tiles the chooser hands out,
     then with one-lane-tile sub-blocks inside a streamed tile shorter than
     the sequence (the clamped index maps and the loops' bounds both at
-    work), GQA, the d=96 zero-pad and the RMS epilogue once each."""
+    work), GQA, the d=96 zero-pad and the RMS epilogue once each; then the
+    routed experts' grouped matmul at the Mellum cell's first product."""
     from paddle_tpu.ops.pallas.flash_attention import Tiles
 
     log(f"kernel phase: bh={bh} seq={seq} d={d} bf16 causal")
@@ -345,6 +394,7 @@ def kernel_phase(bh=TRAIN_BATCH * 16, seq=TRAIN_SEQ, d=128, small_seq=512,
                         3),
         _pad96_case(2, small_seq, 4),
         _epilogue_case(2, small_seq, 4, d),
+        _grouped_matmul_case(*grouped),
     ]
     return {"cases": cases}
 

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 __all__ = ["moe_dispatch_combine", "ExpertParallelMoE", "gshard_dispatch",
-           "route_top_k", "dropless_moe"]
+           "route_top_k", "grouped_matmul", "dropless_moe"]
 
 
 def gshard_dispatch(x, gate_logits, num_experts, capacity, top_k=2):
@@ -316,6 +316,79 @@ def _in_slot_order_bwd(rows, res, g):
 _in_slot_order.defvjp(_in_slot_order_fwd, _in_slot_order_bwd)
 
 
+# Tiles (rows, k, n) of jax's Pallas grouped matmul (megablox `gmm`, and
+# `tgmm` for the weights' gradient) for bf16 on a TPU, by the (k, n) of
+# one expert's matrix: (forward, the rows' gradient, the weights'
+# gradient). Each is its product's fastest of `tools/moe_rows_bench.py
+# --sweep` on a v5e (PERF.md section 6, PR 35): for `gmm` the contraction
+# whole, so that no accumulator is read back, beside as much of the
+# matrix as VMEM holds twice; for `tgmm` the largest float32 tile of the
+# gradient that fits. XLA's own kernel behind `lax.ragged_dot` runs tiles
+# of 512 x 256 x 256 at these widths and is bound by its grid steps, not
+# the MXU: over the six products at 512 rows a group 31 TFLOP/s where
+# these read 91 or more, at 2,048 rows 51 and 142. Widths that were not
+# measured keep `lax.ragged_dot`.
+_GMM_TILES = {
+    (2304, 1792): ((256, 2304, 896), (256, 1792, 1152), (256, 1152, 896)),
+    (896, 2304): ((256, 896, 2304), (256, 2304, 896), (256, 896, 1152)),
+}
+
+
+def _gmm_tiles(lhs, rhs):
+    """_GMM_TILES' entry for these operands of `grouped_matmul`, or None
+    where `lax.ragged_dot` stays: off a TPU, in another dtype, at widths
+    with no entry, or where a tile does not divide the buffer's rows."""
+    tiles = _GMM_TILES.get(tuple(rhs.shape[1:]))
+    if (tiles is None or jax.default_backend() != "tpu"
+            or lhs.dtype != jnp.bfloat16 or rhs.dtype != jnp.bfloat16
+            or any(lhs.shape[0] % rows for rows, _, _ in tiles)):
+        return None
+    return tiles
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm(lhs, rhs, sizes, tiles, interpret):
+    """`grouped_matmul` through the Pallas kernels, each of the three
+    products at its own tiles (megablox's own `custom_vjp` gives all three
+    the forward's). float32 accumulation, results in the operands' dtype:
+    what `lax.ragged_dot` gives. Rows past the last group are not written,
+    forward or backward, as with XLA's kernel."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    return gmm(lhs, rhs, sizes, lhs.dtype, tiles[0], interpret=interpret)
+
+
+def _gmm_fwd(lhs, rhs, sizes, tiles, interpret):
+    return _gmm(lhs, rhs, sizes, tiles, interpret), (lhs, rhs, sizes)
+
+
+def _gmm_bwd(tiles, interpret, res, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+    lhs, rhs, sizes = res
+    d_lhs = gmm(g, rhs, sizes, lhs.dtype, tiles[1], transpose_rhs=True,
+                interpret=interpret)
+    # tgmm takes its first operand (k, rows) and turns it back itself
+    d_rhs = tgmm(lhs.swapaxes(0, 1), g, sizes, rhs.dtype, tiles[2],
+                 interpret=interpret)
+    return d_lhs, d_rhs, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def grouped_matmul(lhs, rhs, sizes):
+    """out[rows of group g] = lhs[rows of group g] @ rhs[g]: lhs [rows, k]
+    sorted by group, rhs [groups, k, n], sizes [groups] the rows of each.
+    Chosen from the operands' shapes and dtype as the call is traced:
+    jax's Pallas grouped matmul at the tiles of `_GMM_TILES` where there
+    is an entry, `lax.ragged_dot` (XLA's kernel on a TPU) everywhere
+    else. The same sums either way."""
+    tiles = _gmm_tiles(lhs, rhs)
+    if tiles is None:
+        return jax.lax.ragged_dot(lhs, rhs, sizes)
+    # off a TPU (a test that hands out tiles) the kernels are interpreted
+    return _gmm(lhs, rhs, sizes, tiles, jax.default_backend() != "tpu")
+
+
 def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held,
                  differentiate_routing=True):
     """The held experts' part of a routed gated-MLP layer.
@@ -331,16 +404,18 @@ def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held,
     What the experts that are not held would add is left out: over the
     shards of an expert-parallel group the parts add up to the whole layer
     (tests/test_granite_moe_hybrid.py). Nothing is dropped: assignments
-    are sorted by expert, and the grouped product (`lax.ragged_dot`, which
-    the TPU compiler turns into a grouped-matmul kernel that visits only
-    the tiles that hold rows: on the chip a call costs the same in a
-    buffer of 4,096 rows as in one of 18,432, and its rate follows the
-    rows a group has, PERF.md section 6, PR 29) has a row for every
-    assignment that can land here. Rows past the last assignment are not
-    written by that kernel and may hold anything, NaN too: nothing may
-    carry them on, forward or backward. No part of the layer is a
-    scatter, and no gather fetches single numbers: a TPU pays both by the
-    index. Component scope `pt.moe.route` holds what is not expert work.
+    are sorted by expert, and the grouped product (`grouped_matmul`: a
+    kernel that visits only the tiles that hold rows, jax's Pallas one
+    at the widths it was measured at, else `lax.ragged_dot`, which the
+    TPU compiler turns into its own: on the chip a call costs the same in
+    a buffer of 4,096 rows as in one of 18,432, and its rate follows the
+    rows a group has, PERF.md section 6, PR 29 and PR 35) has a row for
+    every assignment that can land here. Rows past the last assignment
+    are not written by those kernels and may hold anything, NaN too:
+    nothing may carry them on, forward or backward. No part of the layer
+    is a scatter, and no gather fetches single numbers: a TPU pays both
+    by the index. Component scope `pt.moe.route` holds what is not expert
+    work.
 
     `differentiate_routing=False` makes the gates constants of the
     backward pass: the router's weight gets no gradient and the routing
@@ -367,9 +442,9 @@ def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held,
         xs = _dispatch(x, token, slot)
     inter = w_out.shape[1]
     # rows past the last assignment hold whatever the kernel left there
-    h = jnp.where(live, jax.lax.ragged_dot(xs, w_in, sizes), 0)
+    h = jnp.where(live, grouped_matmul(xs, w_in, sizes), 0)
     act = (jax.nn.silu(h[:, :inter].astype(jnp.float32))
            * h[:, inter:].astype(jnp.float32) * gate_rows).astype(x.dtype)
-    y = jax.lax.ragged_dot(act, w_out, sizes)
+    y = grouped_matmul(act, w_out, sizes)
     with jax.named_scope("pt.moe.route"):
         return _combine(y, token, slot)

@@ -34,17 +34,29 @@ class TestPhasesAtTinySizes:
         assert out.out == "" and "no TPU" in out.err
 
     def test_kernel_phase(self):
-        res = chip_smoke.kernel_phase(bh=1, seq=256, small_seq=128,
-                                      gqa=(4, 2), interpret=True)
+        res = chip_smoke.kernel_phase(
+            bh=1, seq=256, small_seq=128, gqa=(4, 2), interpret=True,
+            grouped=(512, 160, 256, 4, ((128, 128, 128),) * 3))
         names = [c["case"] for c in res["cases"]]
         assert names == ["chooser_tiles", "small_tiles", "gqa",
-                         "d96_zero_pad", "rms_epilogue"]
+                         "d96_zero_pad", "rms_epilogue", "grouped_matmul"]
         # one causal schedule, whatever the tiles: each case names the
         # tiles it ran and the grid steps they gave
         assert res["cases"][0]["grid_steps"] == {
             "fa_fwd": 1, "fa_bwd_dq": 1, "fa_bwd_dkv": 1}
         assert res["cases"][1]["tiles"]["dkv"] == (128, 256, 128)
         assert res["cases"][1]["grid_steps"]["fa_fwd"] == 2
+
+    def test_grouped_matmul_case_fails_on_a_fall_back_and_a_wrong_kernel(
+            self, monkeypatch):
+        from paddle_tpu.parallel import moe
+        with pytest.raises(AssertionError, match="fell back"):
+            chip_smoke._grouped_matmul_case(512, 160, 256, 4)   # no TPU
+        real = moe._gmm
+        monkeypatch.setattr(moe, "_gmm", lambda *a: real(*a) * 1.2)
+        with pytest.raises(AssertionError, match="differs from"):
+            chip_smoke._grouped_matmul_case(512, 160, 256, 4,
+                                            ((128, 128, 128),) * 3)
 
     def test_kernel_case_fails_on_a_wrong_kernel(self, monkeypatch):
         from paddle_tpu.ops.pallas import flash_attention as fa

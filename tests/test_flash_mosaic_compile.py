@@ -225,8 +225,11 @@ def test_nope_gqa_at_8k_compiles_and_routes_to_the_kernels(one_chip):
     assert "fa_bwd_dq" in text and "fa_bwd_dkv" in text
 
 
-def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(one_chip):
-    """`lax.ragged_dot` in parallel/moe.py dropless_moe becomes the TPU
+def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(
+        one_chip, monkeypatch):
+    """`lax.ragged_dot` in parallel/moe.py dropless_moe (what its
+    `grouped_matmul` keeps at the granite cell's widths, on a TPU too:
+    they have no entry in `_GMM_TILES`) becomes the TPU
     compiler's own grouped-matmul kernel, whose op_name is the kernel's
     name in place of the program's name stack, so the scope `pt.moe` is
     lost on it. benchmark/layer_metrics/moe_time_share.json and
@@ -240,6 +243,9 @@ def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(one_chip):
     import json
     import os
     from paddle_tpu.parallel.moe import dropless_moe
+    # the described chip is not jax.default_backend(): the choice of the
+    # grouped matmul is told it is
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def sds(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
@@ -270,6 +276,50 @@ def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(one_chip):
             assert rx.search(op_name), (name, op_name)
             assert "pt.moe" not in op_name      # or the first alternative
             # of moe_time_share's pattern would count the kernel twice
+
+
+def test_mellum_moe_compiles_with_the_pallas_grouped_matmul(one_chip,
+                                                            monkeypatch):
+    """The Mellum cell's routed experts (models/mellum.py MellumSparseMoe:
+    hidden 2304, 16 held of 64 of width 896, top-8, the routing not
+    differentiated, bf16) over its step's 2 x 16,384 tokens, forward and
+    backward with the blocks' recomputation, for a described v5e. At
+    these widths `parallel/moe.py grouped_matmul` takes jax's Pallas
+    kernels at the tiles of `_GMM_TILES`, which Mosaic has to fit into
+    VMEM beside the step: seven products a block (x W_in forward and
+    recomputed, act W_out, two gradients each), every one under `pt.moe`,
+    none left to XLA's `ragged-dot`. Outside the kernels nothing
+    scatters but the kernels' own metadata (a tile count a group)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.mellum import MellumConfig, MellumSparseMoe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = MellumConfig(num_hidden_layers=1, vocab_size=256, dtype="bfloat16",
+                       experts_held=(0, 16), differentiate_routing=False)
+    with paddle.LazyGuard():
+        sub = MellumSparseMoe(cfg)
+    names, tensors = zip(*sub.named_parameters())
+    params = {n.replace(".", "_"): jax.ShapeDtypeStruct(
+        tuple(t.shape), jnp.bfloat16, sharding=one_chip)
+        for n, t in zip(names, tensors)}
+    h = jax.ShapeDtypeStruct((2, 16384, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(h_, p_):
+        block = jax.checkpoint(lambda x: sub._pure(x, **p_))
+        return jnp.sum(sub._over(block, h_).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        h, params).compile().as_text()
+    kernels = collections.Counter()
+    for line in text.splitlines():
+        if "custom-call(" in line and "tpu_custom_call" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "pt.moe" in op_name and "ragged" not in op_name, op_name
+            kernels[re.search(r"jit\((\w+)\)/pallas_call", op_name).group(1)] \
+                += 1
+    assert kernels == {"gmm": 5, "tgmm": 2}
+    for scattered in re.findall(r"= \w+\[(\d*)\]\S* scatter\(", text):
+        assert int(scattered or 1) <= 1024
 
 
 @pytest.mark.parametrize("rows, weighted", [(5120, True), (1024, False)],

@@ -319,6 +319,59 @@ def test_rows_that_hold_no_assignment_may_hold_anything(monkeypatch):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
+def test_pallas_grouped_matmul_is_ragged_dot_forward_and_backward():
+    """`moe._gmm` (jax's Pallas grouped matmul, interpreted here, each of
+    its three products at its own tiles) against `lax.ragged_dot` and its
+    two gradients: uneven groups, one empty, a k and an n that the tiles
+    do not divide, and NaN in the rows past the last group, which neither
+    reads and neither has to write."""
+    rs = np.random.RandomState(35)
+    sizes = jnp.asarray([100, 0, 156, 71], jnp.int32)
+    live = np.arange(512) < int(sizes.sum())
+    lhs = rs.randn(512, 160).astype(np.float32)
+    lhs[~live] = np.nan
+    lhs = jnp.asarray(lhs)
+    rhs = jnp.asarray(rs.randn(4, 160, 200).astype(np.float32))
+    cot = jnp.asarray(np.where(live[:, None], rs.randn(512, 200), np.nan)
+                      .astype(np.float32))
+    tiles = ((128, 128, 128), (256, 256, 128), (128, 128, 256))
+
+    def run(product):
+        out, back = jax.vjp(product, lhs, rhs)
+        return (out,) + back(cot)
+
+    with jax.default_matmul_precision("highest"):
+        want = run(lambda a, b: jax.lax.ragged_dot(a, b, sizes))
+        got = run(lambda a, b: moe._gmm(a, b, sizes, tiles, True))
+    for g, w, rows in zip(got, want, (live, live, slice(None))):
+        g, w = np.asarray(g)[rows], np.asarray(w)[rows]
+        assert np.isfinite(w).all() and np.abs(w).max() > 1
+        # float32 both: the order of a 160- or 327-term sum
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
+    assert not np.asarray(got[2])[1].any()       # the empty group's
+
+
+@pytest.mark.parametrize("rows, k, n, dtype, backend, picked", [
+    (32768, 2304, 1792, "bfloat16", "tpu", True),    # the Mellum cell's
+    (32768, 896, 2304, "bfloat16", "tpu", True),     # two products
+    (18432, 4096, 1536, "bfloat16", "tpu", False),   # granite's: no entry
+    (32768, 2304, 1792, "float32", "tpu", False),
+    (32768 + 128, 2304, 1792, "bfloat16", "tpu", False),  # half a tile over
+    (32768, 2304, 1792, "bfloat16", "cpu", False),
+], ids=["mellum_in", "mellum_out", "granite", "float32", "odd_rows", "cpu"])
+def test_the_pallas_grouped_matmul_is_chosen_by_shape_dtype_and_backend(
+        monkeypatch, rows, k, n, dtype, backend, picked):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    tiles = moe._gmm_tiles(jax.ShapeDtypeStruct((rows, k), dtype),
+                           jax.ShapeDtypeStruct((16, k, n), dtype))
+    assert (tiles is not None) == picked
+    for product, (tm, tk, tn) in zip((
+            (rows, k, n), (rows, n, k), (rows, k, n)), tiles or ()):
+        # whole 128-lane tiles that divide what they cut
+        assert product[0] % tm == 0 and tk % 128 == 0 and tn % 128 == 0
+        assert product[1] % tk == 0 and product[2] % tn == 0
+
+
 def test_gates_are_the_softmax_of_the_top_k_logits():
     """route_top_k picks the chosen logits out by comparison; values and
     gradients are those of lax.top_k's own values."""

@@ -141,6 +141,59 @@ def test_logits_without_labels_and_the_blocked_loss_agree(tiny, monkeypatch):
     assert np.abs(np.asarray(again - logits)).max() < 1e-5
 
 
+def test_loss_and_every_gradient_are_the_same_at_two_moe_blocks(
+        tiny, monkeypatch):
+    """The experts over all 58 tokens in one call and over two blocks of
+    an odd 29: the same sums, an expert's gradient added over two blocks
+    in place of one."""
+    model, params, ids = tiny
+    results = []
+    for block in (4096, 29):
+        monkeypatch.setattr(mellum, "MOE_TOKEN_BLOCK", block)
+        results.append(jax.jit(jax.value_and_grad(make_loss_fn(model)))(
+            params, (ids, ids), None))
+    (one, g_one), (two, g_two) = results
+    # the tolerances of the comparison with the reference above
+    assert abs(float(one) - float(two)) < 1e-5
+    assert set(g_one) == set(g_two)
+    for name in sorted(g_one):
+        a, b = np.asarray(g_one[name]), np.asarray(g_two[name])
+        assert np.abs(a - b).max() <= 2e-4 * np.abs(a).max() + 1e-8, name
+
+
+def test_loss_and_gradients_through_the_pallas_grouped_matmul(
+        tiny, monkeypatch):
+    """The whole model with its experts' products through jax's Pallas
+    grouped matmul (interpreted here; on a TPU the Mellum cell's widths
+    choose it, parallel/moe.py _GMM_TILES) and through lax.ragged_dot:
+    2 x 32 tokens, so that the sorted buffer's 256 rows are whole tiles."""
+    from paddle_tpu.parallel import moe
+    model, params, _ = tiny
+    ids = np.random.RandomState(5).randint(0, 256, (2, 32)).astype(np.int32)
+
+    def step():
+        return jax.jit(jax.value_and_grad(make_loss_fn(model)))(
+            params, (ids, ids), None)
+
+    want, want_grads = step()
+    calls = []
+
+    def tiles(lhs, rhs):
+        calls.append((lhs.shape, rhs.shape))
+        return ((128, 128, 128),) * 3
+
+    monkeypatch.setattr(moe, "_gmm_tiles", tiles)
+    got, grads = step()
+    # every layer's two products, traced forward and rematerialised
+    assert len(calls) >= 2 * 4 and {c[0][0] for c in calls} == {256}
+    # the tolerances of the comparison with the reference above: the same
+    # float32 sums in another order
+    assert abs(float(got) - float(want)) < 1e-5
+    for name in sorted(want_grads):
+        g, r = np.asarray(grads[name]), np.asarray(want_grads[name])
+        assert np.abs(g - r).max() <= 2e-4 * np.abs(r).max() + 1e-8, name
+
+
 def _block_errors(model, params, ids, cfg, dtype=jnp.float32):
     """What the benchmark's family loader does on the chip, at the test
     size: {"<layer>.<kind>": error of the program's residual update against

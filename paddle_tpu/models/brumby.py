@@ -137,18 +137,28 @@ class BrumbyRetention(SubBlock):
         with jax.named_scope("pt.retn"):
             b, s, _ = h.shape
             d = c.head_dim
-            x = _rms(h, input_layernorm_weight, c.rms_norm_eps)
-            q = (x @ q_proj_weight).reshape(b, s, c.num_attention_heads, d)
-            k = (x @ k_proj_weight).reshape(b, s, c.num_key_value_heads, d)
-            v = (x @ v_proj_weight).reshape(b, s, c.num_key_value_heads, d)
-            log_g = retention_log_gate(h, input_layernorm_weight,
-                                       g_proj_weight, g_proj_bias,
-                                       c.rms_norm_eps)
-            q = _rope(_rms(q, q_norm_weight, c.rms_norm_eps), c.rope_theta)
-            k = _rope(_rms(k, k_norm_weight, c.rms_norm_eps), c.rope_theta)
+            # the mixer's parts, each a scope of its own inside pt.retn
+            # (catalog.py TRACE_SCOPES); the retention enters pt.retn.scan
+            with jax.named_scope("pt.retn.in"):
+                x = _rms(h, input_layernorm_weight, c.rms_norm_eps)
+                q = (x @ q_proj_weight).reshape(
+                    b, s, c.num_attention_heads, d)
+                k = (x @ k_proj_weight).reshape(
+                    b, s, c.num_key_value_heads, d)
+                v = (x @ v_proj_weight).reshape(
+                    b, s, c.num_key_value_heads, d)
+                log_g = retention_log_gate(h, input_layernorm_weight,
+                                           g_proj_weight, g_proj_bias,
+                                           c.rms_norm_eps)
+            with jax.named_scope("pt.retn.pos"):
+                q = _rope(_rms(q, q_norm_weight, c.rms_norm_eps),
+                          c.rope_theta)
+                k = _rope(_rms(k, k_norm_weight, c.rms_norm_eps),
+                          c.rope_theta)
             y = power_retention(q, k, v, log_g, c.retention_chunk,
                                 c.retention_eps)
-            return h + y.reshape(b, s, -1) @ o_proj_weight
+            with jax.named_scope("pt.retn.out"):
+                return h + y.reshape(b, s, -1) @ o_proj_weight
 
 
 class BrumbyMLP(SubBlock):

@@ -57,12 +57,16 @@ class GPTAttention(nn.Layer):
 
     def forward(self, x):
         b, s = x.shape[0], x.shape[1]
-        qkv = reshape(self.qkv_proj(x), [b, s, 3, self.num_heads, self.head_dim])
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+        with jax.named_scope("pt.attn.in"):
+            qkv = reshape(self.qkv_proj(x),
+                          [b, s, 3, self.num_heads, self.head_dim])
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         out = F.scaled_dot_product_attention(q, k, v, is_causal=True,
                                              dropout_p=self.dropout,
                                              training=self.training)
-        return self.out_proj(reshape(out, [b, s, self.num_heads * self.head_dim]))
+        with jax.named_scope("pt.attn.out"):
+            return self.out_proj(
+                reshape(out, [b, s, self.num_heads * self.head_dim]))
 
 
 class GPTBlock(nn.Layer):
@@ -80,9 +84,15 @@ class GPTBlock(nn.Layer):
 
     def forward(self, x):
         # component scopes (observability/catalog.py TRACE_SCOPES): the
-        # names a device trace attributes this block's operations to
+        # names a device trace attributes this block's operations to; the
+        # mixer's parts (pt.attn.in: norm and qkv_proj; pt.attn.out:
+        # out_proj and the residual) nest in its scope
         with jax.named_scope("pt.attn"):
-            x = x + self.attn(self.ln_1(x))
+            with jax.named_scope("pt.attn.in"):
+                normed = self.ln_1(x)
+            mixed = self.attn(normed)
+            with jax.named_scope("pt.attn.out"):
+                x = x + mixed
         with jax.named_scope("pt.mlp"):
             # the activation sits between two matmuls and a GPT step has
             # memory to spare: evaluate it once a layer (ops/gelu_once.py)

@@ -195,21 +195,27 @@ class GraniteMambaMixer(_SubBlock):
             b, s, _ = h.shape
             inter, n, heads = (c.mamba_intermediate, c.mamba_d_state,
                                c.mamba_n_heads)
-            x = _rms(h, input_layernorm_weight, c.rms_norm_eps)
-            zxbcdt = x @ in_proj_weight
-            z = zxbcdt[..., :inter]
-            xbc = zxbcdt[..., inter:inter + c.mamba_conv_dim]
-            dt = zxbcdt[..., inter + c.mamba_conv_dim:]
-            xbc = causal_conv1d_silu(xbc, conv1d_weight, conv1d_bias)
-            dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
+            # the mixer's parts, each a scope of its own inside pt.ssm
+            # (catalog.py TRACE_SCOPES); the scan enters pt.ssm.scan itself
+            with jax.named_scope("pt.ssm.in"):
+                x = _rms(h, input_layernorm_weight, c.rms_norm_eps)
+                zxbcdt = x @ in_proj_weight
+                z = zxbcdt[..., :inter]
+                xbc = zxbcdt[..., inter:inter + c.mamba_conv_dim]
+                dt = zxbcdt[..., inter + c.mamba_conv_dim:]
+            with jax.named_scope("pt.ssm.conv"):
+                xbc = causal_conv1d_silu(xbc, conv1d_weight, conv1d_bias)
+                dt = jax.nn.softplus(dt.astype(f32) + dt_bias.astype(f32))
             y = ssd_chunked_scan(
                 xbc[..., :inter].reshape(b, s, heads, c.mamba_d_head), dt,
                 -jnp.exp(A_log.astype(f32)),
                 xbc[..., inter:inter + n], xbc[..., inter + n:],
                 D, c.mamba_chunk_size)
-            y = gated_rms_norm(y.reshape(b, s, inter), z, norm_weight,
-                               c.rms_norm_eps)
-            return h + c.residual_multiplier * (y @ out_proj_weight)
+            with jax.named_scope("pt.ssm.gate"):
+                y = gated_rms_norm(y.reshape(b, s, inter), z, norm_weight,
+                                   c.rms_norm_eps)
+            with jax.named_scope("pt.ssm.out"):
+                return h + c.residual_multiplier * (y @ out_proj_weight)
 
 
 class GraniteAttentionMixer(_SubBlock):
@@ -234,17 +240,20 @@ class GraniteAttentionMixer(_SubBlock):
         c = self.config
         with jax.named_scope("pt.attn"):
             b, s, _ = h.shape
-            x = _rms(h, input_layernorm_weight, c.rms_norm_eps)
-            q = (x @ q_proj_weight).reshape(b, s, c.num_attention_heads,
-                                            self.head_dim)
-            k = (x @ k_proj_weight).reshape(b, s, c.num_key_value_heads,
-                                            self.head_dim)
-            v = (x @ v_proj_weight).reshape(b, s, c.num_key_value_heads,
-                                            self.head_dim)
+            with jax.named_scope("pt.attn.in"):
+                x = _rms(h, input_layernorm_weight, c.rms_norm_eps)
+                q = (x @ q_proj_weight).reshape(
+                    b, s, c.num_attention_heads, self.head_dim)
+                k = (x @ k_proj_weight).reshape(
+                    b, s, c.num_key_value_heads, self.head_dim)
+                v = (x @ v_proj_weight).reshape(
+                    b, s, c.num_key_value_heads, self.head_dim)
+            # NoPE: no pt.attn.pos here
             out = attention_bshd(q, k, v, is_causal=True,
                                  scale=c.attention_multiplier)
-            return h + c.residual_multiplier * (
-                out.reshape(b, s, -1) @ o_proj_weight)
+            with jax.named_scope("pt.attn.out"):
+                return h + c.residual_multiplier * (
+                    out.reshape(b, s, -1) @ o_proj_weight)
 
 
 class GraniteMoeFFN(_SubBlock):

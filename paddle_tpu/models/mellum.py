@@ -168,16 +168,25 @@ class MellumAttention(SubBlock):
                 "pt.attn.sliding" if sliding else "pt.attn.full"):
             b, s, _ = h.shape
             d = c.head_dim
-            x = _rms(h, input_layernorm_weight, c.rms_norm_eps)
-            q = (x @ q_proj_weight).reshape(b, s, c.num_attention_heads, d)
-            k = (x @ k_proj_weight).reshape(b, s, c.num_key_value_heads, d)
-            v = (x @ v_proj_weight).reshape(b, s, c.num_key_value_heads, d)
-            rope = rope_frequencies(c.rope_parameters[self.layer_type], d)
-            q = apply_rope(_rms(q, q_norm_weight, c.rms_norm_eps), *rope)
-            k = apply_rope(_rms(k, k_norm_weight, c.rms_norm_eps), *rope)
+            # the mixer's parts, each a scope inside the kind's
+            # (catalog.py TRACE_SCOPES)
+            with jax.named_scope("pt.attn.in"):
+                x = _rms(h, input_layernorm_weight, c.rms_norm_eps)
+                q = (x @ q_proj_weight).reshape(
+                    b, s, c.num_attention_heads, d)
+                k = (x @ k_proj_weight).reshape(
+                    b, s, c.num_key_value_heads, d)
+                v = (x @ v_proj_weight).reshape(
+                    b, s, c.num_key_value_heads, d)
+            with jax.named_scope("pt.attn.pos"):
+                rope = rope_frequencies(
+                    c.rope_parameters[self.layer_type], d)
+                q = apply_rope(_rms(q, q_norm_weight, c.rms_norm_eps), *rope)
+                k = apply_rope(_rms(k, k_norm_weight, c.rms_norm_eps), *rope)
             y = attention_bshd(q, k, v, is_causal=True, scale=d ** -0.5,
                                window=c.window_of(self.layer_type))
-            return h + y.reshape(b, s, -1) @ o_proj_weight
+            with jax.named_scope("pt.attn.out"):
+                return h + y.reshape(b, s, -1) @ o_proj_weight
 
 
 class MellumSparseMoe(SubBlock):

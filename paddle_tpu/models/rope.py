@@ -9,7 +9,9 @@ dimensions and scales cos and sin by the attention factor). Tables and the
 rotation are float32, rounded once to the operand's type: tables rounded
 first (incubate's fused_rotary_position_embedding) are, at 16k positions
 in bf16, a second rounding of every rotated entry (tests/test_brumby.py
-holds both to a float64 rotation).
+holds both to a float64 rotation). The callers enter the scope the work
+carries on a device trace, tables included: `pt.attn.pos` (models/mellum.py),
+`pt.retn.pos` (models/brumby.py).
 """
 
 from __future__ import annotations

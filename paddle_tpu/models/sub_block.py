@@ -32,7 +32,11 @@ class Params(nn.Layer):
 
 class SubBlock(nn.Layer):
     """A residual sub-block computed by one pure function of (hidden,
-    parameters), rematerialised in the backward."""
+    parameters), rematerialised in the backward. On a device trace its
+    operations therefore run in three passes under the scopes `_pure`
+    enters: forward, recompute (jax names the second forward
+    `rematted_computation`) and backward; observability/catalog.py
+    trace_pass tells them apart."""
 
     def _pure(self, h, **params):
         raise NotImplementedError

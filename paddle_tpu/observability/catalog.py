@@ -11,10 +11,12 @@ Entry: name -> (type, help, labelnames, buckets_or_None).
 
 from __future__ import annotations
 
+import re
+
 from . import metrics as _metrics
 
-__all__ = ["CATALOG", "TRACE_SCOPES", "KERNEL_NAMES", "metric",
-           "register_all"]
+__all__ = ["CATALOG", "TRACE_SCOPES", "TRACE_PASSES", "KERNEL_NAMES",
+           "trace_pass", "metric", "register_all"]
 
 # latency bucket families (seconds)
 _TTFT_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
@@ -530,8 +532,16 @@ CATALOG = {
 # equal an entry of pir/verifier.py EFFECT_SCOPES.
 TRACE_SCOPES = {
     "pt.embed": "token (+ position) embedding lookup",
-    "pt.attn": "attention sub-block: norm, QKV projections, rope, the "
-               "attention kernel or paged attention, output projection",
+    "pt.attn": "attention sub-block: its parts pt.attn.in, pt.attn.pos, "
+               "pt.attn.out around the attention kernel or paged attention "
+               "(the serving programs enter no part)",
+    "pt.attn.in": "inside pt.attn: the input norm and the q, k, v "
+                  "projections",
+    "pt.attn.pos": "inside pt.attn: per-head q/k RMSNorm and RoPE, tables "
+                   "included (models/rope.py); absent where a model has "
+                   "neither",
+    "pt.attn.out": "inside pt.attn: the output projection and the "
+                   "residual add",
     "pt.attn.sliding": "inside pt.attn, a window layer's whole mixer "
                        "(models/mellum.py sliding_attention: default RoPE, "
                        "the faw_* kernels)",
@@ -540,15 +550,26 @@ TRACE_SCOPES = {
                     "full_attention: YaRN RoPE, the fa_* kernels)",
     "pt.mlp": "MLP sub-block with its norm (in an expert layer: the norm, "
               "the shared expert and the residual)",
-    "pt.ssm": "state-space (Mamba-2) mixer: norm, in/out projections, "
-              "causal conv, the scan, gated norm, residual",
+    "pt.ssm": "state-space (Mamba-2) mixer: its parts pt.ssm.in, "
+              "pt.ssm.conv, pt.ssm.scan, pt.ssm.gate, pt.ssm.out",
+    "pt.ssm.in": "inside pt.ssm: the input norm, in_proj and its split "
+                 "into z, xBC and dt",
+    "pt.ssm.conv": "inside pt.ssm: the causal depthwise conv with its silu "
+                   "and dt's softplus",
     "pt.ssm.scan": "the chunked state-space scan alone (ops/mamba2.py)",
-    "pt.retn": "power-retention mixer (models/brumby.py): norm, q/k/v/gate "
-               "projections, q/k norm, RoPE, the retention, output "
-               "projection, residual",
+    "pt.ssm.gate": "inside pt.ssm: the gated RMSNorm of the scan's output",
+    "pt.ssm.out": "inside pt.ssm: out_proj and the residual add",
+    "pt.retn": "power-retention mixer (models/brumby.py): its parts "
+               "pt.retn.in, pt.retn.pos, pt.retn.scan, pt.retn.out",
+    "pt.retn.in": "inside pt.retn: the input norm, the q, k, v projections "
+                  "and the gate's (retention_log_gate)",
+    "pt.retn.pos": "inside pt.retn: per-head q/k RMSNorm and RoPE, tables "
+                   "included (models/rope.py)",
     "pt.retn.scan": "the chunked power retention alone (ops/"
                     "power_retention.py): expansion, in-chunk products, "
                     "state products, the carried state, the normaliser",
+    "pt.retn.out": "inside pt.retn: the output projection and the "
+                   "residual add",
     "pt.moe": "routed experts: router, dispatch, grouped expert matmuls "
               "with their activation, combine",
     "pt.moe.route": "what in pt.moe is no expert work: router logits, "
@@ -556,12 +577,54 @@ TRACE_SCOPES = {
     "pt.head": "final norm + LM head matmul",
     "pt.loss": "cross entropy over the vocabulary",
     "pt.opt": "gradient clipping + optimizer update of the train step",
+    "pt.recompute": "inside a custom_vjp backward rule, a forward product "
+                    "made again by hand (ops/mamba2.py _conv_bwd: the "
+                    "conv's taps); trace_pass reads it as recompute",
     "pt.serve.gather": "paged attention: block-table gather of K/V "
                        "(+ dequantisation) out of the pool",
     "pt.serve.attend": "paged attention: scores, mask, softmax, PV",
     "pt.serve.sample": "on-device argmax / categorical sampling in the "
                        "decode scan",
 }
+
+# The pass of a device operation: which part of a train step made it.
+# Closed like the scopes; trace_pass is the one rule that reads it off the
+# names a compiled step carries, and tests/test_trace_names.py holds the
+# rule against the compiled step of every model with a training cell: it
+# is the contract with jax's name stack (jvp / transpose / checkpoint /
+# rematted_computation are jax's words, not ours) and with XLA's
+# instruction names (".remat").
+TRACE_PASSES = ("forward", "recompute", "xla_remat", "backward", "update")
+
+_UPDATE = re.compile(r"(?<![\w.])pt\.opt(?![\w.])")
+_RECOMPUTE = re.compile(r"rematted_computation|(?<![\w.])pt\.recompute"
+                        r"(?![\w.])")
+
+
+def trace_pass(op_name, instruction_name=""):
+    """The TRACE_PASSES entry of one operation of a compiled step, from
+    its op_name (the name stack in the HLO's metadata) and its instruction
+    name. In this order: under pt.opt, `update`; an instruction XLA
+    rematerialised itself (".remat" in its name), `xla_remat`; a
+    jax.checkpoint's second forward (`rematted_computation` anywhere in
+    the stack: a loop body inside a checkpointed block keeps it) or a
+    custom_vjp rule's own (scope pt.recompute), `recompute`; under a
+    `transpose(`, `backward` (a custom_vjp backward rule lands under
+    transpose(jvp(<scope>))); everything else, an operation without a name
+    too, `forward`. What no name can show: recomputation inside a Pallas
+    kernel (the flash backward's scores), and a fusion takes one member's
+    name, so a recomputed elementwise chain fused into a backward matmul
+    counts as backward."""
+    if _UPDATE.search(op_name):
+        return "update"
+    if ".remat" in instruction_name:
+        return "xla_remat"
+    if _RECOMPUTE.search(op_name):
+        return "recompute"
+    if "transpose(" in op_name:
+        return "backward"
+    return "forward"
+
 
 # `name=` of the Pallas kernels (ops/pallas/flash_attention.py,
 # ops/pallas/power_retention.py): the Mosaic kernel name and the innermost

@@ -57,7 +57,10 @@ def _conv_fwd(x, weight, bias):
 def _conv_bwd(res, g):
     x, weight, bias = res
     width, seq = weight.shape[0], x.shape[1]
-    u = _conv_taps(x, weight, bias)
+    # the forward's taps made again by hand: on a trace the second forward
+    # of a rule, not its backward (catalog.py trace_pass)
+    with jax.named_scope("pt.recompute"):
+        u = _conv_taps(x, weight, bias)
     sig = jax.nn.sigmoid(u)
     du = g.astype(jnp.float32) * sig * (1.0 + u * (1.0 - sig))
     dup = jnp.pad(du, ((0, 0), (0, width - 1), (0, 0)))

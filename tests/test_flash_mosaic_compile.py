@@ -351,16 +351,28 @@ def test_retention_kernels_compile_at_the_cell_s_shapes(one_chip, rows,
         assert "tpu_custom_call" in text and name in text, name
 
 
-def test_retention_layer_compiles_and_no_expansion_reaches_memory(
-        one_chip, monkeypatch):
+_COMPILED = {}        # one compile a shape, shared by the tests below
+
+
+def _kernel_calls(text):
+    """[(kernel name, op_name, instruction name)] of a compiled text's
+    Pallas calls."""
+    out = []
+    for line in text.splitlines():
+        if "custom-call(" in line and "tpu_custom_call" in line:
+            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+            inst = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line).group(1)
+            out.append((op_name.split("/")[-2], op_name, inst))
+    return out
+
+
+def _retention_layer_text(one_chip, monkeypatch):
     """One layer's `power_retention` of the Brumby cell (16,384 positions,
     40 query heads over 8 state heads, d 128, bf16, chunks of 1,024),
-    forward and backward with the chunk's recomputation, for a described
-    v5e. The compiled text names the three kernels as the catalog does,
-    each under the scan's scope in the forward, the recomputation and the
-    backward, and holds no array of an expansion's shape: neither the
-    query side's bf16[5120,8320] nor the key side's bf16[1024,8320]."""
-    from paddle_tpu.observability.catalog import KERNEL_NAMES
+    forward and backward with the chunk's recomputation, compiled for a
+    described v5e."""
+    if "retention" in _COMPILED:
+        return _COMPILED["retention"]
     from paddle_tpu.ops.pallas import power_retention as kernels
     from paddle_tpu.ops.power_retention import power_retention
     # the described chip is not jax.default_backend(): the kernels are
@@ -378,37 +390,63 @@ def test_retention_layer_compiles_and_no_expansion_reaches_memory(
     text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3))).lower(
         sds(1, seq, heads, d), sds(1, seq, groups, d), sds(1, seq, groups, d),
         sds(1, seq, groups, dt=jnp.float32)).compile().as_text()
+    _COMPILED["retention"] = text
+    return text
+
+
+def test_retention_layer_compiles_and_no_expansion_reaches_memory(
+        one_chip, monkeypatch):
+    """The compiled text of one layer's retention names the three kernels
+    as the catalog does, each under the scan's scope in the forward, the
+    recomputation and the backward, and holds no array of an expansion's
+    shape: neither the query side's bf16[5120,8320] nor the key side's
+    bf16[1024,8320]."""
+    from paddle_tpu.observability.catalog import KERNEL_NAMES
+    text = _retention_layer_text(one_chip, monkeypatch)
     assert not re.search(r"\[(?:5120|1024),8320\]", text)
-    calls = [line for line in text.splitlines()
-             if "custom-call(" in line and "tpu_custom_call" in line]
+    calls = _kernel_calls(text)
     names = collections.Counter()
-    for line in calls:
-        op_name = re.search(r'op_name="([^"]*)"', line).group(1)
+    for kernel, op_name, _ in calls:
         assert "pt.retn.scan" in op_name, op_name
-        names[op_name.split("/")[-2]] += 1
+        names[kernel] += 1
     assert set(names) == {n for n in KERNEL_NAMES if n.startswith("retn_")}
     # forward: read, write. Recomputed: read (the recomputed write feeds
     # nothing). Backward: back twice, write for the state's cotangent,
     # read for vw's
     assert names == {"retn_read": 3, "retn_write": 2, "retn_back": 2}
-    assert any("transpose(jvp(pt.retn.scan))" in line for line in calls)
+    assert any("transpose(jvp(pt.retn.scan))" in op for _, op, _ in calls)
 
 
-def test_mellum_mixers_compile_with_their_kernels_under_their_scopes(
+def test_retention_kernels_run_in_the_passes_the_rule_gives_them(
         one_chip, monkeypatch):
-    """One window layer's and one full layer's mixer of the Mellum cell
-    (16,384 positions, 32 query heads over 4 key-value heads, d 128,
-    bf16, window 1,024; models/mellum.py MellumAttention), forward and
-    backward with the sub-block's recomputation, for a described v5e. The
-    window layer's kernels are the faw_* ones and the full layer's the
-    fa_* ones, as the catalog names them, and the forward's and the
-    recomputation's are under pt.attn/pt.attn.<kind>: the cell's
-    `attn_window_*` and `attn_full_*` metrics tell the kinds apart by
-    these names. (Alone, a sub-block's forward and its recomputation are
-    one call: the step has both.)"""
+    """catalog.py trace_pass on the same compiled layer: `retn_back` runs
+    in the backward alone; `retn_read` once in each of the forward, the
+    chunk's recomputation (inside the backward's loop: the pass holds
+    through `while/body`) and the backward; `retn_write` in the forward
+    and the backward (its recomputation feeds nothing). This is what
+    `retention_scan_recompute_time_share` reads."""
+    from paddle_tpu.observability.catalog import trace_pass
+    passes = collections.defaultdict(collections.Counter)
+    for kernel, op_name, inst in _kernel_calls(
+            _retention_layer_text(one_chip, monkeypatch)):
+        passes[kernel][trace_pass(op_name, inst)] += 1
+    assert passes["retn_back"] == {"backward": 2}
+    assert passes["retn_read"] == {"forward": 1, "recompute": 1,
+                                   "backward": 1}
+    assert passes["retn_write"] == {"forward": 1, "backward": 1}
+
+
+def _mellum_mixer_calls(one_chip, monkeypatch, kind):
+    """The Pallas calls of one Mellum mixer of `kind` (16,384 positions, 32
+    query heads over 4 key-value heads, d 128, bf16, window 1,024;
+    models/mellum.py MellumAttention) compiled for a described v5e: the
+    value and the gradients through the sub-block's rematerialisation, so
+    the forward, its recomputation and the backward are all there (the
+    gradient alone drops the first forward: nothing reads it)."""
+    if kind in _COMPILED:
+        return _COMPILED[kind]
     import paddle_tpu as paddle
     from paddle_tpu.models.mellum import MellumAttention, MellumConfig
-    from paddle_tpu.observability.catalog import KERNEL_NAMES
     from paddle_tpu.ops.pallas import attention_router
     # the described chip is not jax.default_backend(): the rule and the
     # kernels' interpret switch are told it is
@@ -417,41 +455,72 @@ def test_mellum_mixers_compile_with_their_kernels_under_their_scopes(
     cfg = MellumConfig(num_hidden_layers=2, layer_types=[
         "sliding_attention", "full_attention"], vocab_size=256,
         dtype="bfloat16")
+    with paddle.LazyGuard():
+        sub = MellumAttention(cfg, kind)
+    names, tensors = zip(*sub.named_parameters())
+    params = {n.replace(".", "_"): jax.ShapeDtypeStruct(
+        tuple(t.shape), jnp.bfloat16, sharding=one_chip)
+        for n, t in zip(names, tensors)}
+    h = jax.ShapeDtypeStruct((1, 16384, cfg.hidden_size), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def loss(h_, p_):
+        block = jax.checkpoint(lambda x: sub._pure(x, **p_))
+        return jnp.sum(block(h_).astype(jnp.float32))
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+        h, params).compile().as_text()
+    attention_router.clear_routing_cache()
+    _COMPILED[kind] = _kernel_calls(text)
+    return _COMPILED[kind]
+
+
+def test_mellum_mixers_compile_with_their_kernels_under_their_scopes(
+        one_chip, monkeypatch):
+    """One window layer's and one full layer's mixer of the Mellum cell,
+    forward and backward with the sub-block's recomputation, for a
+    described v5e. The window layer's kernels are the faw_* ones and the
+    full layer's the fa_* ones, as the catalog names them, and every call
+    is under pt.attn/pt.attn.<kind>: the cell's `attn_window_*` and
+    `attn_full_*` metrics tell the kinds apart by these names."""
+    from paddle_tpu.observability.catalog import KERNEL_NAMES
     seen = {}
-    for kind in cfg.layer_types:
-        with paddle.LazyGuard():
-            sub = MellumAttention(cfg, kind)
-        names, tensors = zip(*sub.named_parameters())
-        params = {n.replace(".", "_"): jax.ShapeDtypeStruct(
-            tuple(t.shape), jnp.bfloat16, sharding=one_chip)
-            for n, t in zip(names, tensors)}
-        h = jax.ShapeDtypeStruct((1, 16384, cfg.hidden_size), jnp.bfloat16,
-                                 sharding=one_chip)
-
-        def loss(h_, p_, sub=sub):
-            block = jax.checkpoint(lambda x: sub._pure(x, **p_))
-            return jnp.sum(block(h_).astype(jnp.float32))
-
-        text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-            h, params).compile().as_text()
-        calls = [line for line in text.splitlines()
-                 if "custom-call(" in line and "tpu_custom_call" in line]
+    for kind in ("sliding_attention", "full_attention"):
         seen[kind] = collections.Counter()
-        scope = "pt.attn/pt.attn." + kind.split("_")[0]
-        for line in calls:
-            op_name = re.search(r'op_name="([^"]*)"', line).group(1)
-            kernel = op_name.split("/")[-2]
+        # "pt.attn/pt.attn.<kind>", or with the transformation that
+        # wrapped the outer scope, "jvp(pt.attn)/pt.attn.<kind>"
+        scope = re.compile(r"pt\.attn\)*/pt\.attn\." + kind.split("_")[0])
+        for kernel, op_name, _ in _mellum_mixer_calls(one_chip, monkeypatch,
+                                                      kind):
             seen[kind][kernel] += 1
             # the backward's too: the rematerialised sub-block's transpose
             # is traced inside its scopes
-            assert scope in op_name, op_name
-    attention_router.clear_routing_cache()
+            assert scope.search(op_name), op_name
     assert set(seen["sliding_attention"]) == {
         "faw_fwd", "faw_bwd_dq", "faw_bwd_dkv"}
     assert set(seen["full_attention"]) == {
         "fa_fwd", "fa_bwd_dq", "fa_bwd_dkv"}
     assert set(seen["sliding_attention"]) | set(seen["full_attention"]) == {
         n for n in KERNEL_NAMES if n.startswith("fa")}
+
+
+@pytest.mark.parametrize("kind, prefix", [("sliding_attention", "faw"),
+                                          ("full_attention", "fa")])
+def test_mellum_mixer_kernels_run_in_the_passes_the_rule_gives_them(
+        one_chip, monkeypatch, kind, prefix):
+    """catalog.py trace_pass on the compiled mixers: the forward kernel is
+    found once in the forward and once in the sub-block's recomputation
+    (what `attn_window_recompute_time_share` reads, and why
+    `attn_window_fwd_roofline` credits half of the kernel's time), the two
+    backward kernels in the backward alone."""
+    from paddle_tpu.observability.catalog import trace_pass
+    passes = collections.defaultdict(collections.Counter)
+    for kernel, op_name, inst in _mellum_mixer_calls(one_chip, monkeypatch,
+                                                     kind):
+        passes[kernel][trace_pass(op_name, inst)] += 1
+    assert passes[prefix + "_fwd"] == {"forward": 1, "recompute": 1}
+    assert passes[prefix + "_bwd_dq"] == {"backward": 1}
+    assert passes[prefix + "_bwd_dkv"] == {"backward": 1}
 
 
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{\s*$")

@@ -11,6 +11,13 @@
     tiny GPT train step, forward and backward, and of the engine's
     pir_jit decode and prefill programs AFTER PIR replay; the flash
     kernels carry their names.
+(c) parts and passes (ISSUE 36): every part of a mixer (pt.attn.in, ...)
+    is under its mixer in the COMPILED step of each model with a training
+    cell, forward and backward; catalog.py trace_pass, the program's rule
+    for the pass of an operation, finds forward, recompute and backward
+    under every rematerialised sub-block and gives every instruction one
+    pass. These tests are the contract with jax's name stack: an upgrade
+    that renames `rematted_computation` or `transpose(` fails here.
 """
 
 import glob
@@ -28,7 +35,8 @@ from paddle_tpu.inference import ContinuousBatchingEngine
 from paddle_tpu.models.gpt import gpt_tiny
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.observability import tracing
-from paddle_tpu.observability.catalog import KERNEL_NAMES, TRACE_SCOPES
+from paddle_tpu.observability.catalog import (KERNEL_NAMES, TRACE_PASSES,
+                                              TRACE_SCOPES, trace_pass)
 from paddle_tpu.parallel import GPT_SHARDING_RULES, SpmdTrainer, create_mesh
 from paddle_tpu.profiler.phases import PHASES, get_phase_accountant
 
@@ -42,14 +50,61 @@ HYBRID_SCOPES = ("pt.ssm", "pt.ssm.scan", "pt.moe", "pt.moe.route")
 RETENTION_SCOPES = ("pt.retn", "pt.retn.scan")
 # what models/mellum.py adds: the attention kind, inside pt.attn
 KIND_SCOPES = ("pt.attn.sliding", "pt.attn.full")
+# the parts of every mixer, by the model with a training cell that enters
+# them: {model: {mixer scope: its parts}} (ISSUE 36)
+PART_SCOPES = {
+    "gpt": {"pt.attn": ("pt.attn.in", "pt.attn.out")},
+    "granite": {"pt.ssm": ("pt.ssm.in", "pt.ssm.conv", "pt.ssm.gate",
+                           "pt.ssm.out"),
+                "pt.attn": ("pt.attn.in", "pt.attn.out")},      # NoPE
+    "brumby": {"pt.retn": ("pt.retn.in", "pt.retn.pos", "pt.retn.out")},
+    "mellum": {"pt.attn.sliding": ("pt.attn.in", "pt.attn.pos",
+                                   "pt.attn.out"),
+               "pt.attn.full": ("pt.attn.in", "pt.attn.pos",
+                                "pt.attn.out")},
+}
+# the scopes whose sub-block is rematerialised (models/sub_block.py), and
+# with it runs forward, recomputed and backward
+REMAT_SCOPES = {
+    "granite": ("pt.ssm", "pt.ssm.scan", "pt.attn", "pt.mlp", "pt.moe"),
+    "brumby": ("pt.retn", "pt.retn.scan", "pt.mlp", "pt.head", "pt.loss"),
+    "mellum": ("pt.attn.sliding", "pt.attn.full", "pt.moe", "pt.head",
+               "pt.loss"),
+}
+# entered by a custom_vjp backward rule, not by a model (ops/mamba2.py)
+RULE_SCOPES = ("pt.recompute",)
 
 
-def _trainer():
+def _trainer(model="gpt"):
+    """(SpmdTrainer over the model's tiny configuration on one device, the
+    (batch, seq) its step is lowered at)."""
+    from paddle_tpu.parallel import DP_ONLY_RULES
     paddle.seed(0)
-    model = gpt_tiny()
-    opt = optimizer.AdamW(1e-3, parameters=model.parameters())
-    return SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
-                       GPT_SHARDING_RULES)
+    if model == "gpt":
+        net, rules, shape = gpt_tiny(), GPT_SHARDING_RULES, (2, 64)
+    elif model == "granite":
+        from paddle_tpu.models.granite_moe_hybrid import granite_hybrid_tiny
+        net, rules, shape = granite_hybrid_tiny(), DP_ONLY_RULES, (1, 16)
+    elif model == "brumby":
+        from paddle_tpu.models.brumby import brumby_tiny
+        net, rules, shape = brumby_tiny(), DP_ONLY_RULES, (1, 16)
+    else:
+        from paddle_tpu.models.mellum import mellum_tiny
+        net, rules, shape = mellum_tiny(), DP_ONLY_RULES, (1, 16)
+    opt = optimizer.AdamW(1e-3, parameters=net.parameters())
+    return SpmdTrainer(net, opt, create_mesh(devices=jax.devices()[:1]),
+                       rules), shape
+
+
+def _lowered_step(trainer, shape):
+    """The trainer's jitted step lowered at a zero batch of `shape`."""
+    from paddle_tpu.framework.random import get_rng_state
+    ids = np.zeros(shape, np.int32)
+    batch = trainer._batch_arrays((ids, ids))
+    with jax.set_mesh(trainer.mesh):
+        return trainer._compiled.lower(
+            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
+            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32))
 
 
 def _engine(**kw):
@@ -89,7 +144,7 @@ def traced(tmp_path_factory):
     """One profiler session over a tracer span, a RecordEvent, two trainer
     steps and a served batch; the accountant counts the same period."""
     trace_dir = str(tmp_path_factory.mktemp("trace"))
-    trainer, eng = _trainer(), _engine()
+    trainer, eng = _trainer()[0], _engine()
     ids = np.random.RandomState(0).randint(0, 512, (2, 64)).astype(np.int32)
     trainer.step((ids, ids)).block_until_ready()        # compile outside
     _serve(eng)
@@ -222,15 +277,7 @@ def _scope_hits(text, scope):
 
 
 def test_train_step_lowers_with_every_scope_forward_and_backward():
-    from paddle_tpu.framework.random import get_rng_state
-    trainer = _trainer()
-    ids = np.zeros((2, 64), np.int32)
-    batch = trainer._batch_arrays((ids, ids))
-    with jax.set_mesh(trainer.mesh):
-        text = trainer._compiled.lower(
-            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
-            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
-        ).as_text(debug_info=True)
+    text = _lowered_step(*_trainer()).as_text(debug_info=True)
     for scope in TRAIN_SCOPES:
         hits = _scope_hits(text, scope)
         assert hits, scope
@@ -243,21 +290,7 @@ def test_hybrid_train_step_lowers_with_the_mixer_and_expert_scopes():
     per-sub-block rematerialisation, forward and backward, nested as the
     readers' patterns expect (pt.ssm.scan inside pt.ssm, pt.moe.route
     inside pt.moe); attention and the shared expert keep pt.attn, pt.mlp."""
-    from paddle_tpu.framework.random import get_rng_state
-    from paddle_tpu.models.granite_moe_hybrid import granite_hybrid_tiny
-    from paddle_tpu.parallel import DP_ONLY_RULES
-    paddle.seed(0)
-    model = granite_hybrid_tiny()
-    opt = optimizer.AdamW(1e-3, parameters=model.parameters())
-    trainer = SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
-                          DP_ONLY_RULES)
-    ids = np.zeros((1, 16), np.int32)
-    batch = trainer._batch_arrays((ids, ids))
-    with jax.set_mesh(trainer.mesh):
-        text = trainer._compiled.lower(
-            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
-            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
-        ).as_text(debug_info=True)
+    text = _lowered_step(*_trainer("granite")).as_text(debug_info=True)
     for scope in HYBRID_SCOPES + TRAIN_SCOPES:
         hits = _scope_hits(text, scope)
         assert hits, scope
@@ -276,21 +309,7 @@ def test_retention_train_step_lowers_with_the_mixer_scopes():
     patterns expect (pt.retn.scan inside pt.retn); the FFN keeps pt.mlp,
     and the head and the loss, taken a block of tokens at a time, pt.head
     and pt.loss."""
-    from paddle_tpu.framework.random import get_rng_state
-    from paddle_tpu.models.brumby import brumby_tiny
-    from paddle_tpu.parallel import DP_ONLY_RULES
-    paddle.seed(0)
-    model = brumby_tiny()
-    opt = optimizer.AdamW(1e-3, parameters=model.parameters())
-    trainer = SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
-                          DP_ONLY_RULES)
-    ids = np.zeros((1, 16), np.int32)
-    batch = trainer._batch_arrays((ids, ids))
-    with jax.set_mesh(trainer.mesh):
-        text = trainer._compiled.lower(
-            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
-            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
-        ).as_text(debug_info=True)
+    text = _lowered_step(*_trainer("brumby")).as_text(debug_info=True)
     for scope in RETENTION_SCOPES + TRAIN_SCOPES:
         if scope == "pt.attn":
             assert not _scope_hits(text, scope)     # no attention here
@@ -315,21 +334,7 @@ def test_mellum_train_step_lowers_with_both_attention_kinds_scopes():
     rematerialisation; its experts keep pt.moe and pt.moe.route (no
     pt.mlp: there is no shared expert), the blocked head and loss pt.head
     and pt.loss. The cell's two mixer metrics read these patterns."""
-    from paddle_tpu.framework.random import get_rng_state
-    from paddle_tpu.models.mellum import mellum_tiny
-    from paddle_tpu.parallel import DP_ONLY_RULES
-    paddle.seed(0)
-    model = mellum_tiny()
-    opt = optimizer.AdamW(1e-3, parameters=model.parameters())
-    trainer = SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
-                          DP_ONLY_RULES)
-    ids = np.zeros((1, 16), np.int32)
-    batch = trainer._batch_arrays((ids, ids))
-    with jax.set_mesh(trainer.mesh):
-        text = trainer._compiled.lower(
-            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
-            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
-        ).as_text(debug_info=True)
+    text = _lowered_step(*_trainer("mellum")).as_text(debug_info=True)
     for scope in KIND_SCOPES + ("pt.moe", "pt.moe.route") + TRAIN_SCOPES:
         if scope == "pt.mlp":
             assert not _scope_hits(text, scope)     # no dense FFN here
@@ -359,8 +364,177 @@ def test_mellum_train_step_lowers_with_both_attention_kinds_scopes():
 def test_every_declared_scope_is_held_by_a_test_and_none_else():
     """catalog.py TRACE_SCOPES against the scopes these tests look for in
     lowered programs, both directions."""
+    parts = {p for mixers in PART_SCOPES.values()
+             for ps in mixers.values() for p in ps}
     assert set(TRAIN_SCOPES) | set(SERVE_SCOPES) | set(HYBRID_SCOPES) \
-        | set(RETENTION_SCOPES) | set(KIND_SCOPES) == set(TRACE_SCOPES)
+        | set(RETENTION_SCOPES) | set(KIND_SCOPES) | parts \
+        | set(RULE_SCOPES) == set(TRACE_SCOPES)
+
+
+# -- (c) parts of a mixer and the pass of an operation ----------------------
+
+_NAME = re.compile(r"(?<![\w.])pt\.[a-z_.]+")
+_COMPILED = {}
+
+
+def _path(op_name):
+    out = []
+    for name in _NAME.findall(op_name):
+        if name not in out:
+            out.append(name)
+    return out
+
+
+def _compiled_ops(model):
+    """[(instruction name, op_name, scope path)] of every instruction of
+    the model's tiny train step AS COMPILED for the CPU (fusions' members
+    included): an optimised instruction's op_name is the whole path,
+    through loop bodies too, as on a device trace's HloProto. One compile a
+    model."""
+    if model not in _COMPILED:
+        text = _lowered_step(*_trainer(model)).compile().as_text()
+        ops = []
+        for line in text.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+            if m:
+                named = re.search(r'op_name="([^"]*)"', line)
+                op = named.group(1) if named else ""
+                ops.append((m.group(1), op, _path(op)))
+        _COMPILED[model] = ops
+    return _COMPILED[model]
+
+
+@pytest.mark.parametrize("model, mixer, part", [
+    (model, mixer, part) for model, mixers in PART_SCOPES.items()
+    for mixer, parts in mixers.items() for part in parts])
+def test_every_part_is_under_its_mixer_forward_and_backward(model, mixer,
+                                                            part):
+    """In the compiled step the part's operations are there in the forward
+    and in the backward, and every one of them has the mixer's scope
+    outside the part's: what `mixer_*_time_share` and the mixer's own
+    metric both rest on."""
+    ops = _compiled_ops(model)
+    hits = [(inst, op, path) for inst, op, path in ops
+            if part in path and mixer in path]
+    assert hits, (model, part)
+    for _, op, path in hits:
+        assert path.index(mixer) < path.index(part), op
+    passes = {trace_pass(op, inst) for inst, op, _ in hits}
+    assert {"forward", "backward"} <= passes, (model, part, passes)
+    # a part is entered nowhere but inside a mixer
+    assert all(set(path) & set(PART_SCOPES[model])
+               for _, _, path in ops if part in path)
+
+
+@pytest.mark.parametrize("model", sorted(REMAT_SCOPES))
+def test_every_rematerialised_sub_block_runs_in_three_passes(model):
+    """models/sub_block.py rematerialises every sub-block: under each of
+    their scopes the compiled step has operations of the forward, of the
+    program's recomputation and of the backward, by the program's rule;
+    the update is there; and the rule gives every instruction of the step
+    exactly one of TRACE_PASSES."""
+    ops = _compiled_ops(model)
+    by_pass = {}
+    for inst, op, path in ops:
+        which = trace_pass(op, inst)
+        assert which in TRACE_PASSES, (inst, op)
+        by_pass.setdefault(which, []).append((op, path))
+    assert sum(map(len, by_pass.values())) == len(ops)
+    for scope in REMAT_SCOPES[model]:
+        for which in ("forward", "recompute", "backward"):
+            assert any(scope in path for _, path in by_pass[which]), \
+                (model, scope, which)
+    assert by_pass["update"]
+    assert all("pt.opt" in path for _, path in by_pass["update"])
+    # the recomputation inside the backward's loop over chunks keeps its
+    # pass through the loop's body
+    if model != "mellum":
+        scan = "pt.ssm.scan" if model == "granite" else "pt.retn.scan"
+        assert any("while/body" in op and scan in path
+                   for op, path in by_pass["recompute"])
+
+
+def test_a_step_that_rematerialises_nothing_has_no_recomputation():
+    """The GPT step keeps its activations: forward, backward and update,
+    and nothing the rule would call recomputation."""
+    passes = {trace_pass(op, inst) for inst, op, _ in _compiled_ops("gpt")}
+    assert passes == {"forward", "backward", "update"}
+
+
+@pytest.mark.parametrize("op_name, instruction, expected", [
+    ("jit(loss)/jvp(pt.ssm)/pt.ssm.in/dot_general", "fusion.1", "forward"),
+    ("jit(loss)/transpose(jvp(jvp()))/checkpoint/rematted_computation/"
+     "pt.ssm/pt.ssm.in/dot_general", "fusion.2", "recompute"),
+    ("jit(loss)/transpose(jvp(jvp()))/checkpoint/pt.ssm/while/body/mul",
+     "fusion.3", "backward"),
+    ("jit(loss)/transpose(jvp(jvp()))/checkpoint/pt.ssm/while/body/"
+     "closed_call/checkpoint/rematted_computation/pt.ssm.scan/dot_general",
+     "fusion.4", "recompute"),
+    ("jit(train_step)/pt.opt/mul", "fusion.5", "update"),
+    ("jit(train_step)/pt.opt/mul", "fusion.5.remat", "update"),
+    ("jit(loss)/jvp(pt.ssm)/pt.ssm.in/dot_general", "fusion.6.remat",
+     "xla_remat"),
+    ("jit(loss)/transpose(jvp(jvp()))/checkpoint/rematted_computation/"
+     "pt.mlp/dot_general", "fusion.7.remat2", "xla_remat"),
+    ("jit(loss)/transpose(jvp(pt.ssm))/pt.ssm.conv/pt.recompute/mul",
+     "fusion.8", "recompute"),
+    ("jit(loss)/transpose(jvp(pt.attn))/fa_bwd_dq/pallas_call",
+     "fa_bwd_dq.9", "backward"),
+    ("jit(loss)/jvp(pt.optics)/mul", "fusion.10", "forward"),
+    ("", "copy.11", "forward"),
+    ("jit(train_step)/params['gpt.wte.weight']", "", "forward"),
+])
+def test_trace_pass_table(op_name, instruction, expected):
+    assert trace_pass(op_name, instruction) == expected
+    assert expected in TRACE_PASSES
+
+
+def test_every_pass_has_its_row_in_the_observability_doc():
+    import os
+    doc = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "OBSERVABILITY.md")
+    with open(doc) as f:
+        rows = set(re.findall(r"^\| `pass/([a-z_]+)` \|", f.read(), re.M))
+    assert rows == set(TRACE_PASSES)
+
+
+def test_a_backward_rule_s_own_forward_reads_as_recomputation():
+    """ops/mamba2.py _conv_bwd makes the conv's taps again by hand, inside
+    scope pt.recompute: in the lowered backward those operations are under
+    the call site's scopes and the rule reads them as recompute, the rest
+    of the rule as backward. (In a rematerialised sub-block XLA merges them
+    with the checkpoint's own recomputed taps, which read the same.)"""
+    from paddle_tpu.ops.mamba2 import causal_conv1d_silu
+
+    def loss(x, w, b):
+        with jax.named_scope("pt.ssm"), jax.named_scope("pt.ssm.conv"):
+            return causal_conv1d_silu(x, w, b).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        jnp.ones((1, 16, 8)), jnp.ones((4, 8)), jnp.zeros((8,))
+    ).compile().as_text()
+    names = set(re.findall(r'op_name="([^"]*)"', text))
+    again = {n for n in names if "pt.recompute" in _path(n)}
+    assert again and all(trace_pass(n) == "recompute" for n in again)
+    assert all(_path(n)[:2] == ["pt.ssm", "pt.ssm.conv"] for n in again)
+    rest = {n for n in names if "pt.ssm.conv" in _path(n)} - again
+    assert rest and all(trace_pass(n) == "backward" for n in rest)
+
+
+def test_pir_replay_keeps_a_gpt_block_s_parts():
+    """The GPT model runs through nn.Linear / execute: its mixer's parts
+    reach a replayed program as the component scopes do."""
+    from paddle_tpu.pir import pir_jit
+    paddle.seed(0)
+    model = gpt_tiny()
+    block = model.gpt.h[0]
+    g = pir_jit(lambda x: block(paddle.Tensor(x))._data, name="gpt_block")
+    g(jnp.ones((2, 16, model.config.hidden_size), jnp.float32))
+    assert g.report.fallback is None
+    text = _replayed_text(g)
+    for part in PART_SCOPES["gpt"]["pt.attn"]:
+        hits = _scope_hits(text, part)
+        assert hits and all("pt.attn/" + part in h for h in hits), part
 
 
 @pytest.fixture(scope="module")
@@ -462,7 +636,6 @@ def test_retention_kernels_sit_under_the_scan_s_scope_both_ways():
     transposed, outside the forward's scope, and enter it themselves),
     is under pt.retn/pt.retn.scan, so the kernels' time on a trace stays
     in `retention_scan_time_share` and out of `unnamed_op_time_share`."""
-    from paddle_tpu.framework.random import get_rng_state
     from paddle_tpu.models.brumby import brumby_tiny
     from paddle_tpu.parallel import DP_ONLY_RULES
     paddle.seed(0)
@@ -471,16 +644,10 @@ def test_retention_kernels_sit_under_the_scan_s_scope_both_ways():
     opt = optimizer.AdamW(1e-3, parameters=model.parameters())
     trainer = SpmdTrainer(model, opt, create_mesh(devices=jax.devices()[:1]),
                           DP_ONLY_RULES)
-    ids = np.zeros((1, 16), np.int32)
-    batch = trainer._batch_arrays((ids, ids))
-    with jax.set_mesh(trainer.mesh):
-        # compiled, not lowered: a lowered operation inside the scan's body
-        # carries the body's own name stack, an optimised one's op_name
-        # the whole path, as a device trace's HloProto does
-        text = trainer._compiled.lower(
-            trainer.params, trainer.opt_state, batch, get_rng_state()[0],
-            jnp.asarray(1, jnp.int32), jnp.asarray(1e-3, jnp.float32)
-        ).compile().as_text()
+    # compiled, not lowered: a lowered operation inside the scan's body
+    # carries the body's own name stack, an optimised one's op_name the
+    # whole path, as a device trace's HloProto does
+    text = _lowered_step(trainer, (1, 16)).compile().as_text()
     op_names = set(re.findall(r'op_name="([^"]*)"', text))
     retention = sorted(n for n in KERNEL_NAMES if n.startswith("retn_"))
     assert retention == ["retn_back", "retn_read", "retn_write"]

@@ -47,7 +47,7 @@ from ..generation import _rms
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..ops.power_retention import power_retention
-from .rope import apply_rope, rope_frequencies
+from .rope import apply_rope, norm_rope, rope_frequencies
 from .sub_block import Params, SubBlock, blocked_lm_loss, over_token_blocks
 
 __all__ = ["BrumbyConfig", "BrumbyModel", "BrumbyForCausalLM", "brumby_tiny",
@@ -95,10 +95,15 @@ class BrumbyConfig:
         self.dtype = dtype
 
 
+def _frequencies(theta, head_dim):
+    """The default kind's (inv_freq, attention_factor) (models/rope.py)."""
+    return rope_frequencies({"rope_type": "default", "rope_theta": theta},
+                            head_dim)
+
+
 def _rope(x, theta):
     """Default-kind rotate-half RoPE at positions 0..T-1 (models/rope.py)."""
-    return apply_rope(x, *rope_frequencies(
-        {"rope_type": "default", "rope_theta": theta}, x.shape[-1]))
+    return apply_rope(x, *_frequencies(theta, x.shape[-1]))
 
 
 def retention_log_gate(h, norm_weight, gate_weight, gate_bias, eps):
@@ -151,10 +156,9 @@ class BrumbyRetention(SubBlock):
                                            g_proj_weight, g_proj_bias,
                                            c.rms_norm_eps)
             with jax.named_scope("pt.retn.pos"):
-                q = _rope(_rms(q, q_norm_weight, c.rms_norm_eps),
-                          c.rope_theta)
-                k = _rope(_rms(k, k_norm_weight, c.rms_norm_eps),
-                          c.rope_theta)
+                rope = _frequencies(c.rope_theta, d)
+                q = norm_rope(q, q_norm_weight, c.rms_norm_eps, *rope)
+                k = norm_rope(k, k_norm_weight, c.rms_norm_eps, *rope)
             y = power_retention(q, k, v, log_g, c.retention_chunk,
                                 c.retention_eps)
             with jax.named_scope("pt.retn.out"):

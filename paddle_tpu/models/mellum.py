@@ -52,7 +52,7 @@ from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.functional.attention import attention_bshd
 from ..parallel.moe import dropless_moe
-from .rope import apply_rope, rope_frequencies
+from .rope import norm_rope, rope_frequencies
 from .sub_block import Params, SubBlock, blocked_lm_loss, over_token_blocks
 
 __all__ = ["MellumConfig", "MellumModel", "MellumForCausalLM", "mellum_tiny"]
@@ -181,8 +181,8 @@ class MellumAttention(SubBlock):
             with jax.named_scope("pt.attn.pos"):
                 rope = rope_frequencies(
                     c.rope_parameters[self.layer_type], d)
-                q = apply_rope(_rms(q, q_norm_weight, c.rms_norm_eps), *rope)
-                k = apply_rope(_rms(k, k_norm_weight, c.rms_norm_eps), *rope)
+                q = norm_rope(q, q_norm_weight, c.rms_norm_eps, *rope)
+                k = norm_rope(k, k_norm_weight, c.rms_norm_eps, *rope)
             y = attention_bshd(q, k, v, is_causal=True, scale=d ** -0.5,
                                window=c.window_of(self.layer_type))
             with jax.named_scope("pt.attn.out"):

@@ -12,15 +12,29 @@ in bf16, a second rounding of every rotated entry (tests/test_brumby.py
 holds both to a float64 rotation). The callers enter the scope the work
 carries on a device trace, tables included: `pt.attn.pos` (models/mellum.py),
 `pt.retn.pos` (models/brumby.py).
+
+`norm_rope` is what both models call for q and for k: the per-head RMSNorm
+(generation._rms), its weight and the rotation. Rotate-half is a rotation
+by d / 2 lanes times a sine table whose first half carries the minus sign;
+where the lanes of a head can be rotated whole (`_rotates_whole_lanes`: the
+input says, no flag) the three are one pass over the projection in VMEM,
+forward and backward (ops/pallas/rope_norm.py, `normrope_fwd` and
+`normrope_bwd`, under a `custom_vjp` whose residuals are the raw projection
+and the weight); every other call is the `jax.numpy` composition.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["rope_frequencies", "apply_rope"]
+from ..generation import _rms
+from ..ops.pallas import rope_norm as _kernels
+
+__all__ = ["rope_frequencies", "apply_rope", "norm_rope"]
 
 
 def _yarn_correction_range(params, head_dim):
@@ -70,16 +84,74 @@ def rope_frequencies(params, head_dim):
             float(attention_factor))
 
 
+def _rotation_tables(positions, inv_freq, attention_factor):
+    """cos and sin at positions 0..positions-1, (positions, d) float32,
+    both scaled by `attention_factor`; sin with the first half's sign, so
+    that rotate-half is x cos + roll(x, d / 2) sin."""
+    freqs = jnp.arange(positions, dtype=jnp.float32)[:, None] * inv_freq
+    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    if attention_factor != 1.0:
+        cos, sin = cos * attention_factor, sin * attention_factor
+    return (jnp.concatenate([cos, cos], axis=-1),
+            jnp.concatenate([-sin, sin], axis=-1))
+
+
 def apply_rope(x, inv_freq, attention_factor=1.0):
     """Rotate-half RoPE at positions 0..T-1 of x (batch, T, heads, d): the
     tables and the rotation in float32, cos and sin scaled by
     `attention_factor`, one rounding to x's type."""
-    freqs = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv_freq
-    emb = jnp.concatenate([freqs, freqs], axis=-1)[None, :, None, :]
-    cos, sin = jnp.cos(emb), jnp.sin(emb)
-    if attention_factor != 1.0:
-        cos, sin = cos * attention_factor, sin * attention_factor
+    cos, sin = _rotation_tables(x.shape[1], inv_freq, attention_factor)
     x32 = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x32, 2, axis=-1)
-    rot = jnp.concatenate([-x2, x1], axis=-1)
-    return (x32 * cos + rot * sin).astype(x.dtype)
+    rolled = jnp.roll(x32, x.shape[-1] // 2, axis=-1)
+    return (x32 * cos[:, None, :] + rolled * sin[:, None, :]).astype(x.dtype)
+
+
+def _rotates_whole_lanes(x, norm_weight):
+    """Whether `norm_rope` of these operands runs the kernels: on a TPU, a
+    head's lanes whole vector registers (head_dim a multiple of 128),
+    bf16 or float32 throughout, and a row tile that divides the positions."""
+    return (jax.default_backend() == "tpu"
+            and x.shape[-1] % 128 == 0
+            and x.dtype == norm_weight.dtype
+            and x.dtype in (jnp.bfloat16, jnp.float32)
+            and _kernels.row_tile(x.shape[1]) is not None)
+
+
+def norm_rope(x, norm_weight, eps, inv_freq, attention_factor=1.0):
+    """apply_rope(_rms(x, norm_weight, eps), inv_freq, attention_factor) of
+    a projection x (batch, T, heads, d): the RMSNorm over each head's d
+    entries, its weight (d,), the rotation at positions 0..T-1; float32
+    arithmetic, rounded to x's type after the norm, after the weight and
+    after the rotation."""
+    if _rotates_whole_lanes(x, norm_weight):
+        return _norm_rope_in_vmem(x, norm_weight, inv_freq, float(eps),
+                                  float(attention_factor))
+    return apply_rope(_rms(x, norm_weight, eps), inv_freq, attention_factor)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _norm_rope_in_vmem(x, norm_weight, inv_freq, eps, attention_factor):
+    return _norm_rope_fwd(x, norm_weight, inv_freq, eps, attention_factor)[0]
+
+
+def _as_rows(x):
+    """(batch, T, heads, d) as the kernels' (tokens, heads x d)."""
+    return x.reshape(x.shape[0] * x.shape[1], x.shape[2] * x.shape[3])
+
+
+def _norm_rope_fwd(x, norm_weight, inv_freq, eps, attention_factor):
+    tables = _rotation_tables(x.shape[1], inv_freq, attention_factor)
+    out = _kernels.forward(_as_rows(x), norm_weight, *tables, eps)
+    return out.reshape(x.shape), (x, norm_weight, inv_freq)
+
+
+def _norm_rope_bwd(eps, attention_factor, saved, g):
+    x, norm_weight, inv_freq = saved
+    tables = _rotation_tables(x.shape[1], inv_freq, attention_factor)
+    dx, dw = _kernels.backward(_as_rows(x), norm_weight, *tables,
+                               _as_rows(g), eps)
+    return (dx.reshape(x.shape), dw.astype(norm_weight.dtype),
+            jnp.zeros_like(inv_freq))
+
+
+_norm_rope_in_vmem.defvjp(_norm_rope_fwd, _norm_rope_bwd)

@@ -644,6 +644,14 @@ KERNEL_NAMES = {
                   "state's update; in the backward, the state's cotangent)",
     "retn_back": "power retention: the chain rule through phi back to q "
                  "or k, phi's own cotangent never in memory",
+    "normrope_fwd": "per-head q/k RMSNorm, its weight and rotate-half RoPE "
+                    "in one pass over a projection (models/rope.py "
+                    "norm_rope), float32 in VMEM, rounded where the "
+                    "jax.numpy composition rounds",
+    "normrope_bwd": "its backward from the cotangent and the raw "
+                    "projection: the rotation's transpose, the weight, the "
+                    "norm's chain rule, the weight's gradient as float32 "
+                    "partial sums a row tile",
 }
 
 

@@ -12,6 +12,7 @@ handed it loads the TPU's library, inside the fixture.
 
 import collections
 import functools
+import math
 import os
 import re
 
@@ -436,20 +437,41 @@ def test_retention_kernels_run_in_the_passes_the_rule_gives_them(
     assert passes["retn_write"] == {"forward": 1, "backward": 1}
 
 
-def _mellum_mixer_calls(one_chip, monkeypatch, kind):
-    """The Pallas calls of one Mellum mixer of `kind` (16,384 positions, 32
-    query heads over 4 key-value heads, d 128, bf16, window 1,024;
-    models/mellum.py MellumAttention) compiled for a described v5e: the
-    value and the gradients through the sub-block's rematerialisation, so
-    the forward, its recomputation and the backward are all there (the
+def _rematerialised_loss(sub):
+    """h, params -> a scalar through the sub-block's `_pure` under
+    jax.checkpoint: with its value and gradients the forward, the
+    recomputation and the backward are all in one compiled text (the
     gradient alone drops the first forward: nothing reads it)."""
+    def loss(h_, p_):
+        block = jax.checkpoint(lambda x: sub._pure(x, **p_))
+        return jnp.sum(block(h_).astype(jnp.float32))
+    return jax.value_and_grad(loss, argnums=(0, 1))
+
+
+def _sub_block_operands(sub, hidden, one_chip):
+    """(h (1, 16384, hidden), {parameter: shape}) of a sub-block, bf16 on
+    the described chip."""
+    names, tensors = zip(*sub.named_parameters())
+    params = {n.replace(".", "_"): jax.ShapeDtypeStruct(
+        tuple(t.shape), jnp.bfloat16, sharding=one_chip)
+        for n, t in zip(names, tensors)}
+    return jax.ShapeDtypeStruct((1, 16384, hidden), jnp.bfloat16,
+                                sharding=one_chip), params
+
+
+def _mellum_mixer_text(one_chip, monkeypatch, kind):
+    """One Mellum mixer of `kind` (16,384 positions, 32 query heads over 4
+    key-value heads, d 128, bf16, window 1,024; models/mellum.py
+    MellumAttention) compiled for a described v5e, value and gradients
+    through the sub-block's rematerialisation."""
     if kind in _COMPILED:
         return _COMPILED[kind]
     import paddle_tpu as paddle
     from paddle_tpu.models.mellum import MellumAttention, MellumConfig
     from paddle_tpu.ops.pallas import attention_router
-    # the described chip is not jax.default_backend(): the rule and the
-    # kernels' interpret switch are told it is
+    # the described chip is not jax.default_backend(): the rules (the
+    # attention router's, models/rope.py's) and the kernels' interpret
+    # switch are told it is
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     attention_router.clear_routing_cache()
     cfg = MellumConfig(num_hidden_layers=2, layer_types=[
@@ -457,22 +479,34 @@ def _mellum_mixer_calls(one_chip, monkeypatch, kind):
         dtype="bfloat16")
     with paddle.LazyGuard():
         sub = MellumAttention(cfg, kind)
-    names, tensors = zip(*sub.named_parameters())
-    params = {n.replace(".", "_"): jax.ShapeDtypeStruct(
-        tuple(t.shape), jnp.bfloat16, sharding=one_chip)
-        for n, t in zip(names, tensors)}
-    h = jax.ShapeDtypeStruct((1, 16384, cfg.hidden_size), jnp.bfloat16,
-                             sharding=one_chip)
-
-    def loss(h_, p_):
-        block = jax.checkpoint(lambda x: sub._pure(x, **p_))
-        return jnp.sum(block(h_).astype(jnp.float32))
-
-    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
-        h, params).compile().as_text()
+    _COMPILED[kind] = jax.jit(_rematerialised_loss(sub)).lower(
+        *_sub_block_operands(sub, cfg.hidden_size, one_chip)
+    ).compile().as_text()
     attention_router.clear_routing_cache()
-    _COMPILED[kind] = _kernel_calls(text)
     return _COMPILED[kind]
+
+
+def _mellum_mixer_calls(one_chip, monkeypatch, kind):
+    """The Pallas calls of that mixer."""
+    return _kernel_calls(_mellum_mixer_text(one_chip, monkeypatch, kind))
+
+
+def _brumby_mixer_text(one_chip, monkeypatch):
+    """One retention mixer of the Brumby cell (16,384 positions, 40 query
+    heads over 8 state heads, d 128, bf16; models/brumby.py
+    BrumbyRetention) compiled the same way."""
+    if "brumby_mixer" in _COMPILED:
+        return _COMPILED["brumby_mixer"]
+    import paddle_tpu as paddle
+    from paddle_tpu.models.brumby import BrumbyConfig, BrumbyRetention
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = BrumbyConfig(num_hidden_layers=1, vocab_size=256, dtype="bfloat16")
+    with paddle.LazyGuard():
+        sub = BrumbyRetention(cfg)
+    _COMPILED["brumby_mixer"] = jax.jit(_rematerialised_loss(sub)).lower(
+        *_sub_block_operands(sub, cfg.hidden_size, one_chip)
+    ).compile().as_text()
+    return _COMPILED["brumby_mixer"]
 
 
 def test_mellum_mixers_compile_with_their_kernels_under_their_scopes(
@@ -496,12 +530,13 @@ def test_mellum_mixers_compile_with_their_kernels_under_their_scopes(
             # the backward's too: the rematerialised sub-block's transpose
             # is traced inside its scopes
             assert scope.search(op_name), op_name
-    assert set(seen["sliding_attention"]) == {
+    positions = {"normrope_fwd", "normrope_bwd"}      # models/rope.py
+    assert set(seen["sliding_attention"]) - positions == {
         "faw_fwd", "faw_bwd_dq", "faw_bwd_dkv"}
-    assert set(seen["full_attention"]) == {
+    assert set(seen["full_attention"]) - positions == {
         "fa_fwd", "fa_bwd_dq", "fa_bwd_dkv"}
-    assert set(seen["sliding_attention"]) | set(seen["full_attention"]) == {
-        n for n in KERNEL_NAMES if n.startswith("fa")}
+    assert (set(seen["sliding_attention"]) | set(seen["full_attention"])) \
+        - positions == {n for n in KERNEL_NAMES if n.startswith("fa")}
 
 
 @pytest.mark.parametrize("kind, prefix", [("sliding_attention", "faw"),
@@ -567,6 +602,108 @@ def _matmul_fusions(text):
                 if convs:
                     out.append((shape, ops, convs))
     return out
+
+
+# (id, tokens, positions, heads): the q and k projections of the Mellum
+# cell (2 x 16,384 tokens, 32 and 4 heads) and of the Brumby cell (16,384
+# tokens, 40 and 8 heads), d 128, bf16
+NORMROPE_CASES = [
+    ("mellum_q", 32768, 16384, 32), ("mellum_k", 32768, 16384, 4),
+    ("brumby_q", 16384, 16384, 40), ("brumby_k", 16384, 16384, 8)]
+
+
+@pytest.mark.parametrize("case", NORMROPE_CASES,
+                         ids=[c[0] for c in NORMROPE_CASES])
+def test_normrope_kernels_compile_at_the_cells_shapes(one_chip, case):
+    """ops/pallas/rope_norm.py at the tiles it chooses: what the
+    interpreter cannot refuse and Mosaic can is a lane rotation of a
+    float32 tile, a block of eight heads' lanes beside two float32 table
+    tiles inside Mosaic's default VMEM limit (the calls ask for no other:
+    a tiling that needs more fails to compile here), and the weight's
+    gradient accumulated over the inner grid axis."""
+    from paddle_tpu.ops.pallas import rope_norm
+    _, tokens, positions, heads = case
+    d = 128
+
+    def sds(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    x, w = sds(tokens, heads * d), sds(d)
+    table = sds(positions, d, dt=jnp.float32)
+    text = jax.jit(functools.partial(
+        rope_norm.forward, eps=1e-6, interpret=False)).lower(
+            x, w, table, table).compile().as_text()
+    assert "tpu_custom_call" in text and "normrope_fwd" in text
+    text = jax.jit(functools.partial(
+        rope_norm.backward, eps=1e-6, interpret=False)).lower(
+            x, w, table, table, x).compile().as_text()
+    assert "tpu_custom_call" in text and "normrope_bwd" in text
+
+
+_TOP_LEVEL = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\(")
+
+
+def _written_under(text, scope):
+    """[(pass, opcode, result shape)] of the instructions of a compiled
+    text that run as operations of their own (not inside a fusion) under
+    `scope`."""
+    from paddle_tpu.observability.catalog import trace_pass
+    out, fused = [], False
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            fused = "fused_computation" in head.group(1)
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        m = _TOP_LEVEL.match(line)
+        if fused or not m or not op_name or scope not in op_name.group(1):
+            continue
+        if m.group(3) not in ("parameter", "constant", "bitcast", "tuple",
+                              "get-tuple-element"):
+            out.append((trace_pass(op_name.group(1), m.group(1)),
+                        m.group(3), m.group(2)))
+    return out
+
+
+def _float32_elements(shape):
+    """The largest float32 array of a result shape, in elements."""
+    return max([math.prod(int(n) for n in dims.split(",") if n)
+                for dims in re.findall(r"f32\[([\d,]*)\]", shape)] or [0])
+
+
+MIXERS = [("sliding_attention", "pt.attn.sliding/pt.attn.pos", 4),
+          ("full_attention", "pt.attn.full/pt.attn.pos", 4),
+          ("retention", "pt.retn.pos", 8)]
+
+
+@pytest.mark.parametrize("kind, scope, kv_heads", MIXERS,
+                         ids=[m[0] for m in MIXERS])
+def test_the_position_part_is_the_two_kernels_and_no_float32_projection(
+        one_chip, monkeypatch, kind, scope, kv_heads):
+    """models/rope.py norm_rope in the compiled Mellum mixers and the
+    Brumby mixer: under the part's scope `normrope_fwd` runs for q and for
+    k in the forward and in the sub-block's recomputation, `normrope_bwd`
+    for each in the backward alone (catalog.py trace_pass), and in no pass
+    does an operation under that scope write a float32 array of a
+    projection's size (k's, the smaller): before PR 37 XLA wrote q's three
+    times a pass there. What is left beside the kernels is the tables
+    (positions x 128 float32) and the weight's gradient."""
+    from paddle_tpu.observability.catalog import trace_pass
+    text = _brumby_mixer_text(one_chip, monkeypatch) if kind == "retention" \
+        else _mellum_mixer_text(one_chip, monkeypatch, kind)
+    passes = collections.defaultdict(collections.Counter)
+    for kernel, op_name, inst in _kernel_calls(text):
+        if kernel.startswith("normrope"):
+            assert scope in op_name.replace("jvp(", "").replace(")", ""), \
+                op_name
+            passes[kernel][trace_pass(op_name, inst)] += 1
+    assert passes == {"normrope_fwd": {"forward": 2, "recompute": 2},
+                      "normrope_bwd": {"backward": 2}}
+    written = _written_under(text, scope.split("/")[-1])
+    assert {p for p, _, _ in written} == {"forward", "recompute", "backward"}
+    for pass_, opcode, shape in written:
+        assert _float32_elements(shape) < 16384 * kv_heads * 128, (
+            pass_, opcode, shape)
 
 
 def test_gelu_once_leaves_the_activation_in_one_fusion_a_layer(one_chip):

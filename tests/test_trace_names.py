@@ -665,6 +665,54 @@ def test_retention_kernels_sit_under_the_scan_s_scope_both_ways():
             assert any("transpose(jvp(" not in h for h in hits), name
 
 
+@pytest.mark.parametrize("model, scopes", [
+    ("mellum", ("pt.attn.sliding/pt.attn.pos", "pt.attn.full/pt.attn.pos")),
+    ("brumby", ("pt.retn/pt.retn.pos",))])
+def test_position_kernels_keep_the_name_stack_of_their_part(monkeypatch,
+                                                            model, scopes):
+    """At head_dim 128 (and, here, a rule told that the backend is a TPU)
+    models/rope.py norm_rope is the `normrope_*` kernels
+    (ops/pallas/rope_norm.py). In the compiled train step every operation
+    of theirs is under the mixer's position part, for every kind of layer:
+    `normrope_fwd` in the forward and in the sub-block's recomputation,
+    `normrope_bwd` in the backward alone (catalog.py trace_pass), so the
+    kernels' time stays in `mixer_pos_time_share` and in the mixer's own
+    share, and a `pass_report.py` table shows each once a pass."""
+    from paddle_tpu.models import rope
+    from paddle_tpu.parallel import DP_ONLY_RULES
+    monkeypatch.setattr(rope, "_rotates_whole_lanes", lambda x, w: True)
+    paddle.seed(0)
+    if model == "mellum":
+        from paddle_tpu.models.mellum import mellum_tiny
+        net = mellum_tiny(num_hidden_layers=2, num_attention_heads=2,
+                          head_dim=128, layer_types=[
+                              "sliding_attention", "full_attention"])
+    else:
+        from paddle_tpu.models.brumby import brumby_tiny
+        net = brumby_tiny(num_hidden_layers=1, num_attention_heads=2,
+                          num_key_value_heads=1, head_dim=128)
+    opt = optimizer.AdamW(1e-3, parameters=net.parameters())
+    trainer = SpmdTrainer(net, opt, create_mesh(devices=jax.devices()[:1]),
+                          DP_ONLY_RULES)
+    text = _lowered_step(trainer, (1, 16)).compile().as_text()
+    positions = sorted(n for n in KERNEL_NAMES if n.startswith("normrope_"))
+    assert positions == ["normrope_bwd", "normrope_fwd"]
+    found = {name: set() for name in positions}
+    for instruction, op_name in re.findall(
+            r'^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name="([^"]*)"', text,
+            re.MULTILINE):
+        for name in positions:
+            if f"/{name}/" in op_name:
+                plain = op_name.replace("jvp(", "").replace(
+                    "transpose(", "").replace(")", "")
+                assert any(scope in plain for scope in scopes), op_name
+                found[name].add((next(s for s in scopes if s in plain),
+                                 trace_pass(op_name, instruction)))
+    assert found["normrope_fwd"] == {
+        (scope, p) for scope in scopes for p in ("forward", "recompute")}
+    assert found["normrope_bwd"] == {(scope, "backward") for scope in scopes}
+
+
 def test_scope_names_stay_clear_of_effect_scopes():
     from paddle_tpu.pir.verifier import EFFECT_SCOPES
     for name in list(TRACE_SCOPES) + list(KERNEL_NAMES):

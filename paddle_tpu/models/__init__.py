@@ -13,3 +13,5 @@ from .brumby import (BrumbyConfig, BrumbyModel, BrumbyForCausalLM,  # noqa: F401
                      brumby_tiny)
 from .mellum import (MellumConfig, MellumModel, MellumForCausalLM,  # noqa: F401
                      mellum_tiny)
+from .phi4flash import (Phi4FlashConfig, Phi4FlashModel,  # noqa: F401
+                        Phi4FlashForCausalLM, phi4flash_tiny)

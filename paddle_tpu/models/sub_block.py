@@ -1,5 +1,6 @@
 """Residual sub-blocks that are rematerialised in the backward: what
-models/granite_moe_hybrid.py and models/brumby.py build their layers from.
+models/granite_moe_hybrid.py, models/brumby.py, models/mellum.py and
+models/phi4flash.py build their layers from.
 
 A step then holds the sub-blocks' inputs and one sub-block's internals.
 The layers are not stacked and scanned: a scan's backward returns the
@@ -17,7 +18,8 @@ from ..framework.core import execute
 from ..framework.param_attr import ParamAttr
 from ..generation import _rms
 
-__all__ = ["Params", "SubBlock", "over_token_blocks", "blocked_lm_loss"]
+__all__ = ["Params", "SubBlock", "over_token_blocks", "blocked_lm_loss",
+           "layer_norm"]
 
 
 class Params(nn.Layer):
@@ -38,22 +40,40 @@ class SubBlock(nn.Layer):
     `rematted_computation`) and backward; observability/catalog.py
     trace_pass tells them apart."""
 
-    def _pure(self, h, **params):
+    def _pure(self, h, *extra, **params):
         raise NotImplementedError
 
-    def _over(self, block, h):
+    def _over(self, block, h, *extra):
         """`block` (the rematerialised `_pure`) over the hidden states."""
-        return block(h)
+        return block(h, *extra)
 
-    def forward(self, hidden):
+    def forward(self, hidden, *extra):
+        """`extra`: arrays a sub-block reads beside the residual stream (an
+        earlier layer's keys and values, a memory), passed to `_pure` after
+        it and rematerialised with it. `_pure` may return a tuple: the
+        residual stream first, then what later sub-blocks read."""
         names, tensors = zip(*self.named_parameters())
+        n = len(extra)
 
         def pure(h, *arrays):
-            params = {n.replace(".", "_"): a for n, a in zip(names, arrays)}
-            return self._over(
-                jax.checkpoint(lambda hb: self._pure(hb, **params)), h)
+            params = {k.replace(".", "_"): a
+                      for k, a in zip(names, arrays[n:])}
+            return self._over(jax.checkpoint(
+                lambda hb, *xb: self._pure(hb, *xb, **params)), h,
+                *arrays[:n])
 
-        return execute(pure, hidden, *tensors, _name=type(self).__name__)
+        return execute(pure, hidden, *extra, *tensors,
+                       _name=type(self).__name__)
+
+
+def layer_norm(x, weight, bias, eps):
+    """LayerNorm over the last axis, statistics in float32, in x's type."""
+    x32 = x.astype(jnp.float32)
+    mean = jnp.mean(x32, -1, keepdims=True)
+    var = jnp.mean(jnp.square(x32 - mean), -1, keepdims=True)
+    y = (x32 - mean) * jax.lax.rsqrt(var + eps)
+    return (y * weight.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
 
 
 def over_token_blocks(block, h, size):
@@ -70,12 +90,13 @@ def over_token_blocks(block, h, size):
     return jax.lax.map(block, blocks).reshape(b, s, d)
 
 
-def blocked_lm_loss(h, norm_w, head_w, labels, eps, block):
+def blocked_lm_loss(h, norm_w, head_w, labels, eps, block, norm_b=None):
     """Mean next-token cross entropy of (batch, T, hidden) against labels
-    (batch, T): final norm, head and log-softmax over `block` tokens at a
-    time, each block rematerialised in the backward. Every position is a
-    row, so that the blocks are even; a sequence's last position, which
-    predicts nothing, carries weight 0."""
+    (batch, T): final norm (RMSNorm; LayerNorm where `norm_b` is given),
+    head and log-softmax over `block` tokens at a time, each block
+    rematerialised in the backward. Every position is a row, so that the
+    blocks are even; a sequence's last position, which predicts nothing,
+    carries weight 0."""
     b, s, d = h.shape
     rows = h.reshape(b * s, d)
     targets = jnp.roll(labels, -1, axis=1).reshape(b * s)
@@ -84,8 +105,9 @@ def blocked_lm_loss(h, norm_w, head_w, labels, eps, block):
     @jax.checkpoint
     def block_sum(x, tgt, on):
         with jax.named_scope("pt.head"):
-            logits = jnp.dot(_rms(x, norm_w, eps), head_w,
-                             preferred_element_type=jnp.float32)
+            x = _rms(x, norm_w, eps) if norm_b is None else \
+                layer_norm(x, norm_w, norm_b, eps)
+            logits = jnp.dot(x, head_w, preferred_element_type=jnp.float32)
         with jax.named_scope("pt.loss"):
             lse = jax.nn.logsumexp(logits, axis=-1)
             own = jnp.take_along_axis(logits, tgt[:, None], axis=-1)[:, 0]

@@ -547,16 +547,33 @@ TRACE_SCOPES = {
                        "the faw_* kernels)",
     "pt.attn.full": "inside pt.attn, a full-attention layer's whole mixer "
                     "in a model that mixes kinds (models/mellum.py "
-                    "full_attention: YaRN RoPE, the fa_* kernels)",
+                    "full_attention: YaRN RoPE, the fa_* kernels; "
+                    "models/phi4flash.py: the differential full layer "
+                    "whose keys and values the cross layers read)",
+    "pt.attn.cross": "inside pt.attn, a cross-decoder layer's whole mixer "
+                     "(models/phi4flash.py cross_attention: queries alone, "
+                     "the full layer's keys and values, the fa_* kernels)",
+    "pt.attn.diff": "inside pt.attn, differential attention's combination "
+                    "of its two maps: lambda, the difference and the "
+                    "per-head sub-norm (models/phi4flash.py)",
+    "pt.gmu": "gated memory unit sub-block (models/phi4flash.py): its "
+              "norm, the gate's projection, the memory times the gate, the "
+              "output projection and the residual add",
     "pt.mlp": "MLP sub-block with its norm (in an expert layer: the norm, "
               "the shared expert and the residual)",
-    "pt.ssm": "state-space (Mamba-2) mixer: its parts pt.ssm.in, "
-              "pt.ssm.conv, pt.ssm.scan, pt.ssm.gate, pt.ssm.out",
+    "pt.ssm": "state-space mixer: Mamba-2 (its parts pt.ssm.in, "
+              "pt.ssm.conv, pt.ssm.scan, pt.ssm.gate, pt.ssm.out) or "
+              "Mamba-1 (pt.ssm.in, pt.ssm.conv, pt.ssm.sel, pt.ssm.out)",
     "pt.ssm.in": "inside pt.ssm: the input norm, in_proj and its split "
-                 "into z, xBC and dt",
+                 "into z, xBC and dt (Mamba-1: x and z)",
     "pt.ssm.conv": "inside pt.ssm: the causal depthwise conv with its silu "
-                   "and dt's softplus",
+                   "and dt's softplus (Mamba-1: with x_proj and dt_proj, "
+                   "which make delta, B and C from its output)",
     "pt.ssm.scan": "the chunked state-space scan alone (ops/mamba2.py)",
+    "pt.ssm.sel": "Mamba-1's selective scan alone with its z gate "
+                  "(ops/selective_scan.py; on a TPU the selscan_* kernels "
+                  "and the layout of B, C and their cotangents around "
+                  "them)",
     "pt.ssm.gate": "inside pt.ssm: the gated RMSNorm of the scan's output",
     "pt.ssm.out": "inside pt.ssm: out_proj and the residual add",
     "pt.retn": "power-retention mixer (models/brumby.py): its parts "
@@ -627,7 +644,8 @@ def trace_pass(op_name, instruction_name=""):
 
 
 # `name=` of the Pallas kernels (ops/pallas/flash_attention.py,
-# ops/pallas/power_retention.py): the Mosaic kernel name and the innermost
+# ops/pallas/power_retention.py, ops/pallas/rope_norm.py,
+# ops/pallas/selective_scan.py): the Mosaic kernel name and the innermost
 # scope of the call on the trace.
 KERNEL_NAMES = {
     "fa_fwd": "flash attention forward (+ fused RMS epilogue)",
@@ -652,6 +670,13 @@ KERNEL_NAMES = {
                     "projection: the rotation's transpose, the weight, the "
                     "norm's chain rule, the weight's gradient as float32 "
                     "partial sums a row tile",
+    "selscan_fwd": "Mamba-1 selective scan forward with its z gate: the "
+                   "(state, channels) float32 state in VMEM, one row of "
+                   "time at a time; writes the state before every 128 "
+                   "steps (ops/pallas/selective_scan.py)",
+    "selscan_bwd": "its backward, a chunk of 128 steps a grid step from "
+                   "the last: the chunk's states made again from the saved "
+                   "one, then the adjoint walked back through them",
 }
 
 

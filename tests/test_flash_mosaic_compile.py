@@ -756,3 +756,40 @@ def test_gelu_once_leaves_the_activation_in_one_fusion_a_layer(one_chip):
     control = [f for f in compiled(
         lambda a: jax.nn.gelu(a, approximate=False)) if f[1]["exponential"]]
     assert len(control) >= 3 * layers, [(f[0], f[2]) for f in control]
+
+
+@pytest.mark.parametrize("batch, seq, channels", [
+    (1, 32768, 5120), (2, 1000, 640)], ids=["phi4flash_cell", "ragged"])
+def test_selective_scan_kernels_compile_under_their_scope(
+        one_chip, monkeypatch, batch, seq, channels):
+    """ops/selective_scan.py with the kernels (ops/pallas/selective_scan.py)
+    at the Phi-4-mini-flash cell's one layer (32,768 positions, 5,120
+    channels, 16 states, bf16) and at a length that pads and a channel
+    count that takes tiles of 128, forward and backward: what the
+    interpreter cannot refuse and Mosaic can (a row of time loaded at a
+    dynamic sublane offset, a lane taken from B and C by a one-hot select,
+    the chunk's states indexed on the leading axis, the reversed grid).
+    Both kernels under pt.ssm.sel, the backward's too."""
+    from paddle_tpu.ops import selective_scan as op
+    from paddle_tpu.ops.pallas import selective_scan as kernels
+    # the described chip is not jax.default_backend(): the rule is steered
+    # and the kernels asked for compiled
+    monkeypatch.setattr(kernels, "supported", lambda u, a: True)
+    monkeypatch.setattr(kernels, "_interpret_default", lambda: False)
+
+    def sds(*shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def loss(u, delta, a, b, c, d, z, bias):
+        return jnp.sum(op.selective_scan(u, delta, a, b, c, d, z, bias
+                                         ).astype(jnp.float32))
+
+    big, small = sds(batch, seq, channels), sds(batch, seq, 16)
+    vec = sds(channels)
+    text = jax.jit(jax.value_and_grad(loss, argnums=tuple(range(8)))).lower(
+        big, big, sds(channels, 16, dt=jnp.float32), small, small, vec, big,
+        vec).compile().as_text()
+    calls = _kernel_calls(text)
+    assert sorted(name for name, _, _ in calls) == ["selscan_bwd",
+                                                    "selscan_fwd"]
+    assert all("pt.ssm.sel" in op_name for _, op_name, _ in calls)

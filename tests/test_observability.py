@@ -304,13 +304,14 @@ class TestCatalog:
     @pytest.mark.parametrize("name", sorted(obs_catalog.KERNEL_NAMES))
     def test_kernel_names_have_their_row_in_the_docs(self, name):
         """Every `name=` of a Pallas kernel, the flash kernels', the
-        power retention's `retn_*` and the position part's `normrope_*`,
-        has a `kernel/NAME` row in
+        power retention's `retn_*`, the position part's `normrope_*` and
+        the selective scan's `selscan_*`, has a `kernel/NAME` row in
         OBSERVABILITY.md beside the scopes: a trace's reader finds the
         kernel by that name."""
         text = open(os.path.join(REPO, "OBSERVABILITY.md")).read()
         assert re.search(rf"^\| `kernel/{name}` \| \S", text, re.MULTILINE)
-        assert name.startswith(("fa_", "faw_", "retn_", "normrope_"))
+        assert name.startswith(("fa_", "faw_", "retn_", "normrope_",
+                                "selscan_"))
 
     def test_metric_refuses_unknown_names(self):
         with pytest.raises(KeyError, match="catalog"):

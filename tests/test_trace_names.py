@@ -50,6 +50,9 @@ HYBRID_SCOPES = ("pt.ssm", "pt.ssm.scan", "pt.moe", "pt.moe.route")
 RETENTION_SCOPES = ("pt.retn", "pt.retn.scan")
 # what models/mellum.py adds: the attention kind, inside pt.attn
 KIND_SCOPES = ("pt.attn.sliding", "pt.attn.full")
+# what models/phi4flash.py adds: the cross-decoder's attention kind and
+# the gated memory unit
+SAMBAY_SCOPES = ("pt.attn.cross", "pt.gmu")
 # the parts of every mixer, by the model with a training cell that enters
 # them: {model: {mixer scope: its parts}} (ISSUE 36)
 PART_SCOPES = {
@@ -62,6 +65,14 @@ PART_SCOPES = {
                                    "pt.attn.out"),
                "pt.attn.full": ("pt.attn.in", "pt.attn.pos",
                                 "pt.attn.out")},
+    "phi4flash": {"pt.ssm": ("pt.ssm.in", "pt.ssm.conv", "pt.ssm.sel",
+                             "pt.ssm.out"),
+                  "pt.attn.sliding": ("pt.attn.in", "pt.attn.diff",
+                                      "pt.attn.out"),
+                  "pt.attn.full": ("pt.attn.in", "pt.attn.diff",
+                                   "pt.attn.out"),
+                  "pt.attn.cross": ("pt.attn.in", "pt.attn.diff",
+                                    "pt.attn.out")},
 }
 # the scopes whose sub-block is rematerialised (models/sub_block.py), and
 # with it runs forward, recomputed and backward
@@ -70,7 +81,12 @@ REMAT_SCOPES = {
     "brumby": ("pt.retn", "pt.retn.scan", "pt.mlp", "pt.head", "pt.loss"),
     "mellum": ("pt.attn.sliding", "pt.attn.full", "pt.moe", "pt.head",
                "pt.loss"),
+    "phi4flash": ("pt.ssm", "pt.ssm.sel", "pt.attn.sliding", "pt.attn.full",
+                  "pt.attn.cross", "pt.gmu", "pt.mlp", "pt.head", "pt.loss"),
 }
+# the scope of the scan whose chunks a loop recomputes, by model
+LOOP_SCANS = {"granite": "pt.ssm.scan", "brumby": "pt.retn.scan",
+              "phi4flash": "pt.ssm.sel"}
 # entered by a custom_vjp backward rule, not by a model (ops/mamba2.py)
 RULE_SCOPES = ("pt.recompute",)
 
@@ -88,9 +104,12 @@ def _trainer(model="gpt"):
     elif model == "brumby":
         from paddle_tpu.models.brumby import brumby_tiny
         net, rules, shape = brumby_tiny(), DP_ONLY_RULES, (1, 16)
-    else:
+    elif model == "mellum":
         from paddle_tpu.models.mellum import mellum_tiny
         net, rules, shape = mellum_tiny(), DP_ONLY_RULES, (1, 16)
+    else:
+        from paddle_tpu.models.phi4flash import phi4flash_tiny
+        net, rules, shape = phi4flash_tiny(), DP_ONLY_RULES, (1, 16)
     opt = optimizer.AdamW(1e-3, parameters=net.parameters())
     return SpmdTrainer(net, opt, create_mesh(devices=jax.devices()[:1]),
                        rules), shape
@@ -361,14 +380,39 @@ def test_mellum_train_step_lowers_with_both_attention_kinds_scopes():
                for h in _scope_hits(text, "pt.moe.route"))
 
 
+def test_phi4flash_train_step_lowers_with_its_mixers_scopes():
+    """The hybrid of Mamba-1, differential attention of three kinds and
+    gated memory units names each mixer, forward and backward through the
+    sub-block's rematerialisation: the cross-decoder's attention under
+    pt.attn/pt.attn.cross (what `attn_cross_mixer_time_share` reads), the
+    GMU under pt.gmu (`gmu_time_share`), the recurrence under
+    pt.ssm/pt.ssm.sel (`sel_scan_time_share`); the Mamba-1 mixer has no
+    gate part (its gate is the scan's), and no mixer enters pt.attn.pos
+    (no position encoding)."""
+    text = _lowered_step(*_trainer("phi4flash")).as_text(debug_info=True)
+    for scope in (KIND_SCOPES + SAMBAY_SCOPES + ("pt.ssm", "pt.ssm.sel",
+                                                 "pt.attn.diff")
+                  + TRAIN_SCOPES):
+        hits = _scope_hits(text, scope)
+        assert hits, scope
+        if scope != "pt.opt":
+            assert any("transpose(jvp(" in h for h in hits), scope
+    for absent in ("pt.attn.pos", "pt.ssm.gate", "pt.ssm.scan", "pt.moe"):
+        assert not _scope_hits(text, absent), absent
+    nested = re.compile(r"pt\.attn\)*/pt\.attn\.cross")
+    assert all(nested.search(h) for h in _scope_hits(text, "pt.attn.cross"))
+    assert all(re.search(r"pt\.ssm\)*/pt\.ssm\.sel", h)
+               for h in _scope_hits(text, "pt.ssm.sel"))
+
+
 def test_every_declared_scope_is_held_by_a_test_and_none_else():
     """catalog.py TRACE_SCOPES against the scopes these tests look for in
     lowered programs, both directions."""
     parts = {p for mixers in PART_SCOPES.values()
              for ps in mixers.values() for p in ps}
     assert set(TRAIN_SCOPES) | set(SERVE_SCOPES) | set(HYBRID_SCOPES) \
-        | set(RETENTION_SCOPES) | set(KIND_SCOPES) | parts \
-        | set(RULE_SCOPES) == set(TRACE_SCOPES)
+        | set(RETENTION_SCOPES) | set(KIND_SCOPES) | set(SAMBAY_SCOPES) \
+        | parts | set(RULE_SCOPES) == set(TRACE_SCOPES)
 
 
 # -- (c) parts of a mixer and the pass of an operation ----------------------
@@ -448,9 +492,8 @@ def test_every_rematerialised_sub_block_runs_in_three_passes(model):
     assert all("pt.opt" in path for _, path in by_pass["update"])
     # the recomputation inside the backward's loop over chunks keeps its
     # pass through the loop's body
-    if model != "mellum":
-        scan = "pt.ssm.scan" if model == "granite" else "pt.retn.scan"
-        assert any("while/body" in op and scan in path
+    if model in LOOP_SCANS:
+        assert any("while/body" in op and LOOP_SCANS[model] in path
                    for op, path in by_pass["recompute"])
 
 
@@ -711,6 +754,42 @@ def test_position_kernels_keep_the_name_stack_of_their_part(monkeypatch,
     assert found["normrope_fwd"] == {
         (scope, p) for scope in scopes for p in ("forward", "recompute")}
     assert found["normrope_bwd"] == {(scope, "backward") for scope in scopes}
+
+
+def test_scan_kernels_keep_the_name_stack_of_the_scan(monkeypatch):
+    """Where the rule picks them (here steered, the kernels interpreted),
+    Mamba-1's recurrence is the `selscan_*` kernels: in the compiled train
+    step every operation of theirs is under pt.ssm/pt.ssm.sel, for the
+    plain and the memory Mamba alike, `selscan_fwd` in the forward and in
+    the sub-block's recomputation, `selscan_bwd` in the backward alone
+    (its call enters the scope itself where the step is transposed), so
+    that the kernels' time stays in `sel_scan_time_share` and
+    `sel_scan_roofline` finds them by name."""
+    from paddle_tpu.models.phi4flash import phi4flash_tiny
+    from paddle_tpu.ops.pallas import selective_scan as kernels
+    from paddle_tpu.parallel import DP_ONLY_RULES
+    monkeypatch.setattr(kernels, "supported", lambda u, a: True)
+    paddle.seed(0)
+    net = phi4flash_tiny(num_hidden_layers=4, layer_indices=[0, 16, 17, 18],
+                         layer_types=["mamba", "memory_mamba",
+                                      "full_attention", "gmu"])
+    opt = optimizer.AdamW(1e-3, parameters=net.parameters())
+    trainer = SpmdTrainer(net, opt, create_mesh(devices=jax.devices()[:1]),
+                          DP_ONLY_RULES)
+    text = _lowered_step(trainer, (1, 16)).compile().as_text()
+    names = sorted(n for n in KERNEL_NAMES if n.startswith("selscan_"))
+    assert names == ["selscan_bwd", "selscan_fwd"]
+    found = {name: set() for name in names}
+    for instruction, op_name in re.findall(
+            r'^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name="([^"]*)"', text,
+            re.MULTILINE):
+        for name in names:
+            if f"/{name}/" in op_name:
+                path = _path(op_name)
+                assert path[:2] == ["pt.ssm", "pt.ssm.sel"], op_name
+                found[name].add(trace_pass(op_name, instruction))
+    assert found["selscan_fwd"] == {"forward", "recompute"}
+    assert found["selscan_bwd"] == {"backward"}
 
 
 def test_scope_names_stay_clear_of_effect_scopes():

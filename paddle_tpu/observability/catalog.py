@@ -645,8 +645,8 @@ def trace_pass(op_name, instruction_name=""):
 
 # `name=` of the Pallas kernels (ops/pallas/flash_attention.py,
 # ops/pallas/power_retention.py, ops/pallas/rope_norm.py,
-# ops/pallas/selective_scan.py): the Mosaic kernel name and the innermost
-# scope of the call on the trace.
+# ops/pallas/selective_scan.py, ops/pallas/moe_gather.py): the Mosaic
+# kernel name and the innermost scope of the call on the trace.
 KERNEL_NAMES = {
     "fa_fwd": "flash attention forward (+ fused RMS epilogue)",
     "fa_bwd_dq": "flash attention backward, dQ",
@@ -677,6 +677,12 @@ KERNEL_NAMES = {
     "selscan_bwd": "its backward, a chunk of 128 steps a grid step from "
                    "the last: the chunk's states made again from the saved "
                    "one, then the adjoint walked back through them",
+    "moe_gather": "the routed experts' combine (and the dispatch's "
+                  "transpose): a tile of tokens a grid step, the 8-row "
+                  "blocks its live slots name fetched once by DMA, each "
+                  "slot's row picked by a one-hot product on the MXU, the "
+                  "columns added in float32 in order "
+                  "(ops/pallas/moe_gather.py)",
 }
 
 

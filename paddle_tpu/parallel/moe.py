@@ -34,6 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops.pallas import moe_gather as _gather_kernel
+
 __all__ = ["moe_dispatch_combine", "ExpertParallelMoE", "gshard_dispatch",
            "route_top_k", "grouped_matmul", "dropless_moe"]
 
@@ -248,6 +250,18 @@ def _gather_rows(y, slot):
     return out.astype(y.dtype)
 
 
+def _sum_rows(y, slot):
+    """`_gather_rows`, bit for bit, through the `moe_gather` kernel
+    (ops/pallas/moe_gather.py) where it takes the operands: on a TPU, y
+    bf16 of a width that is whole lane tiles, rows a multiple of 8, tokens
+    that a tile divides. It fetches only the 8-row blocks that live slots
+    name, where the column gathers read a row for every slot; elsewhere
+    the gathers stay."""
+    if _gather_kernel.usable(y, slot):
+        return _gather_kernel.gather(y, slot)
+    return _gather_rows(y, slot)
+
+
 # Dispatch (tokens into expert order) and combine (expert order back onto
 # tokens) are each other's transposes, and both are gathers: a row belongs
 # to one assignment and an assignment has one row. Autodiff would write
@@ -270,11 +284,11 @@ def _dispatch_bwd(res, g):
 
 @jax.custom_vjp
 def _combine(y, index, slot):
-    return _gather_rows(y, slot)
+    return _sum_rows(y, slot)
 
 
 def _combine_fwd(y, index, slot):
-    return _gather_rows(y, slot), (index, slot)
+    return _sum_rows(y, slot), (index, slot)
 
 
 def _combine_bwd(res, g):

@@ -226,6 +226,26 @@ def test_nope_gqa_at_8k_compiles_and_routes_to_the_kernels(one_chip):
     assert "fa_bwd_dq" in text and "fa_bwd_dkv" in text
 
 
+def _assert_the_combine_is_moe_gather(text, tokens_shape):
+    """The routed experts' combine in a compiled MoE block is the
+    `moe_gather` kernel (ops/pallas/moe_gather.py) under pt.moe/
+    pt.moe.route, once in the forward pass and once in the backward (the
+    dispatch's transpose; the sub-block's recomputation drops it, nothing
+    there reads it), and no gather of the tokens' shape is left: the
+    column gathers of `parallel/moe.py _gather_rows` are gone."""
+    from paddle_tpu.observability.catalog import trace_pass
+    passes = collections.Counter()
+    for inst, op_name in re.findall(
+            r'^\s*(?:ROOT )?%?([\w.\-]+) = .*tpu_custom_call.*'
+            r'op_name="([^"]*/moe_gather/[^"]*)"', text, re.MULTILINE):
+        plain = op_name.replace("jvp(", "").replace("transpose(", "").replace(
+            ")", "").replace("checkpoint/", "")
+        assert "pt.moe/pt.moe.route/" in plain, op_name
+        passes[trace_pass(op_name, inst)] += 1
+    assert passes == {"forward": 1, "backward": 1}
+    assert not re.search(re.escape(tokens_shape) + r"\S* gather\(", text)
+
+
 def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(
         one_chip, monkeypatch):
     """`lax.ragged_dot` in parallel/moe.py dropless_moe (what its
@@ -263,9 +283,11 @@ def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(
         sds(2048, 4096), sds(4096, 72), sds(9, 4096, 1536),
         sds(9, 768, 4096)).compile().as_text()
     kernels = [line for line in text.splitlines()
-               if "custom-call(" in line and "tpu_custom_call" in line]
+               if "custom-call(" in line and "tpu_custom_call" in line
+               and "/moe_gather/" not in line]
     # x W_in and act W_out, and the two products of each in the backward
     assert len(kernels) >= 6
+    _assert_the_combine_is_moe_gather(text, "bf16[2048,4096]")
     assert not re.search(r" scatter\(", text)
     metrics = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark", "layer_metrics")
@@ -316,9 +338,10 @@ def test_mellum_moe_compiles_with_the_pallas_grouped_matmul(one_chip,
         if "custom-call(" in line and "tpu_custom_call" in line:
             op_name = re.search(r'op_name="([^"]*)"', line).group(1)
             assert "pt.moe" in op_name and "ragged" not in op_name, op_name
-            kernels[re.search(r"jit\((\w+)\)/pallas_call", op_name).group(1)] \
+            kernels[re.search(r"(\w+)\)?/pallas_call", op_name).group(1)] \
                 += 1
-    assert kernels == {"gmm": 5, "tgmm": 2}
+    assert kernels == {"gmm": 5, "tgmm": 2, "moe_gather": 2}
+    _assert_the_combine_is_moe_gather(text, "bf16[4096,2304]")
     for scattered in re.findall(r"= \w+\[(\d*)\]\S* scatter\(", text):
         assert int(scattered or 1) <= 1024
 

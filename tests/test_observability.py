@@ -304,14 +304,14 @@ class TestCatalog:
     @pytest.mark.parametrize("name", sorted(obs_catalog.KERNEL_NAMES))
     def test_kernel_names_have_their_row_in_the_docs(self, name):
         """Every `name=` of a Pallas kernel, the flash kernels', the
-        power retention's `retn_*`, the position part's `normrope_*` and
-        the selective scan's `selscan_*`, has a `kernel/NAME` row in
-        OBSERVABILITY.md beside the scopes: a trace's reader finds the
-        kernel by that name."""
+        power retention's `retn_*`, the position part's `normrope_*`, the
+        selective scan's `selscan_*` and the routed experts' combine
+        `moe_gather`, has a `kernel/NAME` row in OBSERVABILITY.md beside
+        the scopes: a trace's reader finds the kernel by that name."""
         text = open(os.path.join(REPO, "OBSERVABILITY.md")).read()
         assert re.search(rf"^\| `kernel/{name}` \| \S", text, re.MULTILINE)
         assert name.startswith(("fa_", "faw_", "retn_", "normrope_",
-                                "selscan_"))
+                                "selscan_")) or name == "moe_gather"
 
     def test_metric_refuses_unknown_names(self):
         with pytest.raises(KeyError, match="catalog"):

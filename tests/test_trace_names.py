@@ -792,6 +792,34 @@ def test_scan_kernels_keep_the_name_stack_of_the_scan(monkeypatch):
     assert found["selscan_bwd"] == {"backward"}
 
 
+def test_combine_kernel_keeps_the_name_stack_of_the_route(monkeypatch):
+    """Where the rule picks it (here steered, the kernel interpreted), the
+    routed experts' combine is the `moe_gather` kernel (ops/pallas/
+    moe_gather.py): in the compiled train step every operation of its is
+    under pt.moe/pt.moe.route, in the forward (the combine) and in the
+    backward (the dispatch's transpose), so that its time stays in
+    `moe_route_time_share` and `moe_time_share`."""
+    from paddle_tpu.models.mellum import mellum_tiny
+    from paddle_tpu.parallel import DP_ONLY_RULES, moe
+    monkeypatch.setattr(moe._gather_kernel, "usable", lambda y, slot: True)
+    paddle.seed(0)
+    net = mellum_tiny(num_hidden_layers=1, layer_types=["sliding_attention"],
+                      dtype="bfloat16")
+    opt = optimizer.AdamW(1e-3, parameters=net.parameters())
+    trainer = SpmdTrainer(net, opt, create_mesh(devices=jax.devices()[:1]),
+                          DP_ONLY_RULES)
+    text = _lowered_step(trainer, (1, 16)).compile().as_text()
+    assert "moe_gather" in KERNEL_NAMES
+    found = set()
+    for instruction, op_name in re.findall(
+            r'^\s*(?:ROOT )?%?([\w.\-]+) = .*op_name="([^"]*)"', text,
+            re.MULTILINE):
+        if "/moe_gather/" in op_name:
+            assert _path(op_name)[:2] == ["pt.moe", "pt.moe.route"], op_name
+            found.add(trace_pass(op_name, instruction))
+    assert found == {"forward", "backward"}
+
+
 def test_scope_names_stay_clear_of_effect_scopes():
     from paddle_tpu.pir.verifier import EFFECT_SCOPES
     for name in list(TRACE_SCOPES) + list(KERNEL_NAMES):

@@ -28,11 +28,27 @@ Pallas grouped matmul for the widths, through that (`chosen`); `--sweep`
 adds every candidate tiling of that kernel, which is what `_GMM_TILES`
 in parallel/moe.py was read off. Prints one JSON object; ms are medians
 of `--repeat` timings of `--calls` calls each, back to back.
+
+`--gather` times the combine alone instead, at each count of `--tokens`:
+the `moe_gather` kernel (ops/pallas/moe_gather.py, with the `jax.numpy`
+plan it makes first, also timed alone as `plan_ms`) at tiles of 64 tokens
+and of its own list, against the column gathers of `moe._gather_rows`
+under jax.jit, on y [tokens x min(top-k, held), hidden] bf16 and the slot
+table `dropless_moe` makes from seeded routing (the held experts' logits
+raised where `--live` asks for a share of live slots), rows past the last
+live one NaN as the kernels may leave them. Each result is compared with
+the gathers' bit for bit:
+
+    python tools/moe_rows_bench.py --gather --hidden 2304 \
+        --held 16 --experts 64 --top-k 8 --tokens 4096
+    python tools/moe_rows_bench.py --gather --tokens 2048 \
+        --live 0 0.37
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -45,6 +61,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from paddle_tpu.models.sub_block import over_token_blocks  # noqa: E402
+from paddle_tpu.ops.pallas import moe_gather  # noqa: E402
 from paddle_tpu.parallel import moe  # noqa: E402
 
 
@@ -220,6 +237,82 @@ def layer(a, tokens, block, key):
             "tflops_live": round(flops / ms / 1e9, 2)}
 
 
+def combine_operands(a, tokens, live, key):
+    """(y, slot) of one combine as `dropless_moe` hands them over, for
+    `tokens` routed by seeded logits, the held experts' raised by the
+    smallest step of 0.05 that makes at least `live` of the slots live
+    (0: as drawn)."""
+    logits = jax.random.normal(jax.random.key(a.seed), (tokens, a.experts))
+    held, cols = (0, a.held), min(a.top_k, a.held)
+
+    def routed(lift):
+        raised = logits.at[:, :a.held].add(lift)
+        _, slot, sizes = moe.sorted_assignments(
+            jax.lax.top_k(raised, a.top_k)[1], held)
+        return slot, sizes, float(jnp.sum(sizes)) / (tokens * a.top_k)
+    slot, sizes, share = routed(0.0)
+    # lowered where `live` asks for fewer than the draw gives
+    step = 0.05 if share < live else -0.05
+    lift = 0.0
+    while live and (share < live if step > 0 else share > live):
+        lift += step
+        slot, sizes, share = routed(lift)
+    if cols < a.top_k:
+        slot = jnp.sort(slot, axis=1)[:, :cols]
+    rows = tokens * cols
+    y = jax.random.normal(key, (rows, a.hidden), jnp.bfloat16)
+    y = jnp.where((jnp.arange(rows) < jnp.sum(sizes))[:, None], y, jnp.nan)
+    return y.astype(jnp.bfloat16), slot
+
+
+def blocks_a_tile(slot, rows, tile):
+    """(mean, largest) distinct 8-row blocks the live slots of a tile of
+    tokens name: what the kernel fetches a grid step."""
+    s = np.asarray(slot).reshape(-1, tile * slot.shape[1])
+    counts = [len(set((r[r < rows] // 8).tolist())) for r in s]
+    return float(np.mean(counts)), int(max(counts))
+
+
+def gathers(a, tokens, live, key):
+    """ms of the combine through the column gathers and through the
+    kernel at each tile, and whether each equals the gathers bit for
+    bit."""
+    y, slot = combine_operands(a, tokens, live, key)
+    rows, width = y.shape
+    n_live = int(jnp.sum(slot < rows))
+    # live rows read and the tokens' rows written, in bf16
+    moved = 2.0 * width * (n_live + tokens)
+    want = jax.jit(moe._gather_rows)(y, slot)
+    out = {"tokens": tokens, "rows": rows, "k": int(slot.shape[1]),
+           "live_share": round(n_live / (tokens * a.top_k), 4),
+           "live_rows": n_live,
+           "chosen_tile": moe_gather._tile(tokens, slot.shape[1], width)}
+    paths = {"xla_gathers": jax.jit(moe._gather_rows)}
+    for tile in (64,) + moe_gather._TILES:
+        if tokens % tile == 0:
+            paths[f"kernel_tile_{tile}"] = jax.jit(functools.partial(
+                moe_gather.gather, tile=tile))
+    # the block lists and row ids `jax.numpy` makes before the kernel
+    plan = jax.jit(functools.partial(moe_gather._plan, rows=rows,
+                                     tile=out["chosen_tile"]))
+    out["plan_ms"] = round(timed(plan, (slot,), a.calls, a.repeat), 4)
+    for name, fn in paths.items():
+        # every one of five calls, back to back, against the gathers
+        equal = all(bool(jnp.all(
+            jax.lax.bitcast_convert_type(got, jnp.uint16)
+            == jax.lax.bitcast_convert_type(want, jnp.uint16)))
+            for got in [fn(y, slot) for _ in range(5)])
+        ms = timed(fn, (y, slot), a.calls, a.repeat)
+        entry = out[name] = {
+            "ms": round(ms, 4), "gb_s_live": round(moved / ms / 1e6, 1),
+            "bit_equal": equal}
+        if name != "xla_gathers":
+            tile = int(name.rsplit("_", 1)[1])
+            entry["blocks_mean"], entry["blocks_max"] = blocks_a_tile(
+                slot, rows, tile)
+    return out
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--hidden", type=int, default=4096)
@@ -232,6 +325,12 @@ def main():
         help="tokens, or tokens:rows of the buffer, of each call")
     ap.add_argument("--sweep", action="store_true",
                     help="also time the Pallas grouped matmul's tilings")
+    ap.add_argument("--gather", action="store_true",
+                    help="time the combine alone: moe_gather against the "
+                         "column gathers")
+    ap.add_argument("--live", type=float, nargs="+", default=[0.0],
+                    help="with --gather: shares of live slots to time "
+                         "(0: as the seeded routing draws them)")
     ap.add_argument("--calls", type=int, default=40)
     ap.add_argument("--repeat", type=int, default=5)
     ap.add_argument("--seed", type=int, default=0)
@@ -247,6 +346,14 @@ def main():
         shapes.append((int(tokens), int(rows) if rows
                        else int(tokens) * min(a.top_k, a.held)))
     counts = sorted({t for t, _ in shapes})
+    if a.gather:
+        result = {
+            "device": {"platform": dev.platform, "kind": dev.device_kind},
+            "widths": {k: getattr(a, k) for k in
+                       ("hidden", "held", "experts", "top_k")},
+            "gather": [gathers(a, t, live, key)
+                       for t in counts for live in a.live]}
+        return _emit(result, a.out)
     result = {
         "device": {"platform": dev.platform, "kind": dev.device_kind},
         "widths": {k: getattr(a, k) for k in
@@ -257,11 +364,15 @@ def main():
     }
     if a.sweep:
         result["sweep"] = [sweep(a, t, rows, key) for t, rows in shapes]
+    _emit(result, a.out)
+
+
+def _emit(result, path):
     text = json.dumps(result, indent=1)
     print(text)
-    if a.out:
-        os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
-        with open(a.out, "w") as f:
+    if path:
+        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
+        with open(path, "w") as f:
             f.write(text + "\n")
 
 

@@ -370,18 +370,22 @@ def _grouped_matmul_case(rows, k, n, groups, tiles=None):
     if bad:
         raise AssertionError(f"grouped_matmul differs from lax.ragged_dot: "
                              f"{bad} > {KERNEL_GRAD_TOL}")
-    return {"case": "grouped_matmul", "max_rel_err": errs}
+    return {"case": "grouped_matmul", "shape": (rows, k, n, groups),
+            "max_rel_err": errs}
 
 
 def kernel_phase(bh=TRAIN_BATCH * 16, seq=TRAIN_SEQ, d=128, small_seq=512,
                  gqa=(16, 4), interpret=False,
-                 grouped=(8192, 2304, 1792, 16)):
+                 grouped=((8192, 2304, 1792, 16), (18432, 4096, 1536, 9),
+                          (18432, 768, 4096, 9))):
     """Compile (interpret=False on the chip) and run the bf16 flash kernels
     at the trainer's attention shape with the tiles the chooser hands out,
     then with one-lane-tile sub-blocks inside a streamed tile shorter than
     the sequence (the clamped index maps and the loops' bounds both at
     work), GQA, the d=96 zero-pad and the RMS epilogue once each; then the
-    routed experts' grouped matmul at the Mellum cell's first product."""
+    routed experts' grouped matmul at each case of `grouped`: the Mellum
+    cell's first product, and the granite cell's two over its buffer of
+    18,432 rows."""
     from paddle_tpu.ops.pallas.flash_attention import Tiles
 
     log(f"kernel phase: bh={bh} seq={seq} d={d} bf16 causal")
@@ -394,8 +398,7 @@ def kernel_phase(bh=TRAIN_BATCH * 16, seq=TRAIN_SEQ, d=128, small_seq=512,
                         3),
         _pad96_case(2, small_seq, 4),
         _epilogue_case(2, small_seq, 4, d),
-        _grouped_matmul_case(*grouped),
-    ]
+    ] + [_grouped_matmul_case(*case) for case in grouped]
     return {"cases": cases}
 
 

@@ -333,18 +333,35 @@ _in_slot_order.defvjp(_in_slot_order_fwd, _in_slot_order_bwd)
 # Tiles (rows, k, n) of jax's Pallas grouped matmul (megablox `gmm`, and
 # `tgmm` for the weights' gradient) for bf16 on a TPU, by the (k, n) of
 # one expert's matrix: (forward, the rows' gradient, the weights'
-# gradient). Each is its product's fastest of `tools/moe_rows_bench.py
-# --sweep` on a v5e (PERF.md section 6, PR 35): for `gmm` the contraction
-# whole, so that no accumulator is read back, beside as much of the
-# matrix as VMEM holds twice; for `tgmm` the largest float32 tile of the
-# gradient that fits. XLA's own kernel behind `lax.ragged_dot` runs tiles
-# of 512 x 256 x 256 at these widths and is bound by its grid steps, not
-# the MXU: over the six products at 512 rows a group 31 TFLOP/s where
-# these read 91 or more, at 2,048 rows 51 and 142. Widths that were not
-# measured keep `lax.ragged_dot`.
+# gradient), None where that product stays `lax.ragged_dot`'s. Each is
+# its product's fastest of `tools/moe_rows_bench.py --sweep` on a v5e
+# (PERF.md section 6): for `gmm` the contraction whole, so that no
+# accumulator is read back, beside as much of the matrix as VMEM holds
+# twice; for `tgmm` the largest float32 tile of the gradient that fits.
+# XLA's own kernel behind `lax.ragged_dot` runs tiles of 512 x 256 x 256
+# at these widths and is bound by its grid steps, not the MXU. Widths
+# that were not measured keep `lax.ragged_dot`.
 _GMM_TILES = {
+    # the Mellum cell's: over the six products at 512 rows a
+    # group 31 TFLOP/s through `lax.ragged_dot`, 91 or more through
+    # these; at 2,048 rows 51 and 142
     (2304, 1792): ((256, 2304, 896), (256, 1792, 1152), (256, 1152, 896)),
     (896, 2304): ((256, 896, 2304), (256, 2304, 896), (256, 896, 1152)),
+    # the granite cell's: the fastest of 15-19 tilings that Mosaic fits
+    # into the default scoped VMEM, the same at ~280 and ~1,140 rows a
+    # group (9 groups, 2,551 / 10,303 live rows of 18,432; row tiles of
+    # 128 leave less of a tile empty at a group's end); the four products
+    # 2.10 -> 1.17 ms and 4.43 -> 2.74 ms, 46 -> 82 and 88 -> 142
+    # TFLOP/s. The weights' gradients stay XLA's: through `tgmm` (at
+    # (256, 1024, 1536) and (256, 768, 2048): 1.24 -> 0.71 ms, 2.53 ->
+    # 1.54) the granite step, which fills the chip, has XLA
+    # rematerialise 30 instructions where it has 22 (described compile,
+    # whatever the tiles), and 23 without them. End to end the step with
+    # those `tgmm` tiles reads 1.2% faster still (three seeds, PERF.md
+    # section 6): that rematerialisation bound alone keeps them off
+    # (ROADMAP S5a (f2))
+    (4096, 1536): ((128, 4096, 768), (128, 1536, 2048), None),
+    (768, 4096): ((128, 768, 4096), (128, 4096, 768), None),
 }
 
 
@@ -355,7 +372,7 @@ def _gmm_tiles(lhs, rhs):
     tiles = _GMM_TILES.get(tuple(rhs.shape[1:]))
     if (tiles is None or jax.default_backend() != "tpu"
             or lhs.dtype != jnp.bfloat16 or rhs.dtype != jnp.bfloat16
-            or any(lhs.shape[0] % rows for rows, _, _ in tiles)):
+            or any(t and lhs.shape[0] % t[0] for t in tiles)):
         return None
     return tiles
 
@@ -364,9 +381,10 @@ def _gmm_tiles(lhs, rhs):
 def _gmm(lhs, rhs, sizes, tiles, interpret):
     """`grouped_matmul` through the Pallas kernels, each of the three
     products at its own tiles (megablox's own `custom_vjp` gives all three
-    the forward's). float32 accumulation, results in the operands' dtype:
-    what `lax.ragged_dot` gives. Rows past the last group are not written,
-    forward or backward, as with XLA's kernel."""
+    the forward's), the weights' gradient through `lax.ragged_dot` where
+    its tile is None. float32 accumulation, results in the operands'
+    dtype: what `lax.ragged_dot` gives. Rows past the last group are not
+    written, forward or backward, as with XLA's kernel."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
     return gmm(lhs, rhs, sizes, lhs.dtype, tiles[0], interpret=interpret)
 
@@ -380,9 +398,13 @@ def _gmm_bwd(tiles, interpret, res, g):
     lhs, rhs, sizes = res
     d_lhs = gmm(g, rhs, sizes, lhs.dtype, tiles[1], transpose_rhs=True,
                 interpret=interpret)
-    # tgmm takes its first operand (k, rows) and turns it back itself
-    d_rhs = tgmm(lhs.swapaxes(0, 1), g, sizes, rhs.dtype, tiles[2],
-                 interpret=interpret)
+    if tiles[2] is None:
+        d_rhs = jax.vjp(lambda w: jax.lax.ragged_dot(lhs, w, sizes),
+                        rhs)[1](g)[0]
+    else:
+        # tgmm takes its first operand (k, rows) and turns it back itself
+        d_rhs = tgmm(lhs.swapaxes(0, 1), g, sizes, rhs.dtype, tiles[2],
+                     interpret=interpret)
     return d_lhs, d_rhs, None
 
 
@@ -419,15 +441,16 @@ def dropless_moe(x, router_w, w_in, w_out, top_k, experts_held,
     shards of an expert-parallel group the parts add up to the whole layer
     (tests/test_granite_moe_hybrid.py). Nothing is dropped: assignments
     are sorted by expert, and the grouped product (`grouped_matmul`: a
-    kernel that visits only the tiles that hold rows, jax's Pallas one
-    at the widths it was measured at, else `lax.ragged_dot`, which the
-    TPU compiler turns into its own: on the chip a call costs the same in
-    a buffer of 4,096 rows as in one of 18,432, and its rate follows the
-    rows a group has, PERF.md section 6, PR 29 and PR 35) has a row for
-    every assignment that can land here. Rows past the last assignment
-    are not written by those kernels and may hold anything, NaN too:
-    nothing may carry them on, forward or backward. No part of the layer
-    is a scatter, and no gather fetches single numbers: a TPU pays both
+    kernel that visits only the tiles that hold rows: jax's Pallas one
+    at the widths it was measured at, the granite and Mellum cells', else
+    `lax.ragged_dot`, which the TPU compiler turns into its own; on the
+    chip either costs the same in a buffer of 4,096 rows as in one of
+    18,432, and its rate follows the rows a group has, PERF.md section 6)
+    has a row for every assignment that can land here. Rows past the last
+    assignment are not written by those kernels and may hold anything,
+    NaN too: nothing may carry them on, forward or backward. No part of
+    the layer is a scatter (but the Pallas kernels' own count of row
+    tiles a group), and no gather fetches single numbers: a TPU pays both
     by the index. Component scope `pt.moe.route` holds what is not expert
     work.
 

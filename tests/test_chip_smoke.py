@@ -36,7 +36,7 @@ class TestPhasesAtTinySizes:
     def test_kernel_phase(self):
         res = chip_smoke.kernel_phase(
             bh=1, seq=256, small_seq=128, gqa=(4, 2), interpret=True,
-            grouped=(512, 160, 256, 4, ((128, 128, 128),) * 3))
+            grouped=((512, 160, 256, 4, ((128, 128, 128),) * 3),))
         names = [c["case"] for c in res["cases"]]
         assert names == ["chooser_tiles", "small_tiles", "gqa",
                          "d96_zero_pad", "rms_epilogue", "grouped_matmul"]

@@ -246,21 +246,48 @@ def _assert_the_combine_is_moe_gather(text, tokens_shape):
     assert not re.search(re.escape(tokens_shape) + r"\S* gather\(", text)
 
 
+def _metadata_scatters(text):
+    """The scatters of a compiled MoE block, each checked to be what
+    megablox `gmm` / `tgmm` make of the group sizes (a tile count a group,
+    under `jit(gmm)` or `jit(tgmm)`): one dimension of 1,024 elements at
+    most. A scatter of rows, of the tokens' or the buffer's shape, or of
+    anything outside the kernels' metadata fails here. Returns how many
+    there are."""
+    found = 0
+    for line in text.splitlines():
+        if " scatter(" not in line:
+            continue
+        shapes = re.findall(r"\w+\[([\d,]*)\]",
+                            line.split(" scatter(")[0].split(" = ", 1)[1])
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        assert shapes and op_name, line
+        assert re.search(r"jit\((gmm|tgmm)\)/", op_name.group(1)), line
+        for dims in shapes:
+            dims = [int(d) for d in dims.split(",") if d]
+            assert len(dims) == 1 and math.prod(dims) <= 1024, line
+        found += 1
+    return found
+
+
 def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(
         one_chip, monkeypatch):
-    """`lax.ragged_dot` in parallel/moe.py dropless_moe (what its
-    `grouped_matmul` keeps at the granite cell's widths, on a TPU too:
-    they have no entry in `_GMM_TILES`) becomes the TPU
-    compiler's own grouped-matmul kernel, whose op_name is the kernel's
-    name in place of the program's name stack, so the scope `pt.moe` is
-    lost on it. benchmark/layer_metrics/moe_time_share.json and
-    moe_grouped_matmul_roofline.json find it by that name: if a compiler
-    gives it another, this fails here, and the metrics do not go short in
-    silence on the chip. One FFN block of the published widths, forward
-    and backward. What is not the kernel holds no scatter: the counts, the
-    gates' way into expert order and back and the chosen logits' gradient
-    are comparisons and sorts, which the chip does not run one index
-    after the other."""
+    """The granite cell's routed experts (parallel/moe.py dropless_moe:
+    hidden 4096, 9 held of 72 of width 768, top-10, the routing
+    differentiated, bf16) over one 2,048-token FFN block, forward and
+    backward, for a described v5e. At these widths `grouped_matmul`
+    takes jax's Pallas `gmm` at the tiles of `_GMM_TILES` for each
+    product's forward and rows' gradient, which keep the program's name
+    stack: every Pallas kernel is `gmm` or `moe_gather` under `pt.moe`,
+    where benchmark/layer_metrics/moe_time_share.json finds it. The two
+    weights' gradients stay XLA's grouped-matmul kernel (`_GMM_TILES`
+    says why), whose op_name is the kernel's name in place of the name
+    stack: moe_time_share.json and moe_grouped_matmul_roofline.json find
+    those by that name, and if a compiler gives them another, this fails
+    here and the metrics do not go short in silence on the chip. What is
+    not a kernel holds no scatter but the kernels' own metadata (a tile
+    count a group): the counts, the gates' way into expert order and back
+    and the chosen logits' gradient are comparisons and sorts, which the
+    chip does not run one index after the other."""
     import json
     import os
     from paddle_tpu.parallel.moe import dropless_moe
@@ -282,23 +309,37 @@ def test_grouped_matmul_kernel_keeps_the_name_its_metrics_read(
     text = jax.jit(grads).lower(
         sds(2048, 4096), sds(4096, 72), sds(9, 4096, 1536),
         sds(9, 768, 4096)).compile().as_text()
-    kernels = [line for line in text.splitlines()
-               if "custom-call(" in line and "tpu_custom_call" in line
-               and "/moe_gather/" not in line]
-    # x W_in and act W_out, and the two products of each in the backward
-    assert len(kernels) >= 6
-    _assert_the_combine_is_moe_gather(text, "bf16[2048,4096]")
-    assert not re.search(r" scatter\(", text)
     metrics = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "benchmark", "layer_metrics")
+    rx = {}
     for name in ("moe_time_share", "moe_grouped_matmul_roofline"):
         with open(os.path.join(metrics, name + ".json")) as f:
-            rx = re.compile(json.load(f)["params"]["regex"])
-        for line in kernels:
+            rx[name] = re.compile(json.load(f)["params"]["regex"])
+    pallas, xla = collections.Counter(), collections.Counter()
+    for line in text.splitlines():
+        if "custom-call(" in line and "tpu_custom_call" in line:
             op_name = re.search(r'op_name="([^"]*)"', line).group(1)
-            assert rx.search(op_name), (name, op_name)
-            assert "pt.moe" not in op_name      # or the first alternative
-            # of moe_time_share's pattern would count the kernel twice
+            assert rx["moe_time_share"].search(op_name), op_name
+            if "/pallas_call" in op_name:
+                assert "pt.moe" in op_name and "ragged" not in op_name
+                assert not rx["moe_grouped_matmul_roofline"].search(op_name)
+                pallas[re.search(r"(\w+)\)?/pallas_call",
+                                 op_name).group(1)] += 1
+            else:
+                assert rx["moe_grouped_matmul_roofline"].search(op_name)
+                # or the first alternative of moe_time_share's pattern
+                # would count the kernel twice
+                assert "pt.moe" not in op_name
+                xla[re.search(r"= (\S+?)\{", line).group(1)] += 1
+    # x W_in and act W_out, and the rows' gradient of each; the combine,
+    # forward and the dispatch's transpose
+    assert pallas == {"gmm": 4, "moe_gather": 2}
+    # the two weights' gradients, and the metadata XLA's kernel makes
+    assert xla["bf16[9,4096,1536]"] == 1 and xla["bf16[9,768,4096]"] == 1
+    assert sum(xla.values()) == 3
+    _assert_the_combine_is_moe_gather(text, "bf16[2048,4096]")
+    # the four kernels' tile counts, as many as XLA leaves of them
+    assert _metadata_scatters(text) == 3
 
 
 def test_mellum_moe_compiles_with_the_pallas_grouped_matmul(one_chip,
@@ -342,8 +383,8 @@ def test_mellum_moe_compiles_with_the_pallas_grouped_matmul(one_chip,
                 += 1
     assert kernels == {"gmm": 5, "tgmm": 2, "moe_gather": 2}
     _assert_the_combine_is_moe_gather(text, "bf16[4096,2304]")
-    for scattered in re.findall(r"= \w+\[(\d*)\]\S* scatter\(", text):
-        assert int(scattered or 1) <= 1024
+    # the seven kernels' tile counts, as many as XLA leaves of them
+    assert _metadata_scatters(text) == 9
 
 
 @pytest.mark.parametrize("rows, weighted", [(5120, True), (1024, False)],

@@ -319,34 +319,45 @@ def test_rows_that_hold_no_assignment_may_hold_anything(monkeypatch):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
 
 
-def test_pallas_grouped_matmul_is_ragged_dot_forward_and_backward():
+@pytest.mark.parametrize("rows, k, n, sizes, tiles", [
+    # a k and an n that the tiles do not divide
+    (512, 160, 200, (100, 0, 156, 71),
+     ((128, 128, 128), (256, 256, 128), (128, 128, 256))),
+    # the granite cell's two products at `_GMM_TILES`' tiles, the widths
+    # whole, the rows cut to a buffer of 1,024 (the weights' gradient
+    # through `lax.ragged_dot` where its tile is None)
+    (1024, 4096, 1536, (300, 0, 411, 157), None),
+    (1024, 768, 4096, (300, 0, 411, 157), None),
+], ids=["odd_widths", "granite_x_w_in", "granite_act_w_out"])
+def test_pallas_grouped_matmul_is_ragged_dot_forward_and_backward(
+        rows, k, n, sizes, tiles):
     """`moe._gmm` (jax's Pallas grouped matmul, interpreted here, each of
     its three products at its own tiles) against `lax.ragged_dot` and its
-    two gradients: uneven groups, one empty, a k and an n that the tiles
-    do not divide, and NaN in the rows past the last group, which neither
-    reads and neither has to write."""
+    two gradients: uneven groups, one empty, and NaN in the rows past the
+    last group, which neither reads and neither has to write."""
     rs = np.random.RandomState(35)
-    sizes = jnp.asarray([100, 0, 156, 71], jnp.int32)
-    live = np.arange(512) < int(sizes.sum())
-    lhs = rs.randn(512, 160).astype(np.float32)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    tiles = tiles or moe._GMM_TILES[k, n]
+    assert all(rows % t[0] == 0 for t in tiles if t)
+    live = np.arange(rows) < int(sizes.sum())
+    lhs = rs.randn(rows, k).astype(np.float32)
     lhs[~live] = np.nan
     lhs = jnp.asarray(lhs)
-    rhs = jnp.asarray(rs.randn(4, 160, 200).astype(np.float32))
-    cot = jnp.asarray(np.where(live[:, None], rs.randn(512, 200), np.nan)
+    rhs = jnp.asarray((rs.randn(4, k, n) / np.sqrt(k)).astype(np.float32))
+    cot = jnp.asarray(np.where(live[:, None], rs.randn(rows, n), np.nan)
                       .astype(np.float32))
-    tiles = ((128, 128, 128), (256, 256, 128), (128, 128, 256))
 
     def run(product):
-        out, back = jax.vjp(product, lhs, rhs)
+        out, back = jax.jit(lambda a, b: jax.vjp(product, a, b))(lhs, rhs)
         return (out,) + back(cot)
 
     with jax.default_matmul_precision("highest"):
         want = run(lambda a, b: jax.lax.ragged_dot(a, b, sizes))
         got = run(lambda a, b: moe._gmm(a, b, sizes, tiles, True))
-    for g, w, rows in zip(got, want, (live, live, slice(None))):
-        g, w = np.asarray(g)[rows], np.asarray(w)[rows]
+    for g, w, part in zip(got, want, (live, live, slice(None))):
+        g, w = np.asarray(g)[part], np.asarray(w)[part]
         assert np.isfinite(w).all() and np.abs(w).max() > 1
-        # float32 both: the order of a 160- or 327-term sum
+        # float32 both: the order of a sum of up to 4,096 terms
         np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-4)
     assert not np.asarray(got[2])[1].any()       # the empty group's
 
@@ -354,19 +365,24 @@ def test_pallas_grouped_matmul_is_ragged_dot_forward_and_backward():
 @pytest.mark.parametrize("rows, k, n, dtype, backend, picked", [
     (32768, 2304, 1792, "bfloat16", "tpu", True),    # the Mellum cell's
     (32768, 896, 2304, "bfloat16", "tpu", True),     # two products
-    (18432, 4096, 1536, "bfloat16", "tpu", False),   # granite's: no entry
+    (18432, 4096, 1536, "bfloat16", "tpu", True),    # the granite cell's
+    (18432, 768, 4096, "bfloat16", "tpu", True),     # two products
     (32768, 2304, 1792, "float32", "tpu", False),
     (32768 + 128, 2304, 1792, "bfloat16", "tpu", False),  # half a tile over
     (32768, 2304, 1792, "bfloat16", "cpu", False),
-], ids=["mellum_in", "mellum_out", "granite", "float32", "odd_rows", "cpu"])
+], ids=["mellum_in", "mellum_out", "granite", "granite_out", "float32",
+        "odd_rows", "cpu"])
 def test_the_pallas_grouped_matmul_is_chosen_by_shape_dtype_and_backend(
         monkeypatch, rows, k, n, dtype, backend, picked):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     tiles = moe._gmm_tiles(jax.ShapeDtypeStruct((rows, k), dtype),
                            jax.ShapeDtypeStruct((16, k, n), dtype))
     assert (tiles is not None) == picked
-    for product, (tm, tk, tn) in zip((
+    for product, tile in zip((
             (rows, k, n), (rows, n, k), (rows, k, n)), tiles or ()):
+        if tile is None:        # this product stays `lax.ragged_dot`'s
+            continue
+        tm, tk, tn = tile
         # whole 128-lane tiles that divide what they cut
         assert product[0] % tm == 0 and tk % 128 == 0 and tn % 128 == 0
         assert product[1] % tk == 0 and product[2] % tn == 0

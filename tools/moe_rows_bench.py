@@ -1,10 +1,17 @@
-"""XLA's grouped-matmul kernel (`lax.ragged_dot`) on the chip, isolated, at
-the routed experts' published widths: how a call's time depends on the
-length of the sorted buffer and on the rows in each group.
+"""The routed experts' grouped matmuls on the chip, isolated, at their
+published widths: XLA's kernel (`lax.ragged_dot`) and, where
+`moe.grouped_matmul` chooses it (the widths of `_GMM_TILES`: the granite
+cell's, which are the defaults here, and the Mellum cell's), jax's Pallas
+`gmm` / `tgmm`; how a call's time depends on the length of the sorted
+buffer and on the rows in each group.
 
     chiprun -- python tools/moe_rows_bench.py [--out chiprun_out/moe_rows.json]
+    python tools/moe_rows_bench.py --tokens 2048 8192:18432 --sweep  # one chip
     chiprun -- python tools/moe_rows_bench.py --hidden 2304 --inter 896 \
         --held 16 --experts 64 --top-k 8 --tokens 4096 8192 16384 32768
+
+(the second: the granite cell's buffer of 18,432 rows with the groups of
+the window's first steps, ~280 rows, and of its late ones, ~1,140)
 
 One layer's two products (x W_in: held x hidden x 2 inter; act W_out: held
 x inter x hidden), forward and the backward's two products each (the rows'
@@ -146,13 +153,15 @@ def products(a, tokens, rows, key):
     return out
 
 
-def sweep(a, tokens, rows, key):
+def sweep(a, tokens, rows, key, refused):
     """ms of each of the six products through jax's Pallas grouped matmul
     (megablox `gmm`, `tgmm`) at every candidate tiling: rows of 128, 256,
-    512 (and 1,024 for the weights' gradient), k and n whole or halved
-    where the half is whole 128-lane tiles. `_GMM_TILES` in
+    512 (and 1,024 for the weights' gradient), k and n whole, halved or
+    quartered where the part is whole 128-lane tiles. `_GMM_TILES` in
     parallel/moe.py holds each product's fastest; a tiling that Mosaic
-    refuses (VMEM) reads as its error."""
+    refuses (VMEM) reads as its error, and is not tried again at the next
+    shape (`refused`, by product and tiling: what a tile takes of VMEM
+    does not depend on the rows)."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
     sizes, live, both = operands(a, tokens, rows, key)
     bf = jnp.bfloat16
@@ -172,17 +181,26 @@ def sweep(a, tokens, rows, key):
             row_tiles = (256, 512, 1024) if what == "d_weights" \
                 else (128, 256, 512)
             for tiles in [(tm, tk, tn) for tm in row_tiles
-                          for tk in (tk_of, tk_of // 2) if tk % 128 == 0
-                          for tn in (tn_of, tn_of // 2) if tn % 128 == 0]:
+                          for tk in _parts(tk_of) for tn in _parts(tn_of)]:
+                if (name, what, tiles) in refused:
+                    found[str(tiles)] = refused[name, what, tiles]
+                    continue
                 try:
                     ms = timed(make(tiles), (lhs, w, g), a.calls, a.repeat)
                     found[str(tiles)] = {
                         "ms": round(ms, 4),
                         "tflops_live": round(flops / ms / 1e9, 2)}
                 except Exception as e:  # noqa: BLE001 — Mosaic's refusal
-                    found[str(tiles)] = {
+                    found[str(tiles)] = refused[name, what, tiles] = {
                         "error": (str(e).splitlines() or [repr(e)])[0][:120]}
     return out
+
+
+def _parts(width):
+    """`width` whole, halved and quartered, where the part is whole
+    128-lane tiles that divide it."""
+    return [p for p in (width, width // 2, width // 4)
+            if p % 128 == 0 and width % p == 0]
 
 
 def route_pieces(a, tokens, key):
@@ -363,7 +381,9 @@ def main():
         "layer": [layer(a, counts[-1], t, key) for t in counts],
     }
     if a.sweep:
-        result["sweep"] = [sweep(a, t, rows, key) for t, rows in shapes]
+        refused = {}
+        result["sweep"] = [sweep(a, t, rows, key, refused)
+                           for t, rows in shapes]
     _emit(result, a.out)
 
 
